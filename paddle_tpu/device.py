@@ -2,13 +2,35 @@
 of set_device/get_device and the is_compiled_with_* probes)."""
 from __future__ import annotations
 
+import os
+
+import jax
+
 from paddle_tpu.core import (device_count, get_device,  # noqa: F401
                              set_device)
 
 __all__ = ["set_device", "get_device", "device_count",
            "is_compiled_with_cuda", "is_compiled_with_xpu",
            "is_compiled_with_npu", "is_compiled_with_tpu",
-           "get_cudnn_version", "XPUPlace"]
+           "get_cudnn_version", "XPUPlace", "use_compile_cache"]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Every entry point that compiles for the chip calls this
+    before its first compile (``chip_smoke.py``, ``bench.py``, the
+    ``tools/`` and ``perf/`` scripts); the library and the tests do not.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that
+    directory and this names no other.  Otherwise the cache is
+    ``<checkout>/.jax_cache`` — a fixed path with no pid, time or
+    temporary name in it, so every process finds the same entries."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def is_compiled_with_cuda() -> bool:

@@ -625,6 +625,14 @@ class ElasticAgent:
                 continue
             if h.name in self._restart_at:
                 if now >= self._restart_at[h.name]:
+                    if h.alive():
+                        # the killed incarnation has not been reaped yet
+                        # (kill() bounds its wait): it still holds the
+                        # chip, and a replacement started beside it
+                        # would fail or hang on it — kill again and
+                        # look on the next pass
+                        h.kill()
+                        continue
                     del self._restart_at[h.name]
                     h.restart()
                     self._alive_since[h.name] = now

@@ -10,8 +10,8 @@ is forced to 1 — per-device processes are the reference's CUDA shape, not
 XLA's).  Multi-host slices pass ``--ips``; rank/world derive from this
 host's position and jax.distributed uses the first entry as coordinator.
 PS mode (``--server_num/--worker_num``) launches N parameter-server
-processes + M trainers with the TRAINING_ROLE env protocol, matching the
-reference's launch_ps.  All children get supervised: stdout/stderr tee to
+processes (pinned to the host platform) + one trainer with the
+TRAINING_ROLE env protocol, matching the reference's launch_ps.  All children get supervised: stdout/stderr tee to
 ``log_dir/{worker,server}log.N``, and if any child dies the rest are
 terminated and the launcher exits with the failing code (the
 watch_local_trainers contract).
@@ -397,8 +397,21 @@ def _launch_collective(args, ips) -> int:
 
 
 def _launch_ps(args) -> int:
-    """launch_ps: servers first, then trainers, one env block each."""
+    """launch_ps: servers first, then trainers, one env block each.
+
+    One process per chip: servers are host-tier (numpy tables + TCP) and
+    get ``JAX_PLATFORMS=cpu``, so a server that imports jax never opens
+    the accelerator its trainer needs; and several trainers on one host
+    would race for the same chip(s), so ``--worker_num > 1`` is refused
+    unless the launcher itself was started with ``JAX_PLATFORMS=cpu``
+    (host-only trainers, which the children inherit)."""
     n_s, n_w = args.server_num, args.worker_num
+    if n_w > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        print(f"launch: --worker_num {n_w} on one host would start {n_w} "
+              "processes racing for the same chip(s) — one SPMD trainer "
+              "drives every chip of a host; use --worker_num 1, or export "
+              "JAX_PLATFORMS=cpu for host-only trainers", file=sys.stderr)
+        return 2
     server_eps = [f"127.0.0.1:{args.start_port + i}" for i in range(n_s)]
     worker_eps = [f"127.0.0.1:{args.start_port + n_s + i}"
                   for i in range(n_w)]
@@ -419,7 +432,8 @@ def _launch_ps(args) -> int:
                    PADDLE_PSERVER_ID=str(i),
                    PADDLE_PORT=str(args.start_port + i),
                    POD_IP="127.0.0.1",
-                   PADDLE_TRACE_LABEL=f"server-{i}")
+                   PADDLE_TRACE_LABEL=f"server-{i}",
+                   JAX_PLATFORMS="cpu")
         env.update(_collector_env(col_ep, "server"))
         children.append(_Child(
             f"server-{i}", cmd, env,

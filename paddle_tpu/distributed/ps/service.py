@@ -1238,10 +1238,9 @@ def serve(port: int, table_specs: Sequence[str], host: str = "127.0.0.1",
 
 # Spawn recipe for a server subprocess: the server is host-tier only
 # (numpy tables + TCP) and must NOT contend for the accelerator the
-# trainer holds — and the platform override must land BEFORE any
-# paddle_tpu import (a ``-m paddle_tpu...`` child imports the package
-# first, which initializes the backend; the env var alone is not
-# honored once the plugin is registered).  Use:
+# trainer holds (one process per chip) — so the platform override lands
+# BEFORE any paddle_tpu import, and through jax.config so that it holds
+# whatever JAX_PLATFORMS the child inherited.  Use:
 #   subprocess.Popen([sys.executable, "-c", SERVER_BOOT, *args])
 SERVER_BOOT = ("import jax, sys; "
                "jax.config.update('jax_platforms', 'cpu'); "

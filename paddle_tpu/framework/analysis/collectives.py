@@ -10,12 +10,12 @@ collective — were enforced only by example-specific tests.  These passes
 make the contracts whole-program facts: they walk ``shard_map``/``pjit``
 regions of a traced jaxpr and re-run the replication analysis the repo
 deliberately disables at trace time (every manual region goes through
-``mesh.shard_map_compat`` with ``check_vma/check_rep=False``) as
+``mesh.shard_map_compat`` with ``check_vma=False``) as
 *diagnostics* instead of trace errors.
 
-The core is a mapped-axis **varying set** per value (the vma/check_rep
+The core is a mapped-axis **varying set** per value (the vma
 lattice): a value is *varying* over a mesh axis when replicas along that
-axis may hold different data.  Sources: inputs whose ``in_names`` shard
+axis may hold different data.  Sources: inputs whose ``in_specs`` shard
 a dim over the axis, and ``axis_index``.  Sinks: ``psum``/``pmax``/
 ``pmin`` and ``all_gather`` (no ``axis_index_groups``) clear the axis;
 ``psum_scatter``/``all_to_all``/``ppermute`` keep it (replicas still
@@ -25,7 +25,7 @@ Shipped passes (stable IDs, see diagnostics.RULES):
 
 ========  ==============================================================
 PTA501    unreduced value on a mapped axis: a shard_map output whose
-          ``out_names`` claim replication over an axis the value still
+          ``out_specs`` claim replication over an axis the value still
           varies on — the grad-leaf-reaches-the-optimizer-without-a-
           psum bug; replicas silently diverge (error).  A *complete
           ring* scan is recognized as a gather: a scan whose body
@@ -77,6 +77,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.extend.core import Literal
 
 from paddle_tpu.framework.analysis.diagnostics import (
     Diagnostic, Report, Severity, register_rule)
@@ -133,11 +134,11 @@ def _collective_axes(eqn) -> Tuple[str, ...]:
     return tuple(sorted(out))
 
 
-def _names_axes(names) -> frozenset:
-    """Axis set of one shard_map in_names/out_names entry
-    (``{dim: (axes...)}`` → the union of all named axes)."""
+def _spec_axes(spec) -> frozenset:
+    """Axis set of one shard_map in_specs/out_specs entry (a
+    ``PartitionSpec``: per dim None, an axis name or a tuple of them)."""
     out = set()
-    for axes in (names or {}).values():
+    for axes in spec or ():
         if isinstance(axes, (tuple, list)):
             out.update(a for a in axes if isinstance(a, str))
         elif isinstance(axes, str):
@@ -180,8 +181,7 @@ class _Ctx:
 
 
 def _vary(env, v) -> frozenset:
-    import jax
-    if isinstance(v, jax.core.Literal):
+    if isinstance(v, Literal):
         return _EMPTY
     return env.get(v, _EMPTY)
 
@@ -191,7 +191,6 @@ def _is_mean_psum(eqn, jaxpr, ctx: _Ctx) -> bool:
     product of its axis sizes — the ``pmean`` lowering, which is the
     identity on an already-replicated value (sum·k/k), not the
     multiply-by-k double reduction PTA502 warns about."""
-    import jax
     axes = _collective_axes(eqn)
     k = 1
     for a in axes:
@@ -204,7 +203,7 @@ def _is_mean_psum(eqn, jaxpr, ctx: _Ctx) -> bool:
             continue
         if consumer.invars and consumer.invars[0] in outs:
             d = consumer.invars[1]
-            if not isinstance(d, jax.core.Literal):
+            if not isinstance(d, Literal):
                 continue
             try:
                 if float(np.asarray(d.val)) == float(k):
@@ -217,7 +216,6 @@ def _is_mean_psum(eqn, jaxpr, ctx: _Ctx) -> bool:
 def _check_gather_then_slice(eqn, jaxpr, ctx: _Ctx):
     """PTA503: every consumer of this all_gather statically slices a
     single pre-gather chunk back out — chunk 0 on every device."""
-    import jax
     out = eqn.outvars[0]
     dim = int(eqn.params.get("all_gather_dimension", 0))
     size = int(eqn.params.get("axis_size", 0) or 0)
@@ -230,7 +228,7 @@ def _check_gather_then_slice(eqn, jaxpr, ctx: _Ctx):
     else:
         local = in_aval.shape[dim] if dim < len(in_aval.shape) else None
     consumers = [e for e in jaxpr.eqns
-                 if any((not isinstance(v, jax.core.Literal)) and v is out
+                 if any((not isinstance(v, Literal)) and v is out
                         for v in e.invars)]
     if not consumers:
         return
@@ -259,7 +257,6 @@ def _check_gather_then_slice(eqn, jaxpr, ctx: _Ctx):
 
 
 def _check_collective(eqn, jaxpr, env, ctx: _Ctx, pred_vary: frozenset):
-    import jax
     pname = eqn.primitive.name
     axes = _collective_axes(eqn)
     groups = eqn.params.get("axis_index_groups")
@@ -312,7 +309,7 @@ def _check_collective(eqn, jaxpr, env, ctx: _Ctx, pred_vary: frozenset):
                          "f32, or reduce in f32 and cast afterwards"))
     if pname == "psum" and axes and groups is None:
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, Literal):
                 continue
             if _vary(env, v).isdisjoint(axes) and \
                     not _is_mean_psum(eqn, jaxpr, ctx):
@@ -354,11 +351,10 @@ def _check_ring_sum(eqn, ctx: _Ctx):
     received chunk to f32 first (``parallel/ring.py``); adding raw
     encodings accumulates garbage (int8) or half-precision error
     (bf16/f16) on every hop."""
-    import jax
     if id(eqn) in ctx.flagged_ring_sum:
         return                        # scan fixpoint re-walks the body
     for v in eqn.invars:
-        if isinstance(v, jax.core.Literal) or v not in ctx.ppermute_outs:
+        if isinstance(v, Literal) or v not in ctx.ppermute_outs:
             continue
         dt = ctx.ppermute_outs[v]
         if dt in (np.dtype(np.int8), np.dtype(np.uint8)):
@@ -439,7 +435,6 @@ def _bind(env, ctx, outer_vars, inner_vars):
     """Map call-like eqn invars onto body invars.  Aligned from the END
     when lengths differ (leading const conventions); unmatched body
     invars conservatively inherit the union of every operand."""
-    import jax
     n_in, n_body = len(outer_vars), len(inner_vars)
     union = _EMPTY
     for v in outer_vars:
@@ -450,7 +445,7 @@ def _bind(env, ctx, outer_vars, inner_vars):
         if 0 <= i < n_in:
             ov = outer_vars[i]
             env[bv] = _vary(env, ov)
-            if not isinstance(ov, jax.core.Literal) and ov in ctx.donated:
+            if not isinstance(ov, Literal) and ov in ctx.donated:
                 ctx.donated[bv] = ctx.donated[ov]
         else:
             env[bv] = union
@@ -459,7 +454,6 @@ def _bind(env, ctx, outer_vars, inner_vars):
 def _walk(jaxpr, env, ctx: _Ctx, pred_vary: frozenset):
     """One pass over ``jaxpr``'s eqns, propagating varying sets and
     emitting diagnostics.  Recurses into every nested region."""
-    import jax
     for eqn in jaxpr.eqns:
         pname = eqn.primitive.name
         union = _EMPTY
@@ -515,22 +509,19 @@ def _walk(jaxpr, env, ctx: _Ctx, pred_vary: frozenset):
 
 
 def _walk_shard_map(eqn, env, ctx: _Ctx):
-    import jax
     p = eqn.params
     mesh = p.get("mesh")
-    axis_names = tuple(getattr(mesh, "axis_names", ()) or ())
-    auto = p.get("auto") or frozenset()
-    manual = frozenset(a for a in axis_names if a not in auto)
+    manual = frozenset(p.get("manual_axes") or ())
     body = getattr(p.get("jaxpr"), "jaxpr", p.get("jaxpr"))
     if body is None or not hasattr(body, "eqns"):
         return
-    in_names = p.get("in_names") or ()
-    out_names = p.get("out_names") or ()
+    in_specs = p.get("in_specs") or ()
+    out_specs = p.get("out_specs") or ()
     for i, bv in enumerate(body.invars):
-        names = in_names[i] if i < len(in_names) else {}
-        env[bv] = _names_axes(names) & manual
+        env[bv] = _spec_axes(in_specs[i] if i < len(in_specs)
+                             else None) & manual
         ov = eqn.invars[i] if i < len(eqn.invars) else None
-        if ov is not None and not isinstance(ov, jax.core.Literal) \
+        if ov is not None and not isinstance(ov, Literal) \
                 and ov in ctx.donated:
             ctx.donated[bv] = ctx.donated[ov]
     saved = (ctx.manual, ctx.sizes, ctx.seen_manual)
@@ -544,8 +535,8 @@ def _walk_shard_map(eqn, env, ctx: _Ctx):
     try:
         _walk(body, env, ctx, _EMPTY)
         for j, bov in enumerate(body.outvars):
-            claimed = _names_axes(out_names[j] if j < len(out_names)
-                                  else {})
+            claimed = _spec_axes(out_specs[j] if j < len(out_specs)
+                                 else None)
             leak = _vary(env, bov) - claimed
             if leak:
                 outer = eqn.outvars[j] if j < len(eqn.outvars) else None
@@ -679,7 +670,6 @@ def run_collective_passes(closed_jaxpr, name: str, report: Report,
     collective eqns produces no diagnostics — the passes are free for
     ordinary jit programs, which is what lets ``analyze_jaxpr`` run them
     unconditionally."""
-    import jax
     jaxpr = closed_jaxpr.jaxpr
     ctx = _Ctx(report, name)
     if donate_argnums:
@@ -692,10 +682,10 @@ def run_collective_passes(closed_jaxpr, name: str, report: Report,
                                   label)
     ctx.out_avals = [_aval_key(getattr(o, "aval", None))
                      for o in jaxpr.outvars
-                     if not isinstance(o, jax.core.Literal)]
+                     if not isinstance(o, Literal)]
     if outvar_labels:
         for o, lbl in zip(jaxpr.outvars, outvar_labels):
-            if not isinstance(o, jax.core.Literal):
+            if not isinstance(o, Literal):
                 ctx.out_labels[o] = lbl
     env: Dict[object, frozenset] = {}
     _walk(jaxpr, env, ctx, _EMPTY)

@@ -44,6 +44,7 @@ import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.extend.core import Literal
 
 from paddle_tpu.framework.analysis.collectives import (
     COLLECTIVE_PRIMS, run_collective_passes)
@@ -69,8 +70,8 @@ _LARGE_CONST_ELEMS = 4096
 # donation hint (below it the saved HBM is noise)
 _DONATION_HINT_BYTES = 1 << 20
 
-_CALLBACK_PRIMS = {"debug_callback", "io_callback", "pure_callback",
-                   "callback", "outside_call", "host_callback_call"}
+_CALLBACK_PRIMS = {"debug_callback", "debug_print", "io_callback",
+                   "pure_callback"}
 
 # eqn.params values holding nested jaxprs, by primitive
 _SUBJAXPR_KEYS = ("jaxpr", "call_jaxpr", "cond_jaxpr", "body_jaxpr",
@@ -98,7 +99,7 @@ def _aval(v):
     import jax
     if hasattr(v, "aval"):
         return v.aval
-    return jax.core.get_aval(v.val if hasattr(v, "val") else v)
+    return jax.typeof(v.val if hasattr(v, "val") else v)
 
 
 def _nbytes(aval) -> int:
@@ -226,17 +227,16 @@ def _pass_dtype(jaxpr, consts, name, report: Report):
 
 
 def _pass_dead_code(jaxpr, name, invar_labels, report: Report):
-    import jax
     live = {v for v in jaxpr.outvars
-            if not isinstance(v, jax.core.Literal)}
+            if not isinstance(v, Literal)}
     for eqn in reversed(jaxpr.eqns):
         out_live = any(o in live for o in eqn.outvars)
         if out_live or eqn.effects:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     live.add(v)
         elif eqn.primitive.name in ("broadcast_in_dim", "iota") and \
-                all(isinstance(v, jax.core.Literal) for v in eqn.invars):
+                all(isinstance(v, Literal) for v in eqn.invars):
             # a dead LITERAL materialization is free: jax's own vjp
             # rules leave these behind (e.g. relu's custom_jvp zeros)
             # and XLA constant-folds them — flagging would teach users
@@ -373,7 +373,7 @@ def _pass_consts(jaxpr, consts, name, report: Report):
                      "jit._GeneratorKeyGuard: keys are traced inputs)"))
             continue
         try:
-            weak = jax.core.get_aval(c).weak_type
+            weak = jax.typeof(c).weak_type
         except Exception:              # noqa: BLE001
             weak = False
         if weak and elems == 1:

@@ -734,13 +734,9 @@ class MemoryTracker:
             self.tags[tag] = int(nbytes)
         monitor.stat_set(f"device_mem_{tag}_bytes", int(nbytes))
 
-    def profile(self, path: str) -> Optional[str]:
-        """Write jax's pprof device-memory profile to ``path`` (None
-        when the installed jax has no ``device_memory_profile``)."""
-        try:
-            from jax.profiler import device_memory_profile
-        except ImportError:
-            return None
+    def profile(self, path: str) -> str:
+        """Write jax's pprof device-memory profile to ``path``."""
+        from jax.profiler import device_memory_profile
         blob = device_memory_profile()
         with open(path, "wb") as f:
             f.write(blob)

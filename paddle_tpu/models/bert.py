@@ -175,13 +175,10 @@ def _bert_forward(cfg, has_tt, has_mask, wte, wpe, wtt, emb_ln_w, emb_ln_b,
     def _flash_ok(b, s):
         if not cfg.use_flash_attention:
             return False
-        try:
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-            return _fa.supported(
-                (b, s, nh, hd), (b, s, nh, hd), bias is None,
-                bias_shape=None if bias is None else tuple(bias.shape))
-        except Exception:
-            return False
+        from paddle_tpu.ops.pallas import flash_attention as _fa
+        return _fa.supported(
+            (b, s, nh, hd), (b, s, nh, hd), bias is None,
+            bias_shape=None if bias is None else tuple(bias.shape))
 
     def layer(x, lp):
         b, s = x.shape[:2]

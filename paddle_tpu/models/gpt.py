@@ -17,7 +17,8 @@ TPU-native design decisions:
   here just NamedShardings consumed by ShardedTrainStep.
 - **Attention**: Pallas flash kernel on TPU (paddle_tpu/ops/pallas),
   ring attention over the ``sp`` axis for long context (capability the
-  reference lacks, SURVEY.md §5.7), XLA softmax fallback elsewhere.
+  reference lacks, SURVEY.md §5.7), XLA softmax for shapes and backends
+  the kernel's ``supported()`` gate rejects.
 - Logits tied to the (mp-sharded) token embedding.
 """
 from __future__ import annotations
@@ -182,13 +183,14 @@ def _attention(cfg: GPTConfig, q, k, v, manual_sp=False):
         from paddle_tpu.parallel.ring_attention import ring_attention
         return ring_attention(q, k, v, causal=True, scale=scale, mesh=mesh)
     if cfg.use_flash_attention:
-        try:
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-            if _fa.supported(tuple(q.shape), tuple(k.shape), True,
-                             causal=True):
-                return _fa.flash_attention(q, k, v, causal=True, scale=scale)
-        except Exception:
-            pass
+        # supported() decides from shapes and backend; what it accepts
+        # runs in the kernel or raises — never quietly on the XLA path
+        from paddle_tpu.ops.pallas import flash_attention as _fa
+        from paddle_tpu.parallel.mesh import per_device
+        if _fa.supported(tuple(q.shape), tuple(k.shape), True, causal=True):
+            kernel = partial(_fa.flash_attention, causal=True, scale=scale)
+            return per_device(kernel, mesh, (("dp", "sharding"), None,
+                                             "mp", None))(q, k, v)
     from paddle_tpu.nn.functional.attention import _xla_attention
     return _xla_attention(q, k, v, None, scale, True)
 

@@ -5,7 +5,7 @@ The reference ships only full-materialised attention
 inference kernels (operators/fused/multihead_matmul_op.cu).  The TPU-native
 replacement is a Pallas flash-attention kernel (paddle_tpu/ops/pallas/
 flash_attention.py) — blockwise online-softmax so the S×S score matrix never
-hits HBM — with a pure-XLA fallback for CPU tests and odd shapes.
+hits HBM — with a pure-XLA path for CPU tests and shapes its gate rejects.
 """
 from __future__ import annotations
 
@@ -50,24 +50,19 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     broadcastable to (B, H, Sq, Sk) rides the kernel as a tile-streamed
     bias (the reference's fused attention takes the same bias_qk input,
     multihead_matmul_op.cu), so padded-batch workloads stay O(S·D).
-    Falls back to the XLA path (still fused reasonably well by XLA, but
-    materialises scores) for unsupported shapes/backends."""
+    Shapes and backends the kernel's ``supported()`` gate rejects take the
+    XLA path (still fused reasonably well by XLA, but materialises
+    scores); what the gate accepts runs in the kernel or raises."""
     d = query.shape[-1]
     scale = 1.0 / math.sqrt(d)
 
-    use_flash = False
-    try:
-        from paddle_tpu.ops.pallas import flash_attention as _fa
-        use_flash = _fa.supported(
-            tuple(query.shape), tuple(key.shape), attn_mask is None,
-            causal=is_causal,
-            bias_shape=None if attn_mask is None else tuple(attn_mask.shape))
-    except Exception:
-        use_flash = False
+    from paddle_tpu.ops.pallas import flash_attention as _fa
+    use_flash = _fa.supported(
+        tuple(query.shape), tuple(key.shape), attn_mask is None,
+        causal=is_causal,
+        bias_shape=None if attn_mask is None else tuple(attn_mask.shape))
 
     if use_flash:
-        from paddle_tpu.ops.pallas import flash_attention as _fa
-
         if attn_mask is not None:
             # padding masks are feed data: bias_grad=False skips the dbias
             # kernel and nondiff keeps them off the eager tape.  A LEARNED
@@ -119,18 +114,13 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
     if q_segment_ids is not None:
         d = query.shape[-1]
         scale = 1.0 / math.sqrt(d)
-        try:
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-            ok = _fa.supported(
-                tuple(query.shape), tuple(key.shape), attn_mask is None,
-                causal=causal, segments=True,
-                bias_shape=None if attn_mask is None
-                else tuple(attn_mask.shape))
-        except Exception:
-            ok = False
+        from paddle_tpu.ops.pallas import flash_attention as _fa
+        ok = _fa.supported(
+            tuple(query.shape), tuple(key.shape), attn_mask is None,
+            causal=causal, segments=True,
+            bias_shape=None if attn_mask is None
+            else tuple(attn_mask.shape))
         if ok:
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-
             def _run(q, k, v, qs, ks, *m):
                 return _fa.flash_attention(
                     q, k, v, causal=causal, scale=scale,

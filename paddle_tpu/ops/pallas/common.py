@@ -5,18 +5,20 @@ import jax
 import jax.numpy as jnp
 
 
+def backend_is_tpu() -> bool:
+    """The one backend gate behind every kernel module's ``supported()``:
+    Mosaic lowers for the TPU only (tests flip the module's ``_INTERPRET``
+    instead)."""
+    return jax.default_backend() == "tpu"
+
+
 def no_x64():
     """Context manager forcing 32-bit trace semantics for a kernel call.
 
     The package enables jax_enable_x64 globally (paddle parity), but
-    Pallas TPU kernels are written for 32-bit refs; ``jax.enable_x64``
-    was removed upstream, so route through the experimental manager.
-    """
-    try:
-        from jax.experimental import disable_x64
-        return disable_x64()
-    except ImportError:
-        return jax.enable_x64(False)
+    Mosaic rejects 64-bit types — index maps and iotas traced under x64
+    would emit i64."""
+    return jax.enable_x64(False)
 
 
 def dot_nt(a, b):

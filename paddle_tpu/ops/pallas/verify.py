@@ -41,7 +41,17 @@ from paddle_tpu.framework import chaos, monitor
 from paddle_tpu.framework.flags import flag
 
 __all__ = ["armed", "verify_call", "interpreted", "boundary_corpus",
-           "check_flash_candidate", "VerifyResult"]
+           "check_flash_candidate", "VerifyResult", "SCALE_TOL",
+           "max_errors"]
+
+#: a bf16 kernel against a float32 reference: the max abs error allowed
+#: per output, as a share of the reference's largest value (about five
+#: bf16 ulps at the top of the range).  Two bf16 pipelines round in
+#: different places, and elementwise closeness is too strict for
+#: gradients that sum thousands of terms — measured on the chip, XLA's own
+#: bf16 attention sits 2-5x further from the float32 result than the
+#: flash kernel does.
+SCALE_TOL = 2e-2
 
 monitor.describe("pallas_verify_checks_total",
                  "differential-oracle checks completed (armed only)")
@@ -98,6 +108,21 @@ def _leaves(out) -> List[Any]:
     import jax
     return [x for x in jax.tree_util.tree_leaves(out)
             if hasattr(x, "shape")]
+
+
+def max_errors(got, want) -> List[Tuple[float, float]]:
+    """Per output leaf: (max abs error, the reference's largest abs
+    value) — what the on-chip checks (``chip_smoke.py``,
+    ``tools/kernel_check.py``) hold against :data:`SCALE_TOL`."""
+    out = []
+    for g, w in zip(_leaves(got), _leaves(want)):
+        g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
+        if g.shape != w.shape:
+            raise ValueError(f"output shape {g.shape} vs reference "
+                             f"{w.shape}")
+        out.append((float(np.max(np.abs(g - w), initial=0.0)),
+                    float(np.max(np.abs(w), initial=0.0))))
+    return out
 
 
 def _labels_for(name: str, run_kernel, args, n_out: int,
@@ -235,16 +260,24 @@ def check_flash_candidate(block_q, block_k, *, d=64, dtype="bfloat16",
     :func:`verify_call` — compiled vs interpret vs the XLA reference —
     with the candidate blocks forced.  Returns [] when every case
     agrees, else one ``{"sq", "sk", "dtype", "operand"}`` dict per
-    divergent case.  Corpus cases the dispatcher would not send to the
-    kernel anyway (masked non-divisible shapes, causal sq>sk) are
-    skipped, not failed.
+    failed case: a divergence names its operand, and a case the oracle
+    could not finish (:func:`verify_call` returned None — e.g. Mosaic
+    refused the compiled leg) fails as ``<name>.oracle_fault``, because a
+    tiling that did not run is not a tiling that was verified.  Corpus
+    cases the dispatcher would not send to the kernel anyway (masked
+    non-divisible shapes, causal sq>sk) are skipped, not failed.
+    Requires ``FLAGS_pallas_verify``: disarmed, the oracle runs nothing.
     """
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas import autotune
     from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas.common import backend_is_tpu
 
+    if not armed():
+        raise RuntimeError("check_flash_candidate needs FLAGS_pallas_verify "
+                           "armed: the disarmed oracle checks nothing")
     failures = []
     for case in boundary_corpus(block_q, block_k, d):
         sq, sk, cd = case["sq"], case["sk"], case["d"]
@@ -286,13 +319,17 @@ def check_flash_candidate(block_q, block_k, *, d=64, dtype="bfloat16",
         name = f"flash[{block_q}x{block_k}]"
         labels = [f"{name}.out"] if not grads else \
             [f"{name}.{x}" for x in ("loss", "dq", "dk", "dv")]
-        loose = case["dtype"] == "bfloat16"
+        # on the TPU, XLA multiplies f32 in bf16 passes by default (the
+        # interpret and reference legs) while Mosaic does not, so f32
+        # agrees no tighter than bf16 there
+        loose = case["dtype"] == "bfloat16" or backend_is_tpu()
         res = verify_call(name, run_kernel, run_reference, (q, k, v),
                           interpret_modules=(fa,), out_labels=labels,
-                          skip_compiled=not fa._backend_is_tpu(),
+                          skip_compiled=not backend_is_tpu(),
                           rtol=5e-2 if loose else 5e-3,
                           atol=5e-2 if loose else 5e-4)
-        if res is not None and res.divergent:
+        if res is None or res.divergent:
             failures.append({"sq": sq, "sk": sk, "dtype": case["dtype"],
-                             "operand": res.operand})
+                             "operand": res.operand if res is not None
+                             else f"{name}.oracle_fault"})
     return failures

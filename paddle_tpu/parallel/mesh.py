@@ -29,21 +29,13 @@ __all__ = ["make_mesh", "get_mesh", "set_mesh", "auto_mesh", "mesh_axis_size",
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across the jax versions this repo meets: new jax
-    exposes ``jax.shard_map`` (replication check knob ``check_vma``),
-    older releases only ``jax.experimental.shard_map.shard_map``
-    (``check_rep``).  Every fully-manual region in the repo goes through
-    here so the version fork lives in ONE place."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:        # pre-check_vma spelling of the knob
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """Fully-manual ``jax.shard_map`` with the replication check off
+    (``check_vma=False``): the bodies here mix replicated and varying
+    values on purpose, and ``framework/analysis/collectives.py`` re-runs
+    that analysis as diagnostics instead of trace errors."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 _global_mesh: Optional[Mesh] = None
 
@@ -139,13 +131,11 @@ _MANUAL_REGION = threading.local()
 @contextlib.contextmanager
 def manual_region():
     """Mark the dynamic extent of a fully-manual ``shard_map`` trace:
-    :func:`constrain` becomes a no-op inside it.  Newer jax raises a
-    recognizable "manual" error that constrain already swallows, but on
-    older releases a with_sharding_constraint staged inside a manual
-    region traces against the GLOBAL mesh and only fails at run time
-    with a device mismatch — the explicitly-collective train steps
-    (``parallel/zero.py``, ``dp_meta``) wrap their dispatch in this so
-    model-internal activation constraints (e.g. GPT's) are skipped."""
+    :func:`constrain` becomes a no-op inside it.  The explicitly-
+    collective train steps (``parallel/zero.py``, ``dp_meta``) wrap their
+    dispatch in this so model-internal activation constraints (e.g.
+    GPT's) are skipped without staging a with_sharding_constraint that
+    would only be rejected."""
     prev = getattr(_MANUAL_REGION, "depth", 0)
     _MANUAL_REGION.depth = prev + 1
     try:
@@ -181,6 +171,23 @@ def constrain(arr, *axes, strip=()):
         if "manual" in str(e).lower():
             return arr
         raise
+
+
+def per_device(fn, mesh: Mesh, axes):
+    """Run ``fn`` — a Pallas kernel over arrays that share one layout —
+    on each device's shard.
+
+    GSPMD cannot partition a Mosaic custom call (lowering one under a
+    mesh of several devices raises "wrap the call in a shard_map"), so
+    there the kernel runs in a fully-manual region, ``axes`` naming the
+    mesh axes of each array dim (mesh-tolerant, as in :func:`constrain`;
+    the dims must divide).  On one device, or inside an enclosing manual
+    region (the pipeline trunk, the explicitly-collective steps), ``fn``
+    is returned as it is."""
+    if mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+        return fn
+    spec = _clean_axes(axes, mesh)
+    return shard_map_compat(fn, mesh, in_specs=spec, out_specs=spec)
 
 
 class DistAttr:

@@ -5,7 +5,7 @@ The static passes (``framework/analysis/collectives.py``) prove a traced
 step cannot *claim* replication it did not earn; this module checks the
 claim against what actually sits in device memory.  Every manual region
 in the repo runs with jax's replication checking disabled
-(``mesh.shard_map_compat``: ``check_vma/check_rep=False``), so a missing
+(``mesh.shard_map_compat``: ``check_vma=False``), so a missing
 ``psum`` produces a global array whose per-device buffers silently
 differ while its sharding says "replicated" — the PTA501 bug class at
 runtime.  With ``FLAGS_replica_parity`` armed, the train-step classes

@@ -40,18 +40,11 @@ def _shard_map(f, mesh, in_specs, out_specs, manual_axes=None):
     """shard_map with optional partial-manual mode: axes in ``manual_axes``
     are mapped explicitly, the rest stay 'auto' so GSPMD keeps partitioning
     them inside the body (tensor parallelism composes under the pipeline)."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if manual_axes is not None:
-            kwargs["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map
     kwargs = {}
     if manual_axes is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(manual_axes)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, **kwargs)
+        kwargs["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def _psum(x, axis):

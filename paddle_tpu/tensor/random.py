@@ -34,8 +34,21 @@ class Generator:
 
     def manual_seed(self, seed: int):
         self._seed = int(seed)
-        self._key = jax.random.PRNGKey(int(seed))
+        # built on first use: creating a key starts the backend, and
+        # importing the package must not (a launcher or a host-only
+        # child that imports it would take the chip from the trainer)
+        self._key_arr = None
         return self
+
+    @property
+    def _key(self):
+        if self._key_arr is None:
+            self._key_arr = jax.random.PRNGKey(self._seed)
+        return self._key_arr
+
+    @_key.setter
+    def _key(self, key):
+        self._key_arr = key
 
     def initial_seed(self) -> int:
         return self._seed

@@ -4,8 +4,8 @@ functional.py).
 Numpy-first AND host-side: images are HWC uint8/float arrays (CHW
 float32 after ToTensor) and STAY numpy through the whole per-sample
 pipeline — a per-sample device tensor costs one host->device transfer
-per IMAGE (measured 1.5 img/s vs 22 img/s at batch granularity,
-perf/filefed_analysis.md), so the device conversion belongs to the
+per IMAGE (1.5 img/s vs 22 img/s at batch granularity when last
+measured, before PR 1), so the device conversion belongs to the
 loader's collate / the ingest pipeline's transfer stage, at batch
 granularity.  ``to_tensor``/``ToTensor`` therefore return a host
 ndarray by default (``out="tensor"`` restores the reference's

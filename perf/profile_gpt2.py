@@ -1,5 +1,6 @@
 import numpy as np
 import paddle_tpu as paddle
+paddle.device.use_compile_cache()
 from paddle_tpu import optimizer
 from paddle_tpu.jit import TrainStep
 from paddle_tpu.models import GPT, gpt2_345m, gpt_loss

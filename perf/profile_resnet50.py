@@ -1,5 +1,6 @@
 import numpy as np
 import paddle_tpu as paddle
+paddle.device.use_compile_cache()
 import paddle_tpu.nn.functional as F
 from paddle_tpu import optimizer
 from paddle_tpu.jit import TrainStep

@@ -2,6 +2,7 @@
 import gc, time
 import numpy as np
 import paddle_tpu as paddle
+paddle.device.use_compile_cache()
 import paddle_tpu.nn.functional as F
 from paddle_tpu import optimizer
 from paddle_tpu.distributed.ps import DistributedEmbedding, PSTrainStep

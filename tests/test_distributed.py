@@ -444,6 +444,7 @@ def test_pipeline_forward_matches_sequential():
                                atol=1e-5)
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_pipeline_forward_differentiable():
     mesh = make_mesh({"pp": 2})
     set_mesh(mesh)
@@ -510,6 +511,7 @@ def test_ring_attention_matches_local(causal):
                                atol=1e-5)
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_ring_attention_grad():
     mesh = make_mesh({"sp": 2})
     set_mesh(mesh)

@@ -360,6 +360,46 @@ class TestElasticAgent:
         assert _has(agent.events, "restarted", "w0")
         assert runs["n"] == 2 and not agent.failed()
 
+    def test_replacement_waits_until_killed_child_is_gone(self):
+        """One process per chip: a killed child that has not been reaped
+        yet still holds the chip, so the agent must not start its
+        replacement beside it — it kills again and looks next pass."""
+        from paddle_tpu.distributed.elastic import WorkerHandle
+
+        class Lingering(WorkerHandle):
+            name = "w0"
+            kills = restarts = 0
+            lingers = 2               # outlives this many kill() calls
+
+            def alive(self):
+                return self.kills <= self.lingers and self.restarts == 0
+
+            def exit_code(self):
+                return None if self.alive() else -9
+
+            def kill(self, grace=0.0):
+                self.kills += 1
+
+            def restart(self):
+                assert not self.alive(), "replacement raced the old child"
+                self.restarts += 1
+
+        store = DictStore(ttl=60.0)
+        store.register("w0")
+        store.beat("w0", 0)
+        h = Lingering()
+        now = [0.0]
+        agent = ElasticAgent(store, [h], hang_deadline=0.0,
+                             elastic_retries=1, restart_backoff=0.0,
+                             clock=lambda: now[0])
+        for _ in range(6):
+            now[0] += 1.0
+            agent.poll_once()
+            if h.restarts:
+                break
+        assert _has(agent.events, "hang_killed", "w0")
+        assert h.restarts == 1 and h.kills == h.lingers + 1
+
     def test_out_of_budget_worker_shrinks_not_kills(self):
         store = DictStore(ttl=60.0)
 

@@ -123,7 +123,9 @@ def test_fused_adam_matches_reference():
                                    rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("cls", ["Adam", "AdamW"])
+@pytest.mark.parametrize("cls", [
+    pytest.param("Adam", marks=pytest.mark.slow),    # 12-16 s each: AdamW,
+    "AdamW"])                                        # the superset, stays
 def test_optimizer_use_fused_converges_like_unfused(cls):
     from paddle_tpu import optimizer
 
@@ -152,7 +154,8 @@ def test_optimizer_use_fused_converges_like_unfused(cls):
 
 # -- non-divisible / zero-length token axis ---------------------------------
 
-@pytest.mark.parametrize("n", [300, 257, 1])
+@pytest.mark.parametrize("n", [
+    pytest.param(300, marks=pytest.mark.slow), 257, 1])
 def test_ce_non_divisible_tokens_match_xla(n):
     """N that doesn't divide the block rides zero-padded rows (the
     PTA601 fix) — loss and both grads pinned against the reference."""

@@ -10,11 +10,12 @@ hybrid-parallel topologies in per-topology unit tests
 (test_parallel_dygraph_pipeline_parallel.py et al.), not only in CI's
 largest configuration.
 """
+import os
 import sys
 
 import pytest
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import __graft_entry__ as graft_entry  # noqa: E402
 
@@ -36,8 +37,8 @@ def test_factor_axes_branches():
                "precision fix")),
 ])
 def test_dryrun_small_topologies(n):
-    # conftest forces an 8-virtual-device CPU platform, so these run
-    # in-process on the first n devices (no re-exec subprocess).
+    # dryrun_multichip runs in this process on the first n of conftest's
+    # eight virtual CPU devices, as it runs on the first n chips of a host
     graft_entry.dryrun_multichip(n)
 
 

@@ -560,6 +560,8 @@ class TestBenchMeta:
         bench._META = None
         monkeypatch.setattr(bench, "_ARTIFACT",
                             str(tmp_path / "art.json"))
+        monkeypatch.setattr(bench, "_LEDGER",
+                            str(tmp_path / "ledger.jsonl"))
         monkeypatch.setattr(bench, "_RECORDS", [])
         bench._emit("m", 1.0, "u", 1.0)
         art = json.loads((tmp_path / "art.json").read_text())

@@ -344,6 +344,7 @@ class TestCache:
 
 
 class TestWorkerFaults:
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_worker_killed_mid_epoch_raises_clean(self):
         dl = DataLoader(_SlowDataset(), batch_size=4, num_workers=2,
                         use_process_workers=True)
@@ -430,6 +431,7 @@ class TestObservability:
         assert "ingest_cache_hits_total" in text
         assert "ingest_cache_misses_total" in text
 
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_worker_cache_counters_reach_parent_export(self, tmp_path):
         # hits/misses happen inside the WORKER processes; the per-batch
         # stat_deltas shipped with the collated batch must fold them

@@ -8,10 +8,11 @@ LAUNCH = [sys.executable, "-m", "paddle_tpu.distributed.launch"]
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(args, cwd):
+def _run(args, cwd, drop_env=()):
+    env = {k: v for k, v in os.environ.items() if k not in drop_env}
     return subprocess.run(LAUNCH + args, cwd=cwd, capture_output=True,
                           text=True, timeout=120,
-                          env=dict(os.environ, PYTHONPATH=_REPO))
+                          env=dict(env, PYTHONPATH=_REPO))
 
 
 def test_collective_env_and_logs(tmp_path):
@@ -73,3 +74,32 @@ def test_ps_failure_kills_job(tmp_path):
               "--log_dir", str(tmp_path / "log"), str(script)],
              cwd=str(tmp_path))
     assert r.returncode == 5         # supervisor killed the server too
+
+
+def test_ps_servers_are_pinned_to_the_host_platform(tmp_path):
+    # one process per chip: a server that imports jax must not open the
+    # accelerator its trainer needs
+    script = tmp_path / "plat.py"
+    script.write_text(
+        "import os\n"
+        "print(os.environ['TRAINING_ROLE'],\n"
+        "      'JAX_PLATFORMS=' + os.environ.get('JAX_PLATFORMS', '<unset>'))\n")
+    r = _run(["--server_num", "1", "--worker_num", "1",
+              "--log_dir", str(tmp_path / "log"), str(script)],
+             cwd=str(tmp_path), drop_env=("JAX_PLATFORMS",))
+    assert r.returncode == 0, r.stderr
+    assert "PSERVER JAX_PLATFORMS=cpu" in \
+        (tmp_path / "log" / "serverlog.0").read_text()
+    assert "TRAINER JAX_PLATFORMS=<unset>" in \
+        (tmp_path / "log" / "workerlog.0").read_text()
+
+
+def test_several_trainers_on_one_host_are_refused_off_cpu(tmp_path):
+    script = tmp_path / "noop.py"
+    script.write_text("print('ran')\n")
+    r = _run(["--server_num", "1", "--worker_num", "2",
+              "--log_dir", str(tmp_path / "log"), str(script)],
+             cwd=str(tmp_path), drop_env=("JAX_PLATFORMS",))
+    assert r.returncode == 2
+    assert "racing for the same chip" in r.stderr
+    assert not (tmp_path / "log").exists()     # nothing was started

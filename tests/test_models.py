@@ -49,6 +49,42 @@ def test_gpt_trains_eager_backward():
     assert g is not None and np.isfinite(g.numpy()).all()
 
 
+def test_gpt_flash_kernel_runs_per_device_under_a_mesh(monkeypatch):
+    """GSPMD cannot partition a Mosaic custom call, so under dp > 1 the
+    flash kernel runs in a manual region on each device's batch shard —
+    and trains like one-device XLA attention."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    local_batches = []
+    kernel = fa.flash_attention
+
+    def spy(q, *a, **kw):
+        local_batches.append(q.shape[0])
+        return kernel(q, *a, **kw)
+
+    monkeypatch.setattr(fa, "flash_attention", spy)
+    kw = dict(hidden_size=128, num_heads=2, num_layers=1, max_seq_len=128,
+              remat=False)                     # head_dim 64: kernel shape
+    ids = _batch(256, B=2, S=128)
+
+    def losses(mesh_axes, flash):
+        mesh = make_mesh(mesh_axes)
+        set_mesh(mesh)
+        model = GPT(gpt_tiny(use_flash_attention=flash, **kw))
+        opt = optimizer.Adam(learning_rate=1e-3,
+                             parameters=model.parameters())
+        step = ShardedTrainStep(model, gpt_loss, opt, mesh=mesh,
+                                sharding_stage=1)
+        x = paddle.to_tensor(ids)
+        return [float(step(x, x)) for _ in range(2)]
+
+    want = losses({"dp": 1}, flash=False)
+    assert not local_batches
+    got = losses({"dp": 2}, flash=True)
+    assert local_batches and set(local_batches) == {1}   # 2 rows / dp=2
+    np.testing.assert_allclose(got, want, rtol=2e-3)
+
+
 def _train_losses(mesh_axes, steps=3, sharding_stage=0, n_micro=1,
                   seed=0, remat=False):
     mesh = make_mesh(mesh_axes)
@@ -77,12 +113,14 @@ def test_gpt_mesh_layouts_loss_parity():
     assert base[-1] < base[0]
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_gpt_sp_ring_attention_parity():
     base = _train_losses({"dp": 8})
     sp = _train_losses({"dp": 2, "sp": 4})
     np.testing.assert_allclose(base, sp, rtol=5e-3)
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_gpt_remat_parity():
     base = _train_losses({"dp": 8}, remat=False)
     remat = _train_losses({"dp": 8}, remat=True)

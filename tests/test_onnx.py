@@ -101,6 +101,7 @@ def test_lenet(tmp_path):
     assert stats["nodes"] > 10
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_resnet18(tmp_path):
     from paddle_tpu.vision.models import resnet18
     x = rng.standard_normal((1, 3, 32, 32)).astype(np.float32)

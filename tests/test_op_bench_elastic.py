@@ -5,11 +5,14 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 
 class TestOpBench:
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_run_one_and_gate(self, tmp_path):
         import op_bench
         cfg = [{"name": "small_matmul", "op": "paddle_tpu.matmul",

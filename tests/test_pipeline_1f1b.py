@@ -153,6 +153,7 @@ class Test1F1BNumerics:
         np.testing.assert_allclose(np.asarray(de), np.asarray(de_ref),
                                    rtol=2e-4, atol=1e-6)
 
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_loss_parity_vs_fthenb_pipeline(self):
         """Same trunk through schedule_mode 0 (pipeline_forward + autodiff)
         and schedule_mode 1 (1F1B) must agree in loss and grads."""

@@ -56,6 +56,7 @@ def _head_loss(hp, y, lab):
 
 
 class TestMasked1F1BWithRing:
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_exact_parity_pp_sp(self):
         rng = np.random.default_rng(0)
         wq = jnp.asarray(rng.standard_normal((L, D, D)).astype(np.float32)

@@ -1,6 +1,7 @@
 """Quantization tier (fluid/contrib/slim/quantization roles): fake-quant
 ops + STE gradients, QAT module swap + training, PTQ weight packing."""
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -65,6 +66,7 @@ class TestQAT:
         assert isinstance(net.fc2, QuantizedLinear)
         assert isinstance(net.inner[0], QuantizedLinear)
 
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_qat_trains(self):
         paddle.seed(0)
         net = ImperativeQuantAware().quantize(
@@ -181,6 +183,7 @@ def test_int8_conv2d_execution_parity():
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_int8_conv_deploy_pass_on_resnet18():
     """convert_to_int8_inference over the vision zoo: every Conv2D and
     Linear swapped, predictions stay aligned with the float model."""

@@ -199,9 +199,12 @@ class TestRingPrimitives:
         np.testing.assert_array_equal(np.asarray(s_r), np.asarray(s_n))
         np.testing.assert_array_equal(np.asarray(g_r), np.asarray(g_n))
 
+    # the int4 cases are the heaviest of the tier-1 run (15-25 s each, the
+    # nibble pack/unpack under an unrolled ring): slow, the int8 ones stay
     @pytest.mark.parametrize("dp", [2, 4])
-    @pytest.mark.parametrize("wire,qmax", [("int8", 127.0),
-                                           ("int4", 7.0)])
+    @pytest.mark.parametrize("wire,qmax", [
+        ("int8", 127.0),
+        pytest.param("int4", 7.0, marks=pytest.mark.slow)])
     def test_quantized_rs_tracks_exact_sum(self, dp, wire, qmax):
         """Each of the dp-1 hops re-encodes the f32 partial, so the
         error is at most (dp-1) half-scale steps of the largest
@@ -221,8 +224,9 @@ class TestRingPrimitives:
         bound = dp * (part_max / qmax) + 1e-6
         assert np.abs(got - want).max() <= bound
 
-    @pytest.mark.parametrize("wire,qmax", [("int8", 127.0),
-                                           ("int4", 7.0)])
+    @pytest.mark.parametrize("wire,qmax", [
+        ("int8", 127.0),
+        pytest.param("int4", 7.0, marks=pytest.mark.slow)])
     def test_quantized_ag_bitwise_across_replicas(self, wire, qmax):
         """Every replica decodes the SOURCE's single encoding: the
         gathered copies must be bit-identical across the ring, and
@@ -294,6 +298,7 @@ class TestRingTrainStep:
         for a, b in zip(lq, lf):                # and tracks the exact run
             assert abs(a - b) <= tol * max(1.0, abs(b))
 
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_ring_replicas_hold_identical_params(self):
         """Determinism across runs at dp=4 int4: only possible if all
         replicas left every step with identical parameters."""
@@ -334,6 +339,7 @@ class TestRingTrainStep:
         assert totals["int4"] <= 0.14 * totals["f32"]
         assert totals["int8"] <= 0.26 * totals["f32"]
 
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_chaos_collective_deterministic_under_ring(self):
         """The zero.collective fault point wraps the ring path too:
         an injected error is retried to a bit-identical trajectory."""

@@ -2,6 +2,7 @@
 selected_rows_functor MergeAdd + sgd_op/adam_op SelectedRows branches),
 emitted by Embedding(sparse=True) on the eager tape."""
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -134,6 +135,7 @@ class TestSparseOptimizerSteps:
                 opt.clear_grad()
         np.testing.assert_allclose(sp.numpy(), dn.numpy(), rtol=1e-5)
 
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_sparse_embedding_model_trains(self):
         paddle.seed(0)
         emb = nn.Embedding(100, 8, sparse=True)

@@ -35,6 +35,7 @@ def test_resnet18_forward():
     assert out.shape == [2, 7]
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_resnet50_forward():
     out = _fwd(models.resnet50(num_classes=5))
     assert out.shape == [2, 5]
@@ -45,6 +46,7 @@ def test_vgg11_forward():
     assert out.shape == [2, 4]
 
 
+@pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
 def test_mobilenet_forwards():
     assert _fwd(models.mobilenet_v1(num_classes=3)).shape == [2, 3]
     assert _fwd(models.mobilenet_v2(num_classes=3)).shape == [2, 3]

@@ -3,6 +3,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -10,6 +11,7 @@ from paddle_tpu.visualdl import LogWriter, VisualDL
 
 
 class TestLogWriter:
+    @pytest.mark.slow      # heavy for the 870 s tier-1 cap (PR 21): -m slow
     def test_scalar_events_written(self, tmp_path):
         d = str(tmp_path / "log")
         with LogWriter(d) as w:

@@ -275,4 +275,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

@@ -44,8 +44,8 @@ def main(argv=None) -> int:
 
     from paddle_tpu.framework.flags import set_flags
     from paddle_tpu.ops.pallas import autotune
-    from paddle_tpu.ops.pallas.flash_attention import _backend_is_tpu
-    if not _backend_is_tpu():
+    from paddle_tpu.ops.pallas.common import backend_is_tpu
+    if not backend_is_tpu():
         print("no TPU attached — autotune must run on real hardware",
               file=sys.stderr)
         return 1
@@ -82,9 +82,20 @@ def main(argv=None) -> int:
         for (bq, bk), fails in sorted(rejected.items()):
             ops = ", ".join(sorted({f["operand"] for f in fails}))
             print(f"  rejected ({bq},{bk}): {len(fails)} corpus "
-                  f"divergence(s) [{ops}]")
+                  f"failure(s) [{ops}]")
+    from paddle_tpu.framework import monitor
+    faults = monitor.get_stat("pallas_verify_errors_total")
+    if faults:
+        # the oracle swallows its own faults; here a candidate whose
+        # check did not finish was rejected above, and the sweep says so
+        print(f"{int(faults)} oracle fault(s): a leg raised (e.g. Mosaic "
+              "refused a tile) — those candidates were rejected, not "
+              "verified", file=sys.stderr)
+        return 1
     return 0
 
 
 if __name__ == "__main__":
+    from paddle_tpu.device import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -1,0 +1,260 @@
+#!/usr/bin/env python
+"""Compile every Pallas kernel with Mosaic on the attached TPU and compare
+it with its XLA reference — the check behind ``flash_blocks.json`` ("holds
+only tiles that compiled") and behind any change to a kernel body.
+
+    python tools/kernel_check.py            # table on stdout, JSON under
+                                            # chiprun_out/kernel_check.json
+
+Per kernel: compiled yes/no and the max abs error of each output against
+the module's own reference.  What it runs, with what the repo already has:
+
+* flash forward + dq/dk/dv at every key of ``flash_blocks.json`` (its own
+  S, d, mask class and tile), against ``flash_attention._xla_reference``
+  evaluated in float32 at the highest matmul precision — the ground truth;
+  the same reference in bf16 is measured beside it (``xla_bf16_err``), so
+  the table shows what XLA's own bf16 path loses on the same input.  An
+  output passes when its max abs error is within ``verify.SCALE_TOL`` of
+  the largest reference value;
+* the non-divisible tail paths through ``verify.check_flash_candidate``
+  (compiled vs interpret vs reference on ``verify.boundary_corpus``);
+* the bias (padding, and full with its dbias kernel) and segment-id paths
+  at S=2048;
+* ``fused_ce`` at (N=8192, H=1024, V=50304): loss, dh, dW;
+* ``fused_adam`` on one 1024x4096 leaf;
+* ``ring_quant._kernel_quant`` at int8 and int4 on (4096, 1024) rows
+  against ``wire.quantize_rows_traced``.
+
+Exits non-zero when a kernel does not compile or leaves its tolerance.
+Needs the TPU: one process, no children.
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+import traceback
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+ROWS = []
+CE_SHAPE = (8192, 1024, 50304)       # GPT-2 345M head at batch 8 x 1024
+ADAM_SHAPE = (1024, 4096)
+QUANT_SHAPE = (4096, 1024)
+
+
+def check(name, run_kernel, run_reference, labels, tol=None, run_also=None):
+    """One row: compile + run the kernel, then hold each output's max abs
+    error to ``tol`` (one share for all outputs or one per label; default
+    ``verify.SCALE_TOL``) of the reference's largest value.  ``run_also``
+    is a second implementation measured against the same reference, for
+    information (``xla_bf16_err``)."""
+    from paddle_tpu.ops.pallas import verify
+    t0 = time.perf_counter()
+    row = {"kernel": name, "compiled": False, "within_tolerance": False}
+    try:
+        got = run_kernel()
+        np.asarray(got[0])             # the kernel ran to the end
+        row["compiled"] = True
+        want = run_reference()
+        errs = verify.max_errors(got, want)
+        tols = tol if isinstance(tol, (list, tuple)) else \
+            [verify.SCALE_TOL if tol is None else tol] * len(errs)
+        row["max_abs_err"] = {lb: float(f"{e:.3g}")
+                              for lb, (e, _) in zip(labels, errs)}
+        row["ref_max_abs"] = {lb: float(f"{m:.3g}")
+                              for lb, (_, m) in zip(labels, errs)}
+        row["within_tolerance"] = all(
+            e <= t * m for (e, m), t in zip(errs, tols))
+        if run_also is not None:
+            row["xla_bf16_err"] = {
+                lb: float(f"{e:.3g}") for lb, (e, _) in
+                zip(labels, verify.max_errors(run_also(), want))}
+    except Exception as e:             # noqa: BLE001 — the row reports it
+        traceback.print_exc()
+        row["error"] = " ".join(str(e).split())[:400]
+    row["s"] = round(time.perf_counter() - t0, 1)
+    ROWS.append(row)
+    print(json.dumps(row), flush=True)
+
+
+def flash_rows():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import autotune
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def case(name, sq, sk, d, causal, bias_shape=None, segs=False,
+             bias_grad=False, b=1, h=2):
+        rng = np.random.default_rng(sq + sk + d)
+        q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
+                               jnp.bfloat16) for s in (sq, sk, sk))
+        bias = None if bias_shape is None else jnp.asarray(
+            rng.standard_normal(bias_shape), jnp.float32)
+        seg = None if not segs else jnp.asarray(
+            np.sort(rng.integers(0, 4, size=(b, sq)), axis=1), jnp.int32)
+        scale = 1.0 / float(np.sqrt(d))
+        diff = (0, 1, 2, 3) if bias_grad else (0, 1, 2)
+        # no bias: the default (differentiable) variant the models call
+        bias_grad = bias_grad or bias is None
+
+        def grads(attn, dtype=jnp.bfloat16):
+            return jax.jit(jax.value_and_grad(
+                lambda a, b_, c, m: (attn(a, b_, c, m).astype(jnp.float32)
+                                     ** 2).sum(), argnums=diff))(
+                q.astype(dtype), k.astype(dtype), v.astype(dtype), bias)
+
+        def reference(a, b_, c, m):
+            return fa._xla_reference(a, b_, c, scale, causal, bias=m,
+                                     q_seg=seg, kv_seg=seg)
+
+        def truth():
+            with jax.default_matmul_precision("highest"):
+                return grads(reference, jnp.float32)
+
+        check(name,
+              lambda: grads(lambda a, b_, c, m: fa.flash_attention(
+                  a, b_, c, causal=causal, scale=scale, bias=m,
+                  bias_grad=bias_grad, q_segment_ids=seg,
+                  kv_segment_ids=seg)),
+              truth, ("loss", "dq", "dk", "dv", "dbias"),
+              run_also=lambda: grads(reference))
+
+    table = autotune._load()
+    for key in sorted(k for k in table if not k.endswith(":bwd")):
+        shape, d, _, mask, biased = key.split(":")
+        sq, sk = (int(x) for x in shape.split("x"))
+        tiles = {"fwd": autotune._entry_blocks(table[key])}
+        if key + ":bwd" in table:
+            tiles["bwd"] = autotune._entry_blocks(table[key + ":bwd"])
+        case(f"flash {key} tiles {tiles}", sq, sk, int(d[1:]),
+             mask == "causal",
+             bias_shape=(1, 1, 1, sk) if biased == "bias" else None)
+    case("flash padding bias (B,1,1,S) S=2048 d64", 2048, 2048, 64, False,
+         bias_shape=(2, 1, 1, 2048), b=2)
+    case("flash full bias (B,H,S,S) + dbias S=2048 d64 causal", 2048, 2048,
+         64, True, bias_shape=(1, 2, 2048, 2048), bias_grad=True)
+    case("flash segment ids S=2048 d64 causal", 2048, 2048, 64, True,
+         segs=True, b=2)
+
+
+def tail_rows():
+    from paddle_tpu.framework import monitor
+    from paddle_tpu.framework.flags import set_flags
+    from paddle_tpu.ops.pallas import verify
+
+    set_flags({"pallas_verify": True})
+    try:
+        for bq, bk in ((1024, 1024), (128, 128)):
+            for causal in (False, True):
+                t0 = time.perf_counter()
+                before = monitor.get_stat("pallas_verify_errors_total")
+                fails = verify.check_flash_candidate(bq, bk, d=64,
+                                                     causal=causal)
+                faults = monitor.get_stat("pallas_verify_errors_total") \
+                    - before
+                row = {"kernel": f"flash tails corpus({bq},{bk}) d64 "
+                                 f"causal={causal} (compiled vs interpret "
+                                 f"vs reference)",
+                       "compiled": not faults,
+                       "within_tolerance": not fails,
+                       "failures": fails,
+                       "s": round(time.perf_counter() - t0, 1)}
+                ROWS.append(row)
+                print(json.dumps(row), flush=True)
+    finally:
+        set_flags({"pallas_verify": False})
+
+
+def other_rows():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.wire import (_unpack_nibbles,
+                                             quantize_rows_traced)
+    from paddle_tpu.ops.pallas import fused_adam, fused_ce, ring_quant
+
+    rng = np.random.default_rng(0)
+    n, hd, v = CE_SHAPE
+    h = jnp.asarray(rng.standard_normal((n, hd)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((v, hd)) * 0.02, jnp.bfloat16)
+    lab = jnp.asarray(rng.integers(0, v, size=(n,)), jnp.int32)
+
+    def ce(fn):
+        loss = jax.jit(fn)(h, w, lab)
+        dh, dw = jax.jit(jax.grad(lambda a, b: fn(a, b, lab).sum(),
+                                  argnums=(0, 1)))(h, w)
+        return loss, dh, dw
+
+    check(f"fused_ce fwd + dh + dw N={n} H={hd} V={v}",
+          lambda: ce(fused_ce.fused_linear_cross_entropy),
+          lambda: ce(fused_ce.xla_reference),
+          ("loss", "dh", "dw"))
+
+    p, m, s = (jnp.asarray(rng.standard_normal(ADAM_SHAPE), jnp.float32)
+               for _ in range(3))
+    g = jnp.asarray(rng.standard_normal(ADAM_SHAPE), jnp.bfloat16)
+    hyper = dict(lr_t=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, wd_lr=1e-5)
+    check(f"fused_adam {ADAM_SHAPE}",
+          lambda: jax.jit(lambda *a: fused_adam.fused_adam_update(
+              *a, **hyper))(p, g, m, jnp.abs(s)),
+          lambda: jax.jit(lambda *a: fused_adam.xla_reference(
+              *a, **hyper))(p, g, m, jnp.abs(s)),
+          ("p", "m", "v"), tol=1e-5)
+
+    rows = jnp.asarray(rng.standard_normal(QUANT_SHAPE), jnp.float32)
+
+    def traced(wire):
+        bufs = quantize_rows_traced(rows, wire)
+        if wire == "int4":            # the kernel's q is the unpacked one
+            return _unpack_nibbles(bufs[0], rows.shape[-1], jnp), bufs[1]
+        return bufs
+
+    for wire, qmax in (("int8", 127.0), ("int4", 7.0)):
+        # q may differ by one step where x/scale lands on a rounding tie
+        # computed one ulp apart; the scales must agree
+        check(f"ring_quant {wire} {QUANT_SHAPE}",
+              lambda: jax.jit(lambda x: ring_quant._kernel_quant(x, qmax))(
+                  rows),
+              lambda: jax.jit(lambda: traced(wire))(),
+              ("q", "scale"), tol=[1.0 / qmax, 1e-6])
+
+
+def main() -> int:
+    import jax
+
+    import paddle_tpu as paddle
+    if jax.default_backend() != "tpu":
+        print(f"kernel_check: needs the TPU, found backend "
+              f"{jax.default_backend()!r}", file=sys.stderr)
+        return 1
+    paddle.device.use_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}  "
+          f"jax {jax.__version__}", flush=True)
+    flash_rows()
+    tail_rows()
+    other_rows()
+    bad = [r["kernel"] for r in ROWS
+           if not (r["compiled"] and r["within_tolerance"])]
+    out = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "kernel_check.json"), "w") as f:
+        json.dump({"device": {"platform": dev.platform,
+                              "kind": dev.device_kind,
+                              "count": len(jax.devices())},
+                   "jax": jax.__version__, "rows": ROWS, "failed": bad}, f,
+                  indent=1)
+    print(f"\n{len(ROWS) - len(bad)}/{len(ROWS)} kernels compiled and "
+          f"within tolerance" + (f"; FAILED: {bad}" if bad else ""),
+          flush=True)
+    return 1 if bad else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
