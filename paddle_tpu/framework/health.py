@@ -14,7 +14,10 @@ one design center (deterministic, clock-injectable, cheap when off):
   cause* by diffing the new signature against the cached ones
   (``new_signature`` / ``shape_change`` / ``dtype_change`` /
   ``static_arg_change``), bumps ``jit_compiles_total`` (+ a per-cause
-  counter), records ``compile_ms`` (first-dispatch latency:
+  counter; a program jax compiles under a signature the site's own cache
+  HIT, its retrace for an input's new sharding, is counted too, cause
+  ``jax_retrace``, from ``jax.monitoring``'s backend-compile event),
+  records ``compile_ms`` (first-dispatch latency:
   trace+compile+run — the honest proxy without AOT lowering), and
   counts ``jit_recompiles_steady_total`` when a site that already
   compiled recompiles past its warmup calls.  ≥K post-warmup compiles
@@ -64,7 +67,6 @@ future autotuner share one decision surface.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import threading
 import time
@@ -475,7 +477,9 @@ def reset():
 # ---------------------------------------------------------------------------
 
 RECOMPILE_CAUSES = ("new_signature", "shape_change", "dtype_change",
-                    "static_arg_change")
+                    "static_arg_change", "jax_retrace")
+# what jax hands to the backend compiler, a persistent-cache read included
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _DTYPE_NAMES = ("float", "bfloat", "int", "uint", "bool", "complex")
 
@@ -585,10 +589,14 @@ def note_compile(site: str, cause: str, compile_ms: float):
     bookkeeping.  Call sites time the first dispatch of the fresh
     executable (trace + XLA compile + run) and classify the cause with
     :func:`classify_recompile` BEFORE inserting the new signature."""
-    if cause not in RECOMPILE_CAUSES:
-        cause = "new_signature"
     s = _site(site)
     s.calls += 1
+    _count_compile(s, cause, compile_ms)
+
+
+def _count_compile(s: _CompileSite, cause: str, compile_ms: float):
+    if cause not in RECOMPILE_CAUSES:
+        cause = "new_signature"
     s.compiles += 1
     s.causes[cause] = s.causes.get(cause, 0) + 1
     s.last_cause = cause
@@ -606,7 +614,7 @@ def note_compile(site: str, cause: str, compile_ms: float):
         if s.steady_recompiles >= storm_k and \
                 s.steady_recompiles % storm_k == 0:
             flight.record("health.compile_storm", severity="warn",
-                          site=site,
+                          site=s.name,
                           post_warmup_compiles=s.steady_recompiles,
                           causes=dict(s.causes))
 
@@ -625,41 +633,79 @@ def compile_report() -> Dict[str, dict]:
 
 
 class _TimedCompile:
-    """Context manager the jit tiers wrap a cache-miss dispatch in: a
-    ``jit.compile`` tracer span carrying site + cause, timed into
-    :func:`note_compile` on exit."""
+    """Context manager the jit tiers wrap a dispatch in.  On a
+    signature-cache miss (``cause`` set): a ``jit.compile`` tracer span
+    carrying site + cause, timed into :func:`note_compile` on exit.  On
+    a hit nothing is timed, but while it is open the site owns whatever
+    jax hands to the backend compiler (:func:`_on_backend_compile`)."""
 
-    __slots__ = ("site", "cause", "_t0", "_span")
+    __slots__ = ("site", "cause", "_t0", "_span", "_outer")
 
-    def __init__(self, site: str, cause: str):
+    def __init__(self, site: str, cause: Optional[str]):
         self.site = site
         self.cause = cause
         self._span = None
         self._t0 = 0.0
+        self._outer = None
 
     def __enter__(self):
-        self._span = tracer.start_span(
-            "jit.compile", attrs={"site": self.site, "cause": self.cause})
-        self._span.__enter__()
+        _listen_for_backend_compiles()
+        self._outer = getattr(_dispatching, "site", None)
+        _dispatching.site = self
+        if self.cause is not None:
+            self._span = tracer.start_span(
+                "jit.compile",
+                attrs={"site": self.site, "cause": self.cause})
+            self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         ms = (time.perf_counter() - self._t0) * 1e3
-        self._span.__exit__(exc_type, exc, tb)
-        if exc_type is None:
-            note_compile(self.site, self.cause, ms)
+        _dispatching.site = self._outer
+        if self._span is not None:
+            self._span.__exit__(exc_type, exc, tb)
+            if exc_type is None:
+                note_compile(self.site, self.cause, ms)
         return False
 
 
 def timed_compile(site: str, cause: Optional[str]):
     """See :class:`_TimedCompile` — the one-liner the jit tiers use.
-    ``cause=None`` (a cache hit) returns a no-op context, so a call
+    ``cause=None`` is a hit of the site's signature cache, so a call
     site wraps its dispatch unconditionally instead of duplicating the
     dispatch expression across a compile/hit branch pair."""
-    if cause is None:
-        return contextlib.nullcontext()
     return _TimedCompile(site, cause)
+
+
+_dispatching = threading.local()    # .site: the innermost open dispatch
+_listening = threading.Event()
+
+
+def _listen_for_backend_compiles():
+    """Register :func:`_on_backend_compile` with jax, once a process."""
+    if not _listening.is_set():
+        with _sites_lock:
+            if not _listening.is_set():
+                import jax
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_backend_compile)
+                _listening.set()
+
+
+def _on_backend_compile(event: str, duration_secs: float, **_):
+    """jax compiled a program (it calls back on the compiling thread).
+    Under a dispatch whose signature cache MISSED this is the compile
+    :class:`_TimedCompile` counts on exit, once; under a hit it is jax's
+    own retrace (an input's sharding changed: the second call of every
+    process, whose parameters carry the first call's output sharding),
+    which no signature of ours can see: count it for the open site."""
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    open_ = getattr(_dispatching, "site", None)
+    if open_ is not None and open_.cause is None:
+        _count_compile(_site(open_.site), "jax_retrace",
+                       duration_secs * 1e3)
 
 
 # ---------------------------------------------------------------------------

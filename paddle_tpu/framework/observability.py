@@ -82,15 +82,17 @@ class Span:
 
     While profiling is on, entering a span also enters a
     ``profiler.RecordEvent`` of the same name, so traced operations
-    appear in the Profiling Report without double instrumentation."""
+    appear in the Profiling Report without double instrumentation;
+    ``profiler_row=False`` is for a site that opens its own
+    ``RecordEvent`` over the same interval (``TrainStep``)."""
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "attrs", "links", "_t0_wall", "_t0_perf", "_ended",
-                 "_rec", "status")
+                 "_rec", "_profiler_row", "status")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str],
-                 attrs: Optional[dict] = None):
+                 attrs: Optional[dict] = None, profiler_row: bool = True):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
@@ -102,6 +104,7 @@ class Span:
         self._t0_perf = time.perf_counter()
         self._ended = False
         self._rec = None
+        self._profiler_row = profiler_row
         self.status = "ok"
 
     def context(self) -> SpanContext:
@@ -126,7 +129,7 @@ class Span:
     def __enter__(self):
         self.tracer._push(self.context())
         from paddle_tpu import profiler
-        if profiler.is_profiling():
+        if self._profiler_row and profiler.is_profiling():
             self._rec = profiler.RecordEvent(self.name)
             self._rec.__enter__()
         return self
@@ -350,7 +353,8 @@ class Tracer:
     # -- span creation ------------------------------------------------------
     def start_span(self, name: str, parent=None, attrs: Optional[dict] = None,
                    detached: bool = False,
-                   consume_links: bool = True) -> Span:
+                   consume_links: bool = True,
+                   profiler_row: bool = True) -> Span:
         """New span under ``parent`` (a Span, SpanContext, or None for
         the thread's current span; a fresh trace when there is none).
         Context-manager use ends it automatically; ``detached=True``
@@ -370,7 +374,8 @@ class Tracer:
             trace_id, parent_id = _new_id(), None
         else:
             trace_id, parent_id = parent.trace_id, parent.span_id
-        span = Span(self, name, trace_id, _new_id(), parent_id, attrs)
+        span = Span(self, name, trace_id, _new_id(), parent_id, attrs,
+                    profiler_row)
         if not detached and consume_links:
             span.links.extend(self._take_pending_links())
         return span

@@ -299,11 +299,13 @@ def functional_loss_call(model, loss_fn, params, buffers, key, inputs,
 
 def apply_functional_update(opt, grads, params, opt_states, lr):
     """Clip (if the optimizer carries a functional clip) + functional
-    optimizer update — the tail every captured step shares."""
-    grad_clip = getattr(opt, "_grad_clip", None)
-    if grad_clip is not None and hasattr(grad_clip, "functional_clip"):
-        grads = grad_clip.functional_clip(grads)
-    return opt.functional_update(params, grads, opt_states, lr=lr)
+    optimizer update — the tail every captured step shares, and the
+    ``optimizer`` region of its device trace."""
+    with jax.named_scope("optimizer"):
+        grad_clip = getattr(opt, "_grad_clip", None)
+        if grad_clip is not None and hasattr(grad_clip, "functional_clip"):
+            grads = grad_clip.functional_clip(grads)
+        return opt.functional_update(params, grads, opt_states, lr=lr)
 
 
 class TrainStep:
@@ -408,6 +410,11 @@ class TrainStep:
             return new_params, new_states, new_buffers, loss
 
         return one_step
+
+    def _resolve_layouts(self, tag, inputs):
+        """First thing inside ``TrainStep.prepare`` (``tag``: "step" or
+        "multi"): nothing here, the sharding layouts of a mesh-compiled
+        subclass."""
 
     def _prepare_dispatch(self, inputs):
         """Shared prologue of __call__ and multi_step: live state grab,
@@ -537,74 +544,100 @@ class TrainStep:
         per-step host boundary to publish at, so the loop body stays
         the disarmed computation.
         """
-        from paddle_tpu.framework import health
-        named_params, named_buffers, params, buffers, arrs, key, lr = \
-            self._prepare_dispatch(inputs)
-        sig = ("multi", bool(unroll)) + _sig_of(list(named_params.values())) \
-            + _sig_of(arrs)
-        fn = self._cache.get(sig)
-        compile_cause = None
-        if fn is None:
-            compile_cause = health.classify_recompile(
-                sig, [s for s in self._cache if s and s[0] == "multi"])
-            scan_fn, unrolled_fn = self._make_multi_step()
-            fn = unrolled_fn if unroll else scan_fn
-            self._cache[sig] = fn
-        else:
-            health.note_cache_hit("TrainStep.multi_step")
-        self._note_avals(fn, arrs, key)
+        from paddle_tpu.framework import health, monitor
         from paddle_tpu.profiler import RecordEvent
-        with RecordEvent("TrainStep.multi_step"):
-            with health.timed_compile("TrainStep.multi_step",
-                                      compile_cause):
+        with RecordEvent("TrainStep.multi_step",
+                         step=int(self.optimizer._global_step)):
+            with RecordEvent("TrainStep.prepare"):
+                self._resolve_layouts("multi", inputs)
+                named_params, named_buffers, params, buffers, arrs, key, \
+                    lr = self._prepare_dispatch(inputs)
+                sig = ("multi", bool(unroll)) \
+                    + _sig_of(list(named_params.values())) + _sig_of(arrs)
+                fn = self._cache.get(sig)
+                compile_cause = None
+                if fn is None:
+                    compile_cause = health.classify_recompile(
+                        sig, [s for s in self._cache
+                              if s and s[0] == "multi"])
+                    scan_fn, unrolled_fn = self._make_multi_step()
+                    fn = unrolled_fn if unroll else scan_fn
+                    self._cache[sig] = fn
+                else:
+                    health.note_cache_hit("TrainStep.multi_step")
+                self._note_avals(fn, arrs, key)
+            with RecordEvent("TrainStep.launch"), health.timed_compile(
+                    "TrainStep.multi_step", compile_cause):
                 new_params, new_states, new_buffers, losses = fn(
                     params, self._opt_states, buffers, key, lr, *arrs)
-        # same per-step guard as __call__, swept over the K losses in one
-        # host sync
-        self._commit_step(losses, "TrainStep.multi_step", named_params,
-                          new_params, named_buffers, new_buffers,
-                          new_states)
-        k = int(arrs[0].shape[0])
-        self.optimizer._global_step += k
-        from paddle_tpu.framework import monitor
-        monitor.stat_add("train_steps_total", k)
+            with RecordEvent("TrainStep.commit"):
+                # same per-step guard as __call__, swept over the K
+                # losses in one host sync
+                self._commit_step(losses, "TrainStep.multi_step",
+                                  named_params, new_params, named_buffers,
+                                  new_buffers, new_states)
+                k = int(arrs[0].shape[0])
+                self.optimizer._global_step += k
+                monitor.stat_add("train_steps_total", k)
         return Tensor(losses)
 
     def __call__(self, *inputs):
+        """One step.  On the profiler's clock it is one host span,
+        ``TrainStep`` (``step=<n>``), around three that follow each other:
+        ``TrainStep.prepare``, ``TrainStep.launch`` (the jitted call
+        alone) and ``TrainStep.commit``."""
+        import time as _time
+
+        from paddle_tpu.framework import health, numerics
+        from paddle_tpu.framework.observability import tracer
+        from paddle_tpu.profiler import RecordEvent
+        t_start = _time.perf_counter()
+        step_no = int(self.optimizer._global_step)
+        with RecordEvent("TrainStep", step=step_no):
+            with RecordEvent("TrainStep.prepare"):
+                self._resolve_layouts("step", inputs)
+                named_params, named_buffers, params, buffers, arrs, key, \
+                    lr = self._prepare_dispatch(inputs)
+                armed = numerics.enabled()
+                # the marker is only appended when ARMED, so the disarmed
+                # signature — and the traced jaxpr behind it — is
+                # byte-identical to the plane-less seed (no extra
+                # outputs, no recompile)
+                sig = _sig_of(list(named_params.values())) + _sig_of(arrs) \
+                    + (("numerics",) if armed else ())
+                fn = self._cache.get(sig)
+                compile_cause = None
+                if fn is None:
+                    # miss = XLA compile: classify the recompile cause
+                    # against the cached signatures before this one is
+                    # inserted
+                    compile_cause = health.classify_recompile(
+                        sig, [s for s in self._cache
+                              if not (s and s[0] == "multi")])
+                    fn = self._make_step(numerics_aux=armed)
+                    self._cache[sig] = fn
+                else:
+                    health.note_cache_hit("TrainStep")
+                self._note_avals(fn, arrs, key)
+            # the JSONL tracer's record of the same call; its profiler
+            # row is TrainStep.launch
+            with RecordEvent("TrainStep.launch"), tracer.start_span(
+                    "train.step", attrs={"step": step_no},
+                    profiler_row=False), \
+                    health.timed_compile("TrainStep", compile_cause):
+                out = fn(params, self._opt_states, buffers, key, lr, *arrs)
+            with RecordEvent("TrainStep.commit"):
+                loss = self._after_launch(out, armed, named_params,
+                                          named_buffers, t_start)
+        return Tensor(loss)
+
+    def _after_launch(self, out, armed, named_params, named_buffers,
+                      t_start):
+        """``TrainStep.commit``: write the step's outputs back and run
+        every per-step hook.  Returns the loss array."""
         import time as _time
 
         from paddle_tpu.framework import health, monitor, numerics
-        from paddle_tpu.framework.observability import tracer
-        t_start = _time.perf_counter()
-        named_params, named_buffers, params, buffers, arrs, key, lr = \
-            self._prepare_dispatch(inputs)
-        armed = numerics.enabled()
-        # the marker is only appended when ARMED, so the disarmed
-        # signature — and the traced jaxpr behind it — is byte-identical
-        # to the plane-less seed (no extra outputs, no recompile)
-        sig = _sig_of(list(named_params.values())) + _sig_of(arrs) \
-            + (("numerics",) if armed else ())
-        fn = self._cache.get(sig)
-        compile_cause = None
-        if fn is None:
-            # miss = XLA compile: classify the recompile cause against
-            # the cached signatures before this one is inserted
-            compile_cause = health.classify_recompile(
-                sig, [s for s in self._cache
-                      if not (s and s[0] == "multi")])
-            fn = self._make_step(numerics_aux=armed)
-            self._cache[sig] = fn
-        else:
-            health.note_cache_hit("TrainStep")
-        self._note_avals(fn, arrs, key)
-        from paddle_tpu.profiler import RecordEvent
-        with tracer.start_span(
-                "train.step",
-                attrs={"step": int(self.optimizer._global_step)}):
-            with RecordEvent("TrainStep"):
-                with health.timed_compile("TrainStep", compile_cause):
-                    out = fn(params, self._opt_states, buffers, key, lr,
-                             *arrs)
         if armed:
             new_params, new_states, new_buffers, loss, aux = out
             # stash + publish BEFORE the commit guard below: a
@@ -640,9 +673,7 @@ class TrainStep:
         # single-device state makes it a no-op after one flag lookup
         from paddle_tpu.parallel import parity
         parity.maybe_observe(self, mesh=getattr(self, "mesh", None))
-        if self.optimizer._lr_scheduler is not None:
-            pass  # user steps the scheduler explicitly, paddle-style
-        return Tensor(loss)
+        return loss
 
     def analyze(self, *example_inputs, **analyze_kwargs):
         """Static analysis of the fused step (framework.analysis jaxpr

@@ -154,10 +154,13 @@ def _bert_forward(cfg, has_tt, has_mask, wte, wpe, wtt, emb_ln_w, emb_ln_b,
     tt = next(it) if has_tt else jnp.zeros_like(ids)
     mask = next(it) if has_mask else None
 
+    # the named scopes are the regions a device trace is read by
+    # (profiler/__init__.py lists them); metadata only
     B, S = ids.shape
-    x = wte[ids] + wpe[:S][None] + wtt[tt]
-    x = _ln(x, emb_ln_w, emb_ln_b)
-    x = _mark(x, "dp", None, None)
+    with jax.named_scope("embed"):
+        x = wte[ids] + wpe[:S][None] + wtt[tt]
+        x = _ln(x, emb_ln_w, emb_ln_b)
+        x = _mark(x, "dp", None, None)
 
     if mask is not None:
         bias = jnp.where(mask[:, None, :].astype(bool), 0.0,
@@ -182,47 +185,61 @@ def _bert_forward(cfg, has_tt, has_mask, wte, wpe, wtt, emb_ln_w, emb_ln_b,
 
     def layer(x, lp):
         b, s = x.shape[:2]
-        qkv = x @ lp["qkv_w"] + lp["qkv_b"]
-        qkv = _mark(qkv, "dp", None, "mp")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        if _flash_ok(b, s):
-            # Pallas flash kernel, (B,S,H,D) layout; the padding mask rides
-            # as (B,1,1,S) bias tiles so padded batches stay O(S·D)
-            from paddle_tpu.ops.pallas import flash_attention as _fa
-            a = _fa.flash_attention(
-                q.reshape(b, s, nh, hd), k.reshape(b, s, nh, hd),
-                v.reshape(b, s, nh, hd), scale=scale, bias=bias,
-                bias_grad=False)
-            a = a.reshape(b, s, H)
-        else:
-            q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-            k = k.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-            v = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-            if bias is not None:
-                scores = scores + bias
-            p = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(
-                x.dtype)
-            a = jnp.einsum("bhqk,bhkd->bhqd", p, v).transpose(0, 2, 1, 3)
-            a = a.reshape(b, s, H)
-        # post-LN (original BERT): LN(x + sublayer(x))
-        x = _ln(x + a @ lp["prj_w"] + lp["prj_b"], lp["ln1_w"], lp["ln1_b"])
-        ff = jax.nn.gelu(x @ lp["fc_w"] + lp["fc_b"], approximate=True)
-        ff = _mark(ff, "dp", None, "mp")
-        x = _ln(x + ff @ lp["out_w"] + lp["out_b"], lp["ln2_w"],
-                lp["ln2_b"])
+        with jax.named_scope("attn"):
+            with jax.named_scope("qkv"):
+                qkv = x @ lp["qkv_w"] + lp["qkv_b"]
+                qkv = _mark(qkv, "dp", None, "mp")
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+            with jax.named_scope("core"):
+                if _flash_ok(b, s):
+                    # Pallas flash kernel, (B,S,H,D) layout; the padding
+                    # mask rides as (B,1,1,S) bias tiles so padded
+                    # batches stay O(S·D)
+                    from paddle_tpu.ops.pallas import flash_attention as _fa
+                    a = _fa.flash_attention(
+                        q.reshape(b, s, nh, hd), k.reshape(b, s, nh, hd),
+                        v.reshape(b, s, nh, hd), scale=scale, bias=bias,
+                        bias_grad=False)
+                    a = a.reshape(b, s, H)
+                else:
+                    q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+                    k = k.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+                    v = v.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)
+                    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+                    if bias is not None:
+                        scores = scores + bias
+                    p = jax.nn.softmax(scores.astype(jnp.float32),
+                                       -1).astype(x.dtype)
+                    a = jnp.einsum("bhqk,bhkd->bhqd", p,
+                                   v).transpose(0, 2, 1, 3)
+                    a = a.reshape(b, s, H)
+            # post-LN (original BERT): LN(x + sublayer(x))
+            with jax.named_scope("out"):
+                y = x + a @ lp["prj_w"] + lp["prj_b"]
+            with jax.named_scope("ln"):
+                x = _ln(y, lp["ln1_w"], lp["ln1_b"])
+        with jax.named_scope("mlp"):
+            with jax.named_scope("up"):
+                ff = jax.nn.gelu(x @ lp["fc_w"] + lp["fc_b"],
+                                 approximate=True)
+                ff = _mark(ff, "dp", None, "mp")
+            with jax.named_scope("down"):
+                y = x + ff @ lp["out_w"] + lp["out_b"]
+            with jax.named_scope("ln"):
+                x = _ln(y, lp["ln2_w"], lp["ln2_b"])
         return _mark(x, "dp", None, None), None
 
     body = jax.checkpoint(layer) if cfg.remat else layer
     x, _ = jax.lax.scan(lambda c, lp: body(c, lp), x, stacked)
 
-    pooled = jnp.tanh(x[:, 0] @ pool_w + pool_b)
-    nsp_logits = pooled @ nsp_w + nsp_b
+    with jax.named_scope("head_loss"):
+        pooled = jnp.tanh(x[:, 0] @ pool_w + pool_b)
+        nsp_logits = pooled @ nsp_w + nsp_b
 
-    h = jax.nn.gelu(x @ mlm_w + mlm_b, approximate=True)
-    h = _ln(h, mlm_ln_w, mlm_ln_b)
-    mlm_logits = h @ wte.T + mlm_bias
-    return _mark(mlm_logits, "dp", None, "mp"), nsp_logits
+        h = jax.nn.gelu(x @ mlm_w + mlm_b, approximate=True)
+        h = _ln(h, mlm_ln_w, mlm_ln_b)
+        mlm_logits = h @ wte.T + mlm_bias
+        return _mark(mlm_logits, "dp", None, "mp"), nsp_logits
 
 
 def bert_pretrain_loss(model, input_ids, mlm_labels, nsp_labels,
@@ -232,6 +249,7 @@ def bert_pretrain_loss(model, input_ids, mlm_labels, nsp_labels,
     mlm_logits, nsp_logits = model(input_ids,
                                    attention_mask=attention_mask)
 
+    @jax.named_scope("head_loss")
     def loss(mlm_logits, nsp_logits, mlm_labels, nsp_labels):
         lg = mlm_logits.astype(jnp.float32)
         logz = jax.scipy.special.logsumexp(lg, axis=-1)

@@ -200,21 +200,34 @@ def _make_stage(cfg: GPTConfig, manual_sp: bool):
     Shared by forward (F-then-B) and the fused 1F1B loss program."""
     H, nh, hd = cfg.hidden_size, cfg.num_heads, cfg.head_dim
 
+    # the named scopes are the regions a device trace is read by
+    # (profiler/__init__.py lists them); metadata only
     def layer(x, lp):
         b, s = x.shape[:2]   # local (microbatch) shape, not the global B,S
-        h = _ln(x, lp["ln1_w"], lp["ln1_b"])
-        qkv = h @ lp["qkv_w"] + lp["qkv_b"]           # (b,s,3H)
-        qkv = _mark(qkv, "dp", "sp", "mp")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, nh, hd)
-        k = k.reshape(b, s, nh, hd)
-        v = v.reshape(b, s, nh, hd)
-        a = _attention(cfg, q, k, v, manual_sp=manual_sp).reshape(b, s, H)
-        x = x + a @ lp["prj_w"] + lp["prj_b"]
-        h2 = _ln(x, lp["ln2_w"], lp["ln2_b"])
-        ff = jax.nn.gelu(h2 @ lp["fc_w"] + lp["fc_b"], approximate=True)
-        ff = _mark(ff, "dp", "sp", "mp")
-        x = x + ff @ lp["out_w"] + lp["out_b"]
+        with jax.named_scope("attn"):
+            with jax.named_scope("ln"):
+                h = _ln(x, lp["ln1_w"], lp["ln1_b"])
+            with jax.named_scope("qkv"):
+                qkv = h @ lp["qkv_w"] + lp["qkv_b"]           # (b,s,3H)
+                qkv = _mark(qkv, "dp", "sp", "mp")
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = q.reshape(b, s, nh, hd)
+                k = k.reshape(b, s, nh, hd)
+                v = v.reshape(b, s, nh, hd)
+            with jax.named_scope("core"):
+                a = _attention(cfg, q, k, v,
+                               manual_sp=manual_sp).reshape(b, s, H)
+            with jax.named_scope("out"):
+                x = x + a @ lp["prj_w"] + lp["prj_b"]
+        with jax.named_scope("mlp"):
+            with jax.named_scope("ln"):
+                h2 = _ln(x, lp["ln2_w"], lp["ln2_b"])
+            with jax.named_scope("up"):
+                ff = jax.nn.gelu(h2 @ lp["fc_w"] + lp["fc_b"],
+                                 approximate=True)
+                ff = _mark(ff, "dp", "sp", "mp")
+            with jax.named_scope("down"):
+                x = x + ff @ lp["out_w"] + lp["out_b"]
         return _mark(x, "dp", "sp", None), None
 
     body = jax.checkpoint(layer) if cfg.remat else layer
@@ -251,8 +264,9 @@ def _gpt_forward(cfg: GPTConfig, wte, wpe, ln1_w, ln1_b, qkv_w, qkv_b,
     mesh = get_mesh()
     B, S = ids.shape
 
-    x = wte[ids] + wpe[:S][None, :, :]
-    x = _mark(x, "dp", "sp", None)
+    with jax.named_scope("embed"):
+        x = wte[ids] + wpe[:S][None, :, :]
+        x = _mark(x, "dp", "sp", None)
 
     stacked = _stack_params(ln1_w, ln1_b, qkv_w, qkv_b, prj_w, prj_b,
                             ln2_w, ln2_b, fc_w, fc_b, out_w, out_b)
@@ -269,11 +283,12 @@ def _gpt_forward(cfg: GPTConfig, wte, wpe, ln1_w, ln1_b, qkv_w, qkv_b,
     else:
         x = stage_fn(stacked, x)
 
-    x = _ln(x, lnf_w, lnf_b)
-    if features_only:
-        return _mark(x, "dp", "sp", None)
-    logits = x @ wte.T                                 # tied head
-    return _mark(logits, "dp", "sp", "mp")
+    with jax.named_scope("head_loss"):
+        x = _ln(x, lnf_w, lnf_b)
+        if features_only:
+            return _mark(x, "dp", "sp", None)
+        logits = x @ wte.T                             # tied head
+        return _mark(logits, "dp", "sp", "mp")
 
 
 def _gpt_1f1b_loss(cfg: GPTConfig, wte, wpe, ln1_w, ln1_b, qkv_w, qkv_b,
@@ -289,8 +304,9 @@ def _gpt_1f1b_loss(cfg: GPTConfig, wte, wpe, ln1_w, ln1_b, qkv_w, qkv_b,
     pp = mesh.shape.get("pp", 1)
     sp = mesh.shape.get("sp", 1)
 
-    x = wte[ids] + wpe[:S][None, :, :]
-    x = _mark(x, "dp", "sp", None)
+    with jax.named_scope("embed"):
+        x = wte[ids] + wpe[:S][None, :, :]
+        x = _mark(x, "dp", "sp", None)
     stacked = _stack_params(ln1_w, ln1_b, qkv_w, qkv_b, prj_w, prj_b,
                             ln2_w, ln2_b, fc_w, fc_b, out_w, out_b)
     stage_fn = _make_stage(cfg, manual_sp=(pp > 1 and sp > 1))
@@ -312,6 +328,7 @@ def _gpt_1f1b_loss(cfg: GPTConfig, wte, wpe, ln1_w, ln1_b, qkv_w, qkv_b,
     if loss_fn is None:
         if len(_1F1B_CACHE) > 16:   # bound the mesh/jit refs it pins
             _1F1B_CACHE.clear()
+        @jax.named_scope("head_loss")
         def head_loss(hp, y, lab):
             # local-sum / GLOBAL-denominator (make_pipeline_train_1f1b's
             # sp contract): each sp shard sums its slice; the schedule
@@ -344,15 +361,16 @@ def _gpt_fused_ce_loss(cfg: GPTConfig, *args):
     wte = params[0]
     B, S = ids.shape
     h = _gpt_forward(cfg, *params, ids, features_only=True)    # (B,S,H)
-    # next-token labels with a -1 sentinel on the final position (same
-    # convention as the 1F1B head)
-    lab = jnp.concatenate(
-        [labels[:, 1:], jnp.full((B, 1), -1, labels.dtype)], axis=1)
-    lab_flat = lab.reshape(B * S)
-    loss_n = fused_linear_cross_entropy(
-        h.reshape(B * S, h.shape[-1]), wte, lab_flat)
-    w = (lab_flat >= 0).astype(jnp.float32)
-    return jnp.sum(loss_n * w) / (B * (S - 1))
+    with jax.named_scope("head_loss"):
+        # next-token labels with a -1 sentinel on the final position
+        # (same convention as the 1F1B head)
+        lab = jnp.concatenate(
+            [labels[:, 1:], jnp.full((B, 1), -1, labels.dtype)], axis=1)
+        lab_flat = lab.reshape(B * S)
+        loss_n = fused_linear_cross_entropy(
+            h.reshape(B * S, h.shape[-1]), wte, lab_flat)
+        w = (lab_flat >= 0).astype(jnp.float32)
+        return jnp.sum(loss_n * w) / (B * (S - 1))
 
 
 def _use_fused_ce() -> bool:
@@ -395,6 +413,7 @@ def gpt_loss(model, input_ids, labels):
                       name="gpt_loss_fused")
     logits = model(input_ids)
 
+    @jax.named_scope("head_loss")
     def ce(logits, ids):
         lg = logits[:, :-1].astype(jnp.float32)
         tg = ids[:, 1:]

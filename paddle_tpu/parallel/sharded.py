@@ -228,16 +228,12 @@ class ShardedTrainStep(TrainStep):
                                         slices)
         return cache[lkey]
 
-    def multi_step(self, *inputs, unroll: bool = False):
-        self._pending_layouts = self._cached_layouts("multi", inputs, True)
-        return super().multi_step(*inputs, unroll=unroll)
-
-    def __call__(self, *inputs):
+    def _resolve_layouts(self, tag, inputs):
         # place model params on the mesh once (parity: the reference's
         # startup-program broadcast of initial params, sharding_optimizer's
         # param→device assignment)
-        self._pending_layouts = self._cached_layouts("step", inputs, False)
-        return super().__call__(*inputs)
+        self._pending_layouts = self._cached_layouts(tag, inputs,
+                                                     tag == "multi")
 
     # -- introspection (compile-only test tier) -----------------------------
     def lower_hlo(self, *inputs) -> str:

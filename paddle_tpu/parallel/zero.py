@@ -287,6 +287,7 @@ class ShardedUpdateTrainStep:
                                   self.amp_dtype, self.recompute)
         grad_clip = getattr(opt, "_grad_clip", None)
 
+        @jax.named_scope("grad_exchange")
         def reduce_scatter(gflat):
             """(padded,) local grad -> (shard_len,) owned mean chunk."""
             if use_ring:
@@ -337,29 +338,34 @@ class ShardedUpdateTrainStep:
             # update the replicated TrainStep samples at, so the
             # exported global grad norm is parity-comparable)
             gshards_preclip = dict(gshards) if numerics_aux else None
-            if grad_clip is not None and hasattr(grad_clip,
-                                                 "functional_clip"):
-                if hasattr(grad_clip, "clip_norm"):
-                    # global-norm clip over SHARDED grads: shard-local
-                    # sum of squares + psum == the replicated global
-                    # norm (padding contributes exact zeros)
-                    sq = sum(jnp.sum(g.astype(jnp.float32) ** 2)
-                             for g in gshards.values())
-                    gn = jnp.sqrt(jax.lax.psum(sq, "dp"))
-                    cscale = jnp.minimum(
-                        grad_clip.clip_norm / jnp.maximum(gn, 1e-12), 1.0)
-                    gshards = {n: (g * cscale).astype(g.dtype)
-                               for n, g in gshards.items()}
-                else:                  # elementwise clip: shard-local
-                    gshards = grad_clip.functional_clip(gshards)
-            new_pshards, new_states = opt.functional_update(
-                pshards, gshards, opt_sh, lr=lr)
-            new_params = {}
-            for n in names:
-                spec = specs[n]
-                full = all_gather(new_pshards[n].astype(params[n].dtype))
-                new_params[n] = full[:spec.size].reshape(
-                    params[n].shape).astype(params[n].dtype)
+            # clip + sharded update + gather of the updated parameters:
+            # the ``optimizer`` region of the device trace
+            with jax.named_scope("optimizer"):
+                if grad_clip is not None and hasattr(grad_clip,
+                                                     "functional_clip"):
+                    if hasattr(grad_clip, "clip_norm"):
+                        # global-norm clip over SHARDED grads: shard-local
+                        # sum of squares + psum == the replicated global
+                        # norm (padding contributes exact zeros)
+                        sq = sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                                 for g in gshards.values())
+                        gn = jnp.sqrt(jax.lax.psum(sq, "dp"))
+                        cscale = jnp.minimum(
+                            grad_clip.clip_norm / jnp.maximum(gn, 1e-12),
+                            1.0)
+                        gshards = {n: (g * cscale).astype(g.dtype)
+                                   for n, g in gshards.items()}
+                    else:              # elementwise clip: shard-local
+                        gshards = grad_clip.functional_clip(gshards)
+                new_pshards, new_states = opt.functional_update(
+                    pshards, gshards, opt_sh, lr=lr)
+                new_params = {}
+                for n in names:
+                    spec = specs[n]
+                    full = all_gather(
+                        new_pshards[n].astype(params[n].dtype))
+                    new_params[n] = full[:spec.size].reshape(
+                        params[n].shape).astype(params[n].dtype)
             # float buffers (BN stats) average over replicas so every
             # replica leaves the step with identical state
             new_buffers = {

@@ -15,6 +15,26 @@ both (a) feed an in-process aggregate table (calls/total/min/max/ave —
 the Profiling Report) and (b) emit jax.profiler.TraceAnnotation scopes so
 the same names show up inside the device trace.  ``export_chrome_tracing``
 writes the host spans in chrome://tracing JSON (timeline.py's role).
+
+Names in a device trace (``start_profiler`` or a bare
+``jax.profiler.start_trace``; one ``.xplane.pb``, one clock):
+
+* host, the calling thread's line: ``TrainStep`` (one per call of a
+  ``jit.TrainStep`` / ``ShardedTrainStep``, with ``step=<n>``) and inside
+  it, in order, ``TrainStep.prepare`` (layouts, live state, signature and
+  cache lookup), ``TrainStep.launch`` (the jitted call alone: trace +
+  compile on a miss, enqueue on a hit) and ``TrainStep.commit`` (state
+  write-back and every per-step hook); ``TrainStep.multi_step`` with the
+  same three children; ``Predictor.run``; and, while paddle's profiler is
+  on, one row per tracer span (``ps.*``, ``ingest.*``, ``jit.compile``).
+* device, in each operation's ``op_name`` path (``jax.named_scope``):
+  ``embed``, ``attn`` (inner ``ln``, ``qkv``, ``core``, ``out``), ``mlp``
+  (inner ``ln``, ``up``, ``down``) and ``head_loss`` from ``models/gpt.py``
+  and ``models/bert.py``, ``optimizer`` from
+  ``jit.apply_functional_update``, ``grad_exchange`` where a step reduces
+  gradients itself (``parallel/zero.py``).  jax adds the pass: ``jvp(`` is
+  the forward, ``transpose(`` the backward, ``rematted_computation`` the
+  forward that ``jax.checkpoint`` runs again.
 """
 from __future__ import annotations
 
@@ -24,6 +44,8 @@ import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["RecordEvent", "record_event", "start_profiler", "stop_profiler",
            "reset_profiler", "profiler", "export_chrome_tracing",
@@ -57,27 +79,27 @@ def is_profiling() -> bool:
 class RecordEvent:
     """Named host span (platform/profiler.h:127).  Usable as a context
     manager or decorator.  Always emits a jax TraceAnnotation (so names
-    appear in device traces even outside start/stop_profiler); aggregates
-    host wall time only while profiling is on."""
+    appear in device traces even outside start/stop_profiler: with no
+    trace running a TraceMe is a flag test); ``annotations`` become the
+    event's stats there (``step=3``).  Aggregates host wall time only
+    while profiling is on."""
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **annotations):
         self.name = name
+        self._annotations = annotations
         self._ann = None
         self._t0 = 0.0
 
     def __enter__(self):
-        if _state["device"] or _state["on"]:
-            import jax
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
+        # a TraceMe starts at construction, so it is built here
+        self._ann = TraceAnnotation(self.name, **self._annotations)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
+        self._ann.__exit__(*exc)
         if _state["on"]:
             dur = t1 - self._t0
             with _lock:

@@ -5,6 +5,7 @@ without the TPU it was asked for, a compile cache placed from outside,
 and a kernel oracle that does not count "did not run" as "verified"."""
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -128,19 +129,75 @@ def test_compile_cache_dir_resolution(monkeypatch, tmp_path):
     import jax
 
     import paddle_tpu as paddle
-    before = jax.config.jax_compilation_cache_dir
+    options = ("jax_compilation_cache_dir",
+               "jax_compilation_cache_include_metadata_in_key",
+               "jax_hlo_source_file_canonicalization_regex")
+    before, *others = [getattr(jax.config, name) for name in options]
     try:
         # set from outside: that directory, and no code names another
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert paddle.device.use_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before
+        # an executable read back must carry this checkout's scope names
+        # (PR 25), whatever directory the checkout lies in
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        assert re.sub(jax.config.jax_hlo_source_file_canonicalization_regex,
+                      "", os.path.join(REPO, "paddle_tpu", "jit",
+                                       "__init__.py")) \
+            == os.path.join("paddle_tpu", "jit", "__init__.py")
         # unset: a fixed path inside the checkout
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         want = os.path.join(REPO, ".jax_cache")
         assert paddle.device.use_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
     finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+        for name, value in zip(options, [before, *others]):
+            jax.config.update(name, value)
+
+
+def test_an_executable_read_back_carries_this_codes_scope_names(
+        monkeypatch, tmp_path):
+    """jax strips metadata from the cache key unless told otherwise, and
+    a hit then returns the names of whoever compiled first: a profile
+    would show stale ``jax.named_scope`` regions."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import paddle_tpu as paddle
+    options = ("jax_compilation_cache_dir",
+               "jax_compilation_cache_include_metadata_in_key",
+               "jax_hlo_source_file_canonicalization_regex",
+               "jax_persistent_cache_min_compile_time_secs",
+               "jax_persistent_cache_min_entry_size_bytes")
+    before = [getattr(jax.config, name) for name in options]
+
+    def scoped(name):
+        def f(x):
+            with jax.named_scope(name):
+                return jnp.tanh(x @ x)
+        return jax.jit(f).lower(jnp.ones((16, 16))).compile().as_text()
+
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        assert "/before/" in scoped("before")
+        assert "/before/" in scoped("stale")         # jax's default
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        paddle.device.use_compile_cache()
+        entries = []
+        for _ in range(2):         # one call site: its line is in the key
+            assert "/after/" in scoped("after")
+            entries.append(sorted(n for n in os.listdir(tmp_path)
+                                  if n.endswith("-cache")))
+        assert entries[0] == entries[1]              # and still a hit
+    finally:
+        for name, value in zip(options, before):
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
 
 
 # -- "did not run" is not "verified" -----------------------------------------
