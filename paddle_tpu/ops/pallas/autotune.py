@@ -3,7 +3,17 @@
 Round-2 verdict item: BLOCK_Q/K=512 was a config-global compromise (the
 256<->512 flip-flop in history shows the answer is shape-dependent).
 This cache keys measured winners on (Sq, Sk, head_dim, dtype, causal,
-biased):
+biased) and, for the backward kernels, the direction.
+
+An entry is **the tile a kernel computes at once**, (block_q, block_k).
+What else it fixes depends on the loop nest ``flash_attention`` picks for
+the call: on the three-axis grid it is also what one grid step fetches,
+so a smaller tile buys finer causal skipping with more grid steps; on
+the two-level nest (operands of a (batch, head) resident in VMEM) one of
+the two is the block a grid step owns and the other the most a sub-tile
+of the in-kernel loop takes of the resident axis, nothing is fetched per
+sub-tile, and a causal mask clips each sub-tile to what it leaves
+visible.  An entry measured under one nest says nothing about the other.
 
 - ``flash_blocks.json`` next to this file ships pre-measured entries for
   the bench/model configs (regenerate with ``tools/flash_autotune.py``
@@ -26,11 +36,15 @@ _PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _cache = None
 _lock = threading.Lock()
 
-# set via force_blocks() during measurement; keys "both"/"fwd"/"bwd"
+# set via force_blocks() during measurement; keys "both"/"fwd"/"bwd"/"dkv"
 _FORCE: dict = {}
 
-CANDIDATES = [(256, 256), (256, 512), (512, 256), (512, 512),
-              (1024, 512), (512, 1024), (1024, 1024)]
+# the lopsided ones are for the two-level nest: dq wants a large block of
+# queries against small pieces of keys, dk/dv the mirror image
+CANDIDATES = [(128, 128), (256, 128), (128, 256), (256, 256), (512, 128),
+              (256, 512), (512, 256), (512, 512), (1024, 128), (128, 1024),
+              (1024, 256), (256, 1024), (1024, 512), (512, 1024),
+              (1024, 1024)]
 
 
 def _load() -> dict:
@@ -51,7 +65,7 @@ def _key(sq, sk, d, dtype, causal, biased, direction="fwd") -> str:
             f"{'causal' if causal else 'full'}:"
             f"{'bias' if biased else 'nobias'}")
     # fwd keeps the historical key so shipped flash_blocks.json entries
-    # stay valid; bwd entries are suffixed
+    # stay valid; bwd and dkv entries are suffixed
     return base if direction == "fwd" else base + ":" + direction
 
 
@@ -65,16 +79,28 @@ def _entry_blocks(hit):
     return tuple(hit) if hit else None
 
 
+# which pinned tile, then which table entry, a direction reads.  "dkv" is
+# the dk/dv kernel on the two-level nest, where its tile is (the piece of
+# queries a sub-tile takes, the block of keys a grid step owns) and so the
+# mirror image of the dq kernel's: one "bwd" entry cannot suit both.
+# Without an entry of its own it shares the backward's, the backward the
+# forward's.
+_FORCED_BY = {"fwd": ("fwd", "both"), "bwd": ("bwd", "both"),
+              "dkv": ("dkv", "bwd", "both")}
+_ENTRY_OF = {"fwd": ("fwd",), "bwd": ("bwd", "fwd"),
+             "dkv": ("dkv", "bwd", "fwd")}
+
+
 def lookup(sq, sk, d, dtype, causal, biased, direction="fwd"):
-    forced = _FORCE.get(direction, _FORCE.get("both"))
-    if forced is not None:
-        return forced
+    for pin in _FORCED_BY[direction]:
+        if pin in _FORCE:
+            return _FORCE[pin]
     c = _load()
-    hit = c.get(_key(sq, sk, d, str(dtype), causal, biased, direction))
-    if hit is None and direction != "fwd":
-        # fall back to the direction-less (fwd) measurement
-        hit = c.get(_key(sq, sk, d, str(dtype), causal, biased))
-    return _entry_blocks(hit)
+    for entry in _ENTRY_OF[direction]:
+        hit = c.get(_key(sq, sk, d, str(dtype), causal, biased, entry))
+        if hit is not None:
+            return _entry_blocks(hit)
+    return None
 
 
 def record(sq, sk, d, dtype, causal, biased, blocks, persist=True,
@@ -93,8 +119,8 @@ def record(sq, sk, d, dtype, causal, biased, blocks, persist=True,
 
 class force_blocks:
     """Context manager pinning the kernel block choice (measurement).
-    ``direction`` pins only the forward ("fwd") or backward ("bwd")
-    kernels; default pins both."""
+    ``direction`` pins only the forward ("fwd"), the backward ("bwd") or,
+    on the two-level nest, the dk/dv kernel ("dkv"); default pins all."""
 
     def __init__(self, bq: int, bk: int, direction: str = "both"):
         self._blocks = (bq, bk)
@@ -134,18 +160,33 @@ def _bench_inputs(sq, sk, d, dtype, biased, batch, heads):
     return q, k, v, bias
 
 
-def _sweep(sq, sk, make_fn, args, iters, direction="both", verbose=False,
-           oracle=None, rejected=None):
-    """Time make_fn() per viable (bq, bk) candidate with that candidate
-    forced for ``direction``; returns {(bq, bk): seconds}.
+def _first(out):
+    return out[0] if isinstance(out, tuple) else out
+
+
+def _wall_seconds(f, args, iters):
+    """Wall seconds a call of ``f``, the fetch of its value fenced."""
+    import time
+
+    _fence(_first(f(*args)))                     # compile + warm
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = f(*args)
+    _fence(_first(out))
+    return (time.perf_counter() - t0) / iters
+
+
+def _sweep(sq, sk, make_fn, args, iters, verbose=False, oracle=None,
+           rejected=None, clock=_wall_seconds):
+    """``clock(make_fn(), args, iters)`` per viable (bq, bk) candidate
+    with that candidate forced on every kernel; returns {(bq, bk): what
+    the clock read}.
 
     ``oracle(bq, bk) -> list-of-failures`` (the armed differential
     oracle, ops/pallas/verify.py) runs BEFORE a candidate is timed: a
     failing candidate is never measured — a fast wrong kernel must not
     win — and its failures land in the caller's ``rejected`` dict.
     """
-    import time
-
     results = {}
     for bq, bk in CANDIDATES:
         if bq > sq or bk > sk or sq % bq or sk % bk:
@@ -156,25 +197,19 @@ def _sweep(sq, sk, make_fn, args, iters, direction="both", verbose=False,
                 if rejected is not None:
                     rejected[(bq, bk)] = bad
                 if verbose:
-                    print(f"  {direction} ({bq},{bk}): REJECTED by "
-                          f"oracle — {bad[0]}")
+                    print(f"  ({bq},{bk}): REJECTED by oracle — {bad[0]}")
                 continue
         try:
-            with force_blocks(bq, bk, direction=direction):
-                f = make_fn()
-                out = f(*args)                   # compile + warm
-                _fence(out[0] if isinstance(out, tuple) else out)
-                t0 = time.perf_counter()
-                for _ in range(iters):
-                    out = f(*args)
-                _fence(out[0] if isinstance(out, tuple) else out)
-                dt = (time.perf_counter() - t0) / iters
-            results[(bq, bk)] = dt
+            with force_blocks(bq, bk):
+                read = clock(make_fn(), args, iters)
+            results[(bq, bk)] = read
             if verbose:
-                print(f"  {direction} ({bq},{bk}): {dt*1e3:.2f} ms")
+                ms = {k: round(v * 1e3, 3) for k, v in read.items()} \
+                    if isinstance(read, dict) else f"{read * 1e3:.2f}"
+                print(f"  ({bq},{bk}): {ms} ms")
         except Exception as e:                   # noqa: BLE001
             if verbose:
-                print(f"  {direction} ({bq},{bk}): failed {e!r}")
+                print(f"  ({bq},{bk}): failed {e!r}")
     return results
 
 
@@ -194,6 +229,9 @@ def _candidate_oracle(d, dtype, causal, biased):
 
 
 def _loss_fn(causal, bias):
+    """A new function at every call: jax caches a trace by the function it
+    was given, and the forced tile is not an argument, so one ``loss``
+    jitted under two candidates would time the first candidate twice."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas import flash_attention as fa
@@ -215,11 +253,10 @@ def measure(sq, sk, d, dtype="bfloat16", causal=False, biased=False,
     import jax
 
     q, k, v, bias = _bench_inputs(sq, sk, d, dtype, biased, batch, heads)
-    loss = _loss_fn(causal, bias)
     oracle = _candidate_oracle(d, dtype, causal, biased)
     results = _sweep(sq, sk,
                      lambda: jax.jit(jax.value_and_grad(
-                         loss, argnums=(0, 1, 2))),
+                         _loss_fn(causal, bias), argnums=(0, 1, 2))),
                      (q, k, v), iters, verbose=verbose, oracle=oracle,
                      rejected=rejected)
     if not results:
@@ -230,42 +267,90 @@ def measure(sq, sk, d, dtype="bfloat16", causal=False, biased=False,
     return best, results
 
 
+_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def _kernel_seconds(f, args, iters):
+    """{kernel name: device seconds a call} for the three flash kernels,
+    read from a profiler trace of ``iters`` calls of ``f`` on the first
+    chip: what the kernel itself takes, with no dispatch or fetch in it.
+    (A wall-clock sweep of the same candidates ranked them differently:
+    at 1-2 ms a call the host's share decides.  PERF.md, PR 26.)  Off the
+    TPU (interpret mode: the tests) there is no device in a trace, and the
+    call's wall time stands in for every kernel."""
+    import glob
+    import tempfile
+
+    import jax
+
+    from paddle_tpu.ops.pallas.common import backend_is_tpu
+
+    if not backend_is_tpu():
+        return dict.fromkeys(_KERNELS, _wall_seconds(f, args, iters))
+    _fence(_first(f(*args)))                     # compile + warm
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        for _ in range(iters):
+            out = f(*args)
+        _fence(_first(out))
+        jax.profiler.stop_trace()
+        trace, = glob.glob(os.path.join(tmp, "plugins", "profile", "*",
+                                        "*.xplane.pb"))
+        planes = jax.profiler.ProfileData.from_file(trace).planes
+    ns = dict.fromkeys(_KERNELS, 0.0)
+    for plane in planes:
+        if not plane.name.startswith("/device:TPU:0"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for event in line.events:
+                for kernel in _KERNELS:          # no name contains another
+                    if kernel in event.name:
+                        ns[kernel] += event.duration_ns
+    return {kernel: t / iters / 1e9 for kernel, t in ns.items()}
+
+
 def measure_split(sq, sk, d, dtype="bfloat16", causal=False, biased=False,
                   batch=1, heads=8, iters=3, persist=True, verbose=False,
                   rejected=None):
-    """Tune fwd and bwd block sizes independently.
+    """Tune the forward, the backward and, on the two-level nest, the dk/dv
+    tile independently, in one sweep: every candidate is forced on all
+    three kernels, forward + backward run ``iters`` times under a trace,
+    and each kernel's own device time picks its winner (``_kernel_seconds``).
+    The kernels are separate calls, so a kernel's time at a tile does not
+    depend on the tiles of the other two.  On the three-axis grid dq and
+    dk/dv share the "bwd" entry, which their summed time picks.
 
-    Pass 1 times the forward alone per candidate and records the "fwd"
-    winner; pass 2, with the forward pinned to that winner, times
-    fwd+bwd per candidate and records the "bwd" winner (bwd-only time
-    isn't separable under jit, but with fwd pinned the candidate axis
-    only moves the backward kernels).
+    Returns ((fwd_best, fwd_res), (bwd_best, bwd_res), (dkv_best,
+    dkv_res) or None), each ``res`` {(bq, bk): seconds}; None where no
+    candidate is viable.
     """
     import jax
 
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
     q, k, v, bias = _bench_inputs(sq, sk, d, dtype, biased, batch, heads)
-    loss = _loss_fn(causal, bias)
     oracle = _candidate_oracle(d, dtype, causal, biased)
-
-    fwd_res = _sweep(sq, sk, lambda: jax.jit(loss), (q, k, v), iters,
-                     direction="fwd", verbose=verbose, oracle=oracle,
-                     rejected=rejected)
-    if not fwd_res:
+    times = _sweep(sq, sk,
+                   lambda: jax.jit(jax.value_and_grad(
+                       _loss_fn(causal, bias), argnums=(0, 1, 2))),
+                   (q, k, v), iters, verbose=verbose, oracle=oracle,
+                   rejected=rejected, clock=_kernel_seconds)
+    if not times:
         return None
-    fwd_best = min(fwd_res, key=fwd_res.get)
-    record(sq, sk, d, dtype, causal, biased, fwd_best, persist=persist,
-           direction="fwd", verified=oracle is not None)
-
-    with force_blocks(*fwd_best, direction="fwd"):
-        bwd_res = _sweep(sq, sk,
-                         lambda: jax.jit(jax.value_and_grad(
-                             loss, argnums=(0, 1, 2))),
-                         (q, k, v), iters, direction="bwd",
-                         verbose=verbose, oracle=oracle,
-                         rejected=rejected)
-    if not bwd_res:
-        return (fwd_best, fwd_res), None
-    bwd_best = min(bwd_res, key=bwd_res.get)
-    record(sq, sk, d, dtype, causal, biased, bwd_best, persist=persist,
-           direction="bwd", verified=oracle is not None)
-    return (fwd_best, fwd_res), (bwd_best, bwd_res)
+    # every candidate divides the sequences, so all run the same nest
+    nest = fa._two_level(sq, sk, d, q.dtype, *next(iter(times)), biased)
+    cost = {"fwd": lambda t: t["flash_fwd"],
+            "bwd": lambda t: t["flash_bwd_dq"] + (
+                0.0 if nest else t["flash_bwd_dkv"])}
+    if nest:
+        cost["dkv"] = lambda t: t["flash_bwd_dkv"]
+    out = []
+    for direction, of in cost.items():
+        res = {blocks: of(t) for blocks, t in times.items()}
+        best = min(res, key=res.get)
+        record(sq, sk, d, dtype, causal, biased, best, persist=persist,
+               direction=direction, verified=oracle is not None)
+        out.append((best, res))
+    return (*out, None) if not nest else tuple(out)

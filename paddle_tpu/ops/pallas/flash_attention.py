@@ -33,6 +33,20 @@ Backward: three kernels, all recomputing p-tiles from (q, k, lse, mask):
 The softmax-jacobian row term delta = rowsum(dO * O) is an O(S·D) XLA
 precompute.  This is the standard FlashAttention-2 backward dataflow.
 
+Two-level tiling: where a call has no bias, segments or tail, sq == sk and
+the operands of one (batch, head) fit ``_RESIDENT_VMEM_BYTES``, the same
+dataflow runs as a different loop nest.  What is resident is large, what
+is computed at once is small: forward and dq run on a grid (bh, q_blocks)
+with K and V whole in VMEM, dk/dv on (bh, kv_blocks) with Q, dO, lse and
+delta whole, and the innermost axis is a static loop inside the kernel
+over sub-tiles that carries its accumulators as values.  Under a causal
+mask each sub-tile is clipped to the bounding box of its visible scores,
+the loop stops at the diagonal, and only the sub-tiles the diagonal
+crosses pay for the iota/compare/select mask: a grid step costs more than
+the work a finer *grid* would skip (the measured reason S = 1024 sat on
+one (1024, 1024) tile and computed the whole square), a sub-tile of
+straight-line code does not.
+
 Rows with no visible key (fully masked) produce output 0 with zero
 gradients (lse = -inf); the XLA fallback's uniform-attention behaviour on
 such rows is an artifact of its -1e30 clamp, not a semantic to preserve.
@@ -51,22 +65,48 @@ import math
 import jax
 import jax.numpy as jnp
 
-# Block choice: isolated fp32 fwd+bwd sweeps at S=8192 prefer 256/256,
-# but in-model (bf16 + remat + optimizer, GPT-2 and 8k-GPT train steps)
-# 512/512 measures ~20% faster end-to-end — bf16 tiles halve VMEM
-# pressure, so the larger block wins where it matters.  _pick_block
-# halves toward _MIN_BLOCK for sequences 512 doesn't divide.
+# The tile a kernel computes at once, where ``flash_blocks.json`` holds no
+# measured entry for the call's shape (``_blocks_for``); ``_pick_block``
+# halves it toward _MIN_BLOCK for sequences it does not divide.  On the
+# three-axis grid it is also what one grid step fetches, and 512/512 was
+# the in-model winner there at long S (pre-ledger sweeps).  On the
+# two-level nest it is (the block a grid step owns, the most a sub-tile
+# takes of the resident axis), or the reverse in dk/dv, and sub-tiles are
+# clipped to what a causal mask leaves of them; there a large block with
+# small pieces wins, because the matmuls stay tall while the pieces follow
+# the diagonal (measured at S = 1024, d = 64: PERF.md, PR 26).
 BLOCK_Q = 512
 BLOCK_K = 512
 _MIN_BLOCK = 128
+
+# Two-level tiling keeps these operands of one (batch, head) in VMEM for a
+# whole row of the grid: K and V (forward, dq), or Q, dO, lse and delta
+# (dk/dv).  Their VMEM footprint (lanes padded to 128) may be this many
+# bytes; the pipeline double-buffers it, so the kernels hold twice that of
+# the 16 MiB Mosaic scopes by default, beside their working tiles.  2 MiB
+# admits S <= 1024 at d <= 128 in any dtype up to four bytes, which is as
+# far as the nest has been measured (PERF.md, PR 26).
+_RESIDENT_VMEM_BYTES = 2 * 2 ** 20
 
 # tests flip this to run the kernels in interpreter mode on CPU
 _INTERPRET = False
 
 _NEG_INF = float("-inf")
 
+from paddle_tpu.framework import monitor  # noqa: E402
 from paddle_tpu.ops.pallas.common import (  # noqa: E402
     backend_is_tpu, dot_nt as _dot_nt, no_x64)
+
+monitor.describe("flash_subtiles_computed_total",
+                 "squares of the score matrix, of side gcd(block_q, "
+                 "block_k), that the flash kernels' two-level loop nest "
+                 "executes, over batch x heads, added once per traced "
+                 "kernel call (a trace-time count)")
+monitor.describe("flash_subtiles_skipped_total",
+                 "squares of the score matrix above the causal diagonal "
+                 "that the flash kernels' two-level loop nest leaves out, "
+                 "over batch x heads, added once per traced kernel call (a "
+                 "trace-time count)")
 
 
 def _canon_bias_shape(bias_shape, b, h, sq, sk):
@@ -113,7 +153,8 @@ def supported(q_shape, k_shape, no_mask: bool = True, causal: bool = False,
         # 146k vs 97k tok/s in-model; S=512: 104k vs 97k), the kernel wins
         # from S≈2048 (58.8k vs 53.4k) and dominates at 8k+ where the XLA
         # path hits its O(S²) HBM cliff.  Causal configs always take the
-        # kernel — block skipping halves the work (S=1024 in-model win).
+        # kernel (S=1024 in-model win); how much of the masked half it
+        # skips depends on the tile: see ``_sub_tiles``.
         return False
     if d % 128 != 0 and d not in (64,):
         return False
@@ -157,6 +198,91 @@ def _blocks_for(sq, sk, d, dtype, causal, biased, direction="fwd"):
                           direction=direction)
     bq, bk = hit if hit else (BLOCK_Q, BLOCK_K)
     return _pick_block(bq, sq), _pick_block(bk, sk)
+
+
+def _vmem_bytes(rows, cols, itemsize):
+    """Bytes a (rows, cols) operand takes in VMEM: lanes padded to 128,
+    rows to the dtype's sublane packing (8 rows of 32 bits)."""
+    sub = 8 * max(1, 4 // itemsize)
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def _two_level(sq, sk, d, dtype, block_q, block_k, has_mask):
+    """Does this call run the two-level nest (module docstring)?  Decided
+    from what the call shows at trace time: no bias or segments, a square
+    score matrix the tiles divide, and the resident operands of one
+    (batch, head) within ``_RESIDENT_VMEM_BYTES``.  Causal or not: without
+    a mask the loop runs to the last sub-tile."""
+    if has_mask or sq != sk or sq % block_q or sk % block_k:
+        return False
+    item = jnp.dtype(dtype).itemsize
+    kv = 2 * _vmem_bytes(sk, d, item)                    # forward, dq
+    q_side = 2 * _vmem_bytes(sq, d, item) + 2 * _vmem_bytes(sq, 1, 4)
+    return max(kv, q_side) <= _RESIDENT_VMEM_BYTES
+
+
+def _count_subtiles(bh, s, block, width, causal, block_is_q):
+    """Add one traced call of a two-level kernel to the monitor: the
+    scores its sub-tiles cover (``_sub_tiles``, every block of the grid)
+    and the rest of the s x s square, over ``bh`` heads, in squares of side
+    gcd(block, width), so that lopsided tiles count area.  Taken when the
+    call is traced, not when it runs; the three-axis grid is not counted."""
+    unit = math.gcd(block, width) ** 2
+    computed = sum(n * (hi - lo) for b0 in range(0, s, block)
+                   for _, n, lo, hi, _ in _sub_tiles(b0, block, s, width,
+                                                     causal, block_is_q))
+    monitor.stat_add("flash_subtiles_computed_total", bh * computed // unit)
+    monitor.stat_add("flash_subtiles_skipped_total",
+                     bh * (s * s - computed) // unit)
+
+
+def _at_block(pl, n, run):
+    """``run(i)`` with the second grid index as a Python int: one
+    ``pl.when`` branch per block, so every loop bound and slice in ``run``
+    is static and Mosaic schedules each block's sub-tiles as straight-line
+    code.  (A ``fori_loop`` with bounds computed from ``program_id`` ran
+    the same sub-tiles 1.4-2.2 times slower on the v5e: PERF.md, PR 26.)"""
+    i = pl.program_id(1)
+    for c in range(n):
+        pl.when(i == c)(functools.partial(run, c))
+
+
+def _sub_tiles(b0, size, limit, width, causal, block_is_q):
+    """The sub-tiles the two-level nest computes for one block of the grid:
+    the block [b0, b0 + size) of one axis of the score matrix against
+    pieces of at most ``width`` of the other, resident axis (length
+    ``limit``; sq == sk).  Static (start, n, lo, hi, masked) tuples: the
+    piece [start, start + n) of the resident axis, and the part [lo, hi) of
+    the block, relative to b0, that its visible scores span.
+
+    Under a causal mask a sub-tile is the bounding box of what the mask
+    leaves of it: a q block stops at its last row's key and a piece of
+    keys skips the rows before it (``block_is_q``); a kv block starts at
+    its first column's query and a piece of queries skips the columns
+    after it.  ``masked`` says whether the diagonal crosses the box; the
+    boxes wholly under it run without the mask."""
+    if not causal:
+        return [(c, min(width, limit - c), 0, size, False)
+                for c in range(0, limit, width)]
+    tiles = []
+    if block_is_q:
+        for c in range(0, b0 + size, width):
+            n = min(width, b0 + size - c)
+            lo = max(c - b0, 0)
+            tiles.append((c, n, lo, size, c + n - 1 > b0 + lo))
+    else:
+        for r in range(b0, limit, width):
+            n = min(width, limit - r)
+            hi = min(size, r + n - b0)
+            tiles.append((r, n, 0, hi, r < b0 + hi - 1))
+    return tiles
+
+
+def _set_rows(x, lo, hi, new):
+    """``x`` with its rows [lo, hi) replaced by ``new`` (static bounds)."""
+    parts = ([x[:lo]] if lo else []) + [new] + \
+        ([x[hi:]] if hi < x.shape[0] else [])
+    return new if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
 def _bias_g_map(bb, hb, h):
@@ -264,6 +390,65 @@ def _fwd_kernel(*args, scale, causal, block_k, block_q, n_kb, off,
             l > 0.0, m_scr[...] + jnp.log(jnp.maximum(l, 1e-30)), -jnp.inf)
 
 
+def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
+                         causal, block_q, block_k):
+    """Forward on the two-level nest: grid (bh, qi), K and V whole in
+    VMEM, the kv axis a static loop over sub-tiles that carries (m, l,
+    acc) as values; a piece of keys updates the rows from its first key
+    on.  sq == sk, so the first piece (key 0) is visible to every row and
+    initialises the state: m is finite from then on and ``_fwd_kernel``'s
+    guards for rows without a visible key have nothing to catch."""
+    from jax.experimental import pallas as pl
+
+    sk = k_ref.shape[1]
+
+    def run(qi):
+        r0 = qi * block_q
+        for c, n, lo, hi, masked in _sub_tiles(r0, block_q, sk, block_k,
+                                               causal, True):
+            rows = slice(lo, hi)
+            v = v_ref[0, c:c + n, :]
+            s = _dot_nt(q_ref[0, rows, :], k_ref[0, c:c + n, :]) * scale
+            if masked:
+                s = _causal_mask(s, r0 + lo, c)
+            m_new = jnp.max(s, axis=1, keepdims=True)
+            if c:
+                m_prev = m[rows]
+                m_new = jnp.maximum(m_prev, m_new)
+                alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = jnp.sum(p, axis=1, keepdims=True)
+            pv = jnp.dot(p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+            if c:
+                m = _set_rows(m, lo, hi, m_new)
+                l = _set_rows(l, lo, hi, alpha * l[rows] + l_new)
+                acc = _set_rows(acc, lo, hi, alpha * acc[rows] + pv)
+            else:
+                m, l, acc = m_new, l_new, pv
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0] = m + jnp.log(l)
+
+    _at_block(pl, sk // block_q, run)
+
+
+def _causal_mask(s, row0, col0):
+    """-inf where a score's key (col0 + column) is after its query."""
+    q_idx = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_idx = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(q_idx >= k_idx, s, -jnp.inf)
+
+
+def _rebuild_p_at(q, k, lse, scale, masked, row0, col0):
+    """``_rebuild_p`` for a sub-tile of the two-level nest, whose first score
+    is query ``row0`` against key ``col0``: no bias, segments or tail, and
+    no row without a visible key, so lse is finite and needs no guard."""
+    s = _dot_nt(q, k) * scale
+    if masked:
+        s = _causal_mask(s, row0, col0)
+    return jnp.exp(s - lse)
+
+
 def _mask_specs(pl, b, h, sqb, g_map, block_q, block_k, has_bias, has_segs,
                 order):
     """Block specs for (bias?, qseg?, kseg?) under grid order
@@ -309,6 +494,46 @@ def _unfold(x, b, h):
     return jnp.einsum("bhsd->bshd", x.reshape(b, h, s, d))
 
 
+# The model calls the kernels once a layer, and a two-level kernel is a
+# straight-line loop of sub-tiles in every ``pl.when`` branch: seconds of
+# Python to trace, which a step of 24 layers would pay 24 times at every
+# start.  The nest's ``pallas_call``s therefore go through jax's trace
+# cache (one trace per shape, dtype and tile); ``inline`` leaves the
+# caller's jaxpr what a direct call would have left.  ``pallas_call`` is an
+# argument, and so part of the cache's key: ``framework.analysis`` swaps
+# ``pl.pallas_call`` for a recorder, whose trace must not be found again.
+_traced_once = functools.partial(jax.jit, inline=True)
+
+
+@functools.partial(_traced_once, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd_resident_call(qt, kt, vt, scale, causal, block_q, block_k,
+                       interpret, pallas_call):
+    """The forward's ``pallas_call`` on the two-level nest: (out, lse)."""
+    from jax.experimental import pallas as pl
+
+    bh, sq, d = qt.shape
+    sk = kt.shape[1]
+    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0))
+    kv_spec = pl.BlockSpec((1, sk, d), lambda bh, qi: (bh, 0, 0))
+    with no_x64():
+        return pallas_call(
+            functools.partial(_fwd_resident_kernel, scale=scale,
+                              causal=causal, block_q=block_q,
+                              block_k=block_k),
+            grid=(bh, sq // block_q),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec,
+                       pl.BlockSpec((1, block_q, 1),
+                                    lambda bh, qi: (bh, qi, 0))],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sq, d), qt.dtype),
+                jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            ],
+            name="flash_fwd",
+            interpret=interpret,
+        )(qt, kt, vt)
+
+
 def _flash_fwd(q, k, v, bias, qseg, kseg, scale, causal):
     """Returns (out (B,S,H,D), lse (B*H, Sq, 1) float32)."""
     b, sq, h, d = q.shape
@@ -339,6 +564,11 @@ def _flash_fwd_folded(qt, kt, vt, bias, qseg, kseg, scale, causal, h):
                                    has_bias or has_segs)
     n_qb = -(-sq // block_q)
     n_kb = -(-sk // block_k)
+    if _two_level(sq, sk, d, qt.dtype, block_q, block_k,
+                  has_bias or has_segs):
+        _count_subtiles(bh, sq, block_q, block_k, causal, True)
+        return _fwd_resident_call(qt, kt, vt, scale, causal, block_q,
+                                  block_k, _INTERPRET, pl.pallas_call)
     if has_bias:
         bb, hb, sqb, _ = bias.shape
         g_map = _bias_g_map(bb, hb, h)
@@ -555,6 +785,76 @@ def _bwd_dkv_kernel(*args, scale, causal, block_q, block_k, n_qb, off,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _bwd_dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, *, scale, causal, block_q, block_k):
+    """dq on the two-level nest: grid (bh, qi), K and V whole in VMEM,
+    the kv axis a static loop to the diagonal that carries the
+    accumulator; a piece of keys adds to the rows from its first key on."""
+    from jax.experimental import pallas as pl
+
+    sk = k_ref.shape[1]
+
+    def run(qi):
+        r0 = qi * block_q
+        for c, n, lo, hi, masked in _sub_tiles(r0, block_q, sk, block_k,
+                                               causal, True):
+            rows = slice(lo, hi)
+            k = k_ref[0, c:c + n, :]
+            p = _rebuild_p_at(q_ref[0, rows, :], k, lse_ref[0, rows, :],
+                              scale, masked, r0 + lo, c)
+            dp = _dot_nt(do_ref[0, rows, :], v_ref[0, c:c + n, :])
+            ds = p * (dp - delta_ref[0, rows, :])
+            dq = jnp.dot(ds.astype(k.dtype), k,
+                         preferred_element_type=jnp.float32)
+            acc = _set_rows(acc, lo, hi, acc[rows] + dq) if c else dq
+        dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
+
+    _at_block(pl, sk // block_q, run)
+
+
+def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                             dk_ref, dv_ref, *, scale, causal, block_q,
+                             block_k):
+    """dk/dv on the two-level nest, the transpose of the above: grid
+    (bh, kb), Q, dO, lse and delta whole in VMEM, the q axis a static loop
+    that carries both accumulators; a piece of queries adds to the columns
+    up to its last query.  The loop runs from the last piece, which sees
+    every column of the block and so starts the accumulators, back to the
+    diagonal."""
+    from jax.experimental import pallas as pl
+
+    sq = q_ref.shape[1]
+
+    def run(kb):
+        c0 = kb * block_k
+        dk = dv = None
+        for r, n, lo, hi, masked in reversed(_sub_tiles(
+                c0, block_k, sq, block_q, causal, False)):
+            rows, cols = slice(r, r + n), slice(lo, hi)
+            q = q_ref[0, rows, :]
+            do = do_ref[0, rows, :]
+            p = _rebuild_p_at(q, k_ref[0, cols, :], lse_ref[0, rows, :],
+                              scale, masked, r, c0 + lo)
+            # contract the query axis: pT@do and dsT@q with bf16 operands
+            dv_t = jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = _dot_nt(do, v_ref[0, cols, :])
+            ds = p * (dp - delta_ref[0, rows, :])
+            dk_t = jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if dk is None:
+                dk, dv = dk_t, dv_t
+            else:
+                dk = _set_rows(dk, lo, hi, dk[cols] + dk_t)
+                dv = _set_rows(dv, lo, hi, dv[cols] + dv_t)
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    _at_block(pl, sq // block_k, run)
+
+
 def _bwd_dbias_kernel(*args, scale, causal, block_q, block_k, n_qb, n_r,
                       off, sq_full, has_segs):
     """ds accumulated over the bias' broadcast extents.
@@ -636,6 +936,19 @@ def _flash_bwd_folded(qt, kt, vt, bias, qseg, kseg, ot, lse, do, scale,
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
                     axis=-1, keepdims=True)
 
+    # the two-level nest reads a tile of its own for dk/dv (autotune)
+    dkv_blocks = _blocks_for(sq, sk, d, qt.dtype, causal,
+                             has_bias or has_segs, direction="dkv")
+    if all(_two_level(sq, sk, d, qt.dtype, *blocks, has_bias or has_segs)
+           for blocks in ((block_q, block_k), dkv_blocks)):
+        _count_subtiles(bh, sq, block_q, block_k, causal, True)
+        _count_subtiles(bh, sq, dkv_blocks[1], dkv_blocks[0], causal, False)
+        dq, dk, dv = _bwd_resident_calls(qt, kt, vt, dot, lse, delta, scale,
+                                         causal, (block_q, block_k),
+                                         dkv_blocks, _INTERPRET,
+                                         pl.pallas_call)
+        return _unfold(dq, b, h), _unfold(dk, b, h), _unfold(dv, b, h), None
+
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, kb: (bh, kb, 0))
     row_spec = pl.BlockSpec((1, block_q, 1), lambda bh, qi, kb: (bh, qi, 0))
@@ -693,6 +1006,54 @@ def _flash_bwd_folded(qt, kt, vt, bias, qseg, kseg, ot, lse, do, scale,
 
     return (_unfold(dq, b, h), _unfold(dk, b, h), _unfold(dv, b, h),
             dbias)
+
+
+@functools.partial(_traced_once, static_argnums=(6, 7, 8, 9, 10, 11))
+def _bwd_resident_calls(qt, kt, vt, dot, lse, delta, scale, causal,
+                        dq_blocks, dkv_blocks, interpret, pallas_call):
+    """The two backward kernels on the two-level nest: (dq, dk, dv), folded.
+    ``tile`` blocks an operand along the grid's second axis, ``whole``
+    keeps it resident for the (batch, head): its index map ignores that
+    axis, so the pipeline fetches it once a row of the grid."""
+    from jax.experimental import pallas as pl
+
+    bh, s, d = qt.shape
+    tile = lambda rows, cols: pl.BlockSpec((1, rows, cols),
+                                           lambda bh, i: (bh, i, 0))
+    whole = lambda rows, cols: pl.BlockSpec((1, rows, cols),
+                                            lambda bh, i: (bh, 0, 0))
+    block_q, block_k = dq_blocks
+    with no_x64():
+        dq = pallas_call(
+            functools.partial(_bwd_dq_resident_kernel, scale=scale,
+                              causal=causal, block_q=block_q,
+                              block_k=block_k),
+            grid=(bh, s // block_q),
+            in_specs=[tile(block_q, d), whole(s, d), whole(s, d),
+                      tile(block_q, d), tile(block_q, 1), tile(block_q, 1)],
+            out_specs=tile(block_q, d),
+            out_shape=jax.ShapeDtypeStruct((bh, s, d), qt.dtype),
+            name="flash_bwd_dq",
+            interpret=interpret,
+        )(qt, kt, vt, dot, lse, delta)
+    block_q, block_k = dkv_blocks
+    with no_x64():
+        dk, dv = pallas_call(
+            functools.partial(_bwd_dkv_resident_kernel, scale=scale,
+                              causal=causal, block_q=block_q,
+                              block_k=block_k),
+            grid=(bh, s // block_k),
+            in_specs=[whole(s, d), tile(block_k, d), tile(block_k, d),
+                      whole(s, d), whole(s, 1), whole(s, 1)],
+            out_specs=[tile(block_k, d), tile(block_k, d)],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, s, d), kt.dtype),
+                jax.ShapeDtypeStruct((bh, s, d), vt.dtype),
+            ],
+            name="flash_bwd_dkv",
+            interpret=interpret,
+        )(qt, kt, vt, dot, lse, delta)
+    return dq, dk, dv
 
 
 def _dbias_call(pl, pltpu, qt, kt, vt, dot, lse, delta, mask_ins, bias,

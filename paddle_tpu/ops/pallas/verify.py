@@ -32,6 +32,7 @@ max abs error.
 from __future__ import annotations
 
 import contextlib
+import math
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
@@ -230,16 +231,26 @@ def boundary_corpus(block_q: int = 128, block_k: int = 128,
                     d: int = 64) -> List[dict]:
     """The deterministic boundary-shape corpus the autotune oracle
     sweeps per tiling candidate: non-divisible lengths (tail blocks on
-    both grid axes), the single-block case, a zero-tail case, and the
-    dtype matrix.  Pure function of the block shape — same candidate,
-    same corpus, same verdict."""
+    both grid axes), the single-block case, a zero-tail case, a square
+    that both tile sides divide (the two-level loop nest: sub-tiles
+    under, on and above the diagonal), and the dtype matrix.  Pure
+    function of the block shape — same candidate, same corpus, same
+    verdict."""
     bq, bk = int(block_q), int(block_k)
+    # two tiles a side where that stays within S = 1024, as far as the
+    # nest's VMEM budget reaches (flash_attention._two_level); a lopsided
+    # tile such as (1024, 256) still has four pieces a block there
+    side = math.lcm(bq, bk)
+    if 2 * side <= 1024:
+        side *= 2
     shapes = [
-        # (sq, sk): non-divisible tails on q, on k, on both, single block
+        # (sq, sk): non-divisible tails on q, on k, on both, single block,
+        # multi-tile square
         (bq + bq // 2, bk + bk // 2),
         (bq, bk + 1),
         (bq + 1, bk),
         (bq, bk),
+        (side, side),
     ]
     corpus = []
     for dtype in ("float32", "bfloat16"):

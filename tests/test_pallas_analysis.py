@@ -343,6 +343,29 @@ class TestInTreeKernels:
                               sds, sds, sds, name="flash")
         assert rep.errors == [] and rep.warnings == [], rep.to_text()
 
+    @pytest.mark.parametrize("blocks", [(256, 256), (256, 128)])
+    def test_flash_two_level_traced_clean(self, blocks):
+        """The GPT-2 cells' call on the two-level nest: K/V (or Q/dO)
+        resident through an index map that ignores the second grid axis,
+        the innermost axis a loop in the kernel.  No pass may mistake the
+        resident operand for a race or the loop for ref-driven control."""
+        from paddle_tpu.framework.analysis.pallas_kernels import (
+            trace_kernels)
+        from paddle_tpu.ops.pallas import autotune
+        from paddle_tpu.ops.pallas import flash_attention as fa
+
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+
+        sds = bf16(1, 1024, 2, 64)
+        grad = jax.grad(loss, argnums=(0, 1, 2))
+        with autotune.force_blocks(*blocks):
+            models = trace_kernels(grad, sds, sds, sds)
+            rep = analyze_kernels(grad, sds, sds, sds, name="flash")
+        assert [len(m.grid) for m in models] == [2, 2, 2]
+        assert rep.errors == [] and rep.warnings == [], rep.to_text()
+
     def test_fused_ce_non_divisible_traced_clean(self):
         from paddle_tpu.ops.pallas.fused_ce import (
             fused_linear_cross_entropy)
@@ -367,7 +390,18 @@ class TestVerifyOracle:
         a = verify.boundary_corpus(128, 256)
         b = verify.boundary_corpus(128, 256)
         assert a == b
-        assert len(a) == 8                      # 4 shapes x 2 dtypes
+        assert len(a) == 10                     # 5 shapes x 2 dtypes
+        # the last shape is a square of several tiles a side
+        assert (a[4]["sq"], a[4]["sk"]) == (512, 512)
+        # ... on which every candidate of the sweep runs the two-level
+        # nest in both dtypes, the table's lopsided tiles included
+        from paddle_tpu.ops.pallas import autotune
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        for bq, bk in autotune.CANDIDATES:
+            for case in verify.boundary_corpus(bq, bk)[4::5]:
+                assert case["sq"] == case["sk"] <= 1024
+                assert fa._two_level(case["sq"], case["sk"], case["d"],
+                                     case["dtype"], bq, bk, False), case
         assert {c["dtype"] for c in a} == {"float32", "bfloat16"}
         assert all(c["sq"] >= 128 and c["sk"] >= 256 for c in a)
 

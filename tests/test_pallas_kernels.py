@@ -372,3 +372,185 @@ class TestNonDivisibleTails:
                                 bias_shape=(1, 1, 200, 200))
         assert not fa.supported((1, 200, 2, 64), (1, 200, 2, 64), True,
                                 segments=True)
+
+
+def _kernel_grids(fn, *args):
+    """The grid of every pallas_call that tracing ``fn(*args)`` reaches."""
+    from paddle_tpu.framework.analysis.pallas_kernels import trace_kernels
+    return [tuple(m.grid) for m in trace_kernels(fn, *args)]
+
+
+class TestTwoLevelTiling:
+    """The two-level nest (resident K/V or Q/dO, an in-kernel loop over
+    sub-tiles to the diagonal) in interpret mode: the unmasked body under
+    the diagonal, the masked body on it and the loop bounds all execute,
+    and calls outside its class keep the three-axis grid."""
+
+    def setup_method(self):
+        fa._INTERPRET = True
+
+    def teardown_method(self):
+        fa._INTERPRET = False
+
+    @pytest.mark.parametrize("direction", ["forward", "backward"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    # (256, 128): a piece of keys skips the q block's rows before it (the
+    # forward and dq update part of their accumulators); (128, 256): a
+    # piece of queries skips the kv block's columns after it (dk/dv)
+    @pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)])
+    @pytest.mark.parametrize("s", [256, 512])
+    def test_causal_matches_xla(self, s, blocks, dtype, direction):
+        from paddle_tpu.ops.pallas import autotune
+        rng = np.random.default_rng(10)
+        B, H, D = 1, 2, 64
+        q, k, v = (x.astype(dtype) for x in _rand_qkv(rng, B, s, s, H, D))
+        scale = 1.0 / np.sqrt(D)
+        f32 = lambda x: np.asarray(x, dtype=np.float32)
+
+        def loss(attn):
+            return lambda q, k, v: (attn(q, k, v).astype(jnp.float32)
+                                    ** 2).sum()
+
+        flash = lambda q, k, v: fa.flash_attention(q, k, v, True, scale)
+        ref = lambda q, k, v: fa._xla_reference(q, k, v, scale, True)
+        if direction == "forward":
+            run = lambda attn: (attn(q, k, v),)
+            tol = dict(rtol=2e-4, atol=2e-5) if dtype == "float32" \
+                else dict(rtol=3e-2, atol=3e-2)
+        else:
+            run = lambda attn: jax.grad(loss(attn), argnums=(0, 1, 2))(
+                q, k, v)
+            tol = dict(rtol=5e-3, atol=5e-4) if dtype == "float32" \
+                else dict(rtol=5e-2, atol=1e-1)
+        with autotune.force_blocks(*blocks):
+            grids = _kernel_grids(lambda: run(flash))
+            got = run(flash)
+        n = 1 if direction == "forward" else 3
+        assert len(grids) == n and all(len(g) == 2 for g in grids), grids
+        # the float32 reference on the same (possibly bf16-rounded) inputs
+        want = run(lambda q, k, v: ref(q.astype(jnp.float32),
+                                       k.astype(jnp.float32),
+                                       v.astype(jnp.float32)))
+        for a, b, name in zip(got, want, ("out",) if n == 1 else "qkv"):
+            assert a.dtype == jnp.dtype(dtype)
+            np.testing.assert_allclose(f32(a), f32(b), err_msg=name, **tol)
+
+    def test_a_model_of_many_layers_traces_each_kernel_once(self,
+                                                           monkeypatch):
+        """The nest's ``pallas_call``s go through jax's trace cache: the
+        kernel bodies, a straight-line loop in every branch, are traced
+        once however many layers call them, and every call still lands in
+        the jaxpr and in the counters."""
+        from paddle_tpu.framework import monitor
+        from paddle_tpu.ops.pallas import autotune
+        traced = []
+        for name in ("_fwd_resident_kernel", "_bwd_dq_resident_kernel",
+                     "_bwd_dkv_resident_kernel"):
+            def body(*a, _real=getattr(fa, name), _name=name, **k):
+                traced.append(_name)
+                return _real(*a, **k)
+            monkeypatch.setattr(fa, name, body)
+
+        def loss(q, k, v):
+            for _ in range(3):                       # three "layers"
+                q = fa.flash_attention(q, k, v, causal=True)
+            return q.astype(jnp.float32).sum()
+
+        x = jax.ShapeDtypeStruct((1, 384, 3, 64), jnp.float32)  # 3 x 3 tiles
+        before = monitor.get_stat("flash_subtiles_computed_total")
+        with autotune.force_blocks(128, 128):
+            text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+                x, x, x))
+        assert sorted(traced) == ["_bwd_dkv_resident_kernel",
+                                  "_bwd_dq_resident_kernel",
+                                  "_fwd_resident_kernel"]
+        assert [text.count(f"name={n}") for n in (
+            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [3, 3, 3]
+        # 6 of 9 sub-tiles a head a kernel, three kernels, three layers
+        assert monitor.get_stat("flash_subtiles_computed_total") - before \
+            == 6 * 3 * 3 * 3
+
+    @pytest.mark.parametrize("kind", ["bias", "segments", "tail",
+                                      "cross_length"])
+    def test_other_calls_keep_the_three_axis_grid(self, kind):
+        from paddle_tpu.ops.pallas import autotune
+        rng = np.random.default_rng(11)
+        B, H, D = 1, 2, 64
+        sq, sk = {"tail": (320, 320), "cross_length": (128, 256)}.get(
+            kind, (256, 256))
+        q, k, v = _rand_qkv(rng, B, sq, sk, H, D)
+        scale = 1.0 / np.sqrt(D)
+        bias = jnp.asarray(rng.standard_normal((B, 1, 1, sk))
+                           .astype(np.float32)) if kind == "bias" else None
+        segs = jnp.asarray(np.repeat(np.arange(2), sq // 2)[None, :]
+                           .astype(np.int32)) if kind == "segments" else None
+
+        def loss(attn):
+            return lambda q, k, v: (attn(q, k, v) ** 2).sum()
+
+        flash = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, scale=scale, bias=bias,
+            q_segment_ids=segs, kv_segment_ids=segs)
+        ref = lambda q, k, v: fa._xla_reference(
+            q, k, v, scale, True, bias=bias, q_seg=segs, kv_seg=segs)
+        with autotune.force_blocks(128, 128):
+            grids = _kernel_grids(jax.grad(loss(flash), argnums=(0, 1, 2)),
+                                  q, k, v)
+            gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        # (a bias adds its dbias kernel, on four axes)
+        assert [len(g) for g in grids][:3] == [3, 3, 3] and \
+            2 not in map(len, grids), grids
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(gf, gr, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-3, atol=5e-4,
+                                       err_msg=f"d{name}")
+
+    def test_operands_over_the_vmem_budget_keep_the_three_axis_grid(self):
+        assert fa._two_level(1024, 1024, 64, jnp.bfloat16, 256, 256, False)
+        assert fa._two_level(1024, 1024, 128, jnp.float32, 512, 512, False)
+        assert not fa._two_level(2048, 2048, 64, jnp.bfloat16, 256, 256,
+                                 False)
+        assert not fa._two_level(1024, 1024, 64, jnp.bfloat16, 256, 256,
+                                 True)
+
+    def test_subtile_counters_at_the_gpt2_shape(self):
+        """(S, t) = (1024, 256): 10 of a head's 16 sub-tiles are computed
+        and 6 left out, in each of the three kernels — counts taken when
+        the call is traced."""
+        from paddle_tpu.framework import monitor
+        from paddle_tpu.ops.pallas import autotune
+        B, S, H, D = 2, 1024, 3, 64
+        x = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16)
+
+        def traced(grad=False):
+            """(computed, skipped) a head that tracing one call adds.  A
+            new function each time: jax caches a trace by the function."""
+            loss = lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True).astype(jnp.float32).sum()
+            names = ("flash_subtiles_computed_total",
+                     "flash_subtiles_skipped_total")
+            before = [monitor.get_stat(n) for n in names]
+            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)) if grad
+                           else loss, x, x, x)
+            return tuple((monitor.get_stat(n) - b) / (B * H)
+                         for n, b in zip(names, before))
+
+        with autotune.force_blocks(256, 256):
+            assert traced() == (10, 6)
+            computed, skipped = traced(grad=True)    # forward, dq, dk/dv
+        assert (computed, skipped) == (30, 18)
+        assert skipped / (computed + skipped) == 0.375
+        # one (1024, 1024) tile, the parent's table entry, skips nothing
+        with autotune.force_blocks(1024, 1024):
+            assert traced() == (1, 0)
+        # tiles that are not square count in squares of their shorter side,
+        # so the share is of the area: a (1024, 256) dq kernel computes the
+        # trapezoid under the diagonal, a (128, 1024) dk/dv kernel 36 of 64
+        with autotune.force_blocks(512, 512, direction="fwd"), \
+                autotune.force_blocks(1024, 256, direction="bwd"), \
+                autotune.force_blocks(128, 1024, direction="dkv"):
+            assert traced() == (3, 1)
+            assert traced(grad=True) == (3 + 10 + 36, 1 + 6 + 28)
+        text = monitor.export_prometheus()
+        assert "above the causal diagonal" in text

@@ -36,7 +36,9 @@ def main(argv=None) -> int:
     ap.add_argument("--biased", action="store_true")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--split", action="store_true",
-                    help="tune fwd and bwd block sizes independently")
+                    help="tune fwd, bwd and (two-level nest) dk/dv block "
+                         "sizes independently, each kernel by its own "
+                         "device time from a trace")
     ap.add_argument("--no-verify", action="store_true",
                     help="skip the differential oracle pre-timing gate "
                          "(candidates are then recorded unstamped)")
@@ -67,9 +69,9 @@ def main(argv=None) -> int:
             if out is None:
                 print("  no viable candidate")
             else:
-                fwd, bwd = out
-                print(f"  -> fwd {fwd[0]}" +
-                      (f", bwd {bwd[0]}" if bwd else ""))
+                fwd, bwd, dkv = out
+                print(f"  -> fwd {fwd[0]}, bwd {bwd[0]}" +
+                      (f", dkv {dkv[0]}" if dkv else ""))
         else:
             out = autotune.measure(sq, sk, d, dt, causal, biased,
                                    iters=a.iters, verbose=True,

@@ -16,8 +16,12 @@ the module's own reference.  What it runs, with what the repo already has:
   the table shows what XLA's own bf16 path loses on the same input.  An
   output passes when its max abs error is within ``verify.SCALE_TOL`` of
   the largest reference value;
-* the non-divisible tail paths through ``verify.check_flash_candidate``
-  (compiled vs interpret vs reference on ``verify.boundary_corpus``);
+* the benchmark's own call, (8, 16, 1024, 64) causal bf16, the same way;
+* the two-level nest at the edge of its VMEM budget, S=1024 d=128 float32;
+* ``verify.check_flash_candidate`` (compiled vs interpret vs reference on
+  ``verify.boundary_corpus``: the non-divisible tail paths and a square of
+  several tiles a side) at the largest and the smallest tile and at the
+  tiles the table holds for the benchmark's call;
 * the bias (padding, and full with its dbias kernel) and segment-id paths
   at S=2048;
 * ``fused_ce`` at (N=8192, H=1024, V=50304): loss, dh, dW;
@@ -90,7 +94,7 @@ def flash_rows():
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     def case(name, sq, sk, d, causal, bias_shape=None, segs=False,
-             bias_grad=False, b=1, h=2):
+             bias_grad=False, b=1, h=2, dtype=jnp.bfloat16):
         rng = np.random.default_rng(sq + sk + d)
         q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
                                jnp.bfloat16) for s in (sq, sk, sk))
@@ -103,7 +107,7 @@ def flash_rows():
         # no bias: the default (differentiable) variant the models call
         bias_grad = bias_grad or bias is None
 
-        def grads(attn, dtype=jnp.bfloat16):
+        def grads(attn, dtype=dtype):
             return jax.jit(jax.value_and_grad(
                 lambda a, b_, c, m: (attn(a, b_, c, m).astype(jnp.float32)
                                      ** 2).sum(), argnums=diff))(
@@ -126,15 +130,24 @@ def flash_rows():
               run_also=lambda: grads(reference))
 
     table = autotune._load()
-    for key in sorted(k for k in table if not k.endswith(":bwd")):
+    for key in sorted(k for k in table
+                      if not k.endswith((":bwd", ":dkv"))):
         shape, d, _, mask, biased = key.split(":")
         sq, sk = (int(x) for x in shape.split("x"))
         tiles = {"fwd": autotune._entry_blocks(table[key])}
-        if key + ":bwd" in table:
-            tiles["bwd"] = autotune._entry_blocks(table[key + ":bwd"])
+        for direction in ("bwd", "dkv"):
+            if f"{key}:{direction}" in table:
+                tiles[direction] = autotune._entry_blocks(
+                    table[f"{key}:{direction}"])
         case(f"flash {key} tiles {tiles}", sq, sk, int(d[1:]),
              mask == "causal",
              bias_shape=(1, 1, 1, sk) if biased == "bias" else None)
+    # the benchmark's GPT-2 cells call the kernels at exactly this shape
+    case("flash GPT-2 345M cell shape (8,16,1024,64) causal", 1024, 1024, 64,
+         True, b=8, h=16)
+    # the most the two-level nest keeps resident (its VMEM budget's edge)
+    case("flash two-level nest at its budget: S=1024 d128 float32 causal",
+         1024, 1024, 128, True, dtype=jnp.float32)
     case("flash padding bias (B,1,1,S) S=2048 d64", 2048, 2048, 64, False,
          bias_shape=(2, 1, 1, 2048), b=2)
     case("flash full bias (B,H,S,S) + dbias S=2048 d64 causal", 2048, 2048,
@@ -146,11 +159,19 @@ def flash_rows():
 def tail_rows():
     from paddle_tpu.framework import monitor
     from paddle_tpu.framework.flags import set_flags
-    from paddle_tpu.ops.pallas import verify
+    from paddle_tpu.ops.pallas import autotune, verify
 
+    # the largest and the smallest tile, and the tiles the table holds for
+    # the shape the benchmark's cells run (forward, dq and dk/dv)
+    tiles = [(1024, 1024), (128, 128)]
+    for direction in ("fwd", "bwd", "dkv"):
+        tile = autotune.lookup(1024, 1024, 64, "bfloat16", True, False,
+                               direction=direction)
+        if tile and tile not in tiles:
+            tiles.append(tile)
     set_flags({"pallas_verify": True})
     try:
-        for bq, bk in ((1024, 1024), (128, 128)):
+        for bq, bk in tiles:
             for causal in (False, True):
                 t0 = time.perf_counter()
                 before = monitor.get_stat("pallas_verify_errors_total")
