@@ -12,4 +12,7 @@ from paddle_tpu.models.gpt import (  # noqa: F401
 from paddle_tpu.models.bert import (  # noqa: F401
     Bert, BertConfig, bert_base, bert_tiny, bert_pretrain_loss, Ernie,
     ErnieConfig)
+from paddle_tpu.models.nemotron_h import (  # noqa: F401
+    NemotronH, NemotronHConfig, nemotron_h_loss, nemotron_h_tiny,
+    routing_load)
 from paddle_tpu.models.rank import WideDeep, DeepFM, WideDeepHost  # noqa: F401

@@ -6,9 +6,10 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core import Tensor, apply, apply1
+from paddle_tpu.nn.functional.ssm import rms_norm_array
 
-__all__ = ["batch_norm", "layer_norm", "instance_norm", "group_norm",
-           "local_response_norm", "normalize"]
+__all__ = ["batch_norm", "layer_norm", "rms_norm", "instance_norm",
+           "group_norm", "local_response_norm", "normalize"]
 
 
 def _mean_var_1pass(a, axes, keepdims=False):
@@ -132,6 +133,14 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
     if bias is not None:
         args.append(bias)
     return apply1(_ln, *args, name="layer_norm")
+
+
+def rms_norm(x, weight, epsilon=1e-5, name=None):
+    """``x / sqrt(mean(x^2) + epsilon) * weight`` over the last axis (no
+    mean, no bias), statistics in float32.  The reference framework has
+    no such functional; ``models/nemotron_h.py`` norms with it."""
+    return apply1(lambda a, w: rms_norm_array(a, w, epsilon), x, weight,
+                  name="rms_norm")
 
 
 def instance_norm(x, running_mean=None, running_var=None, weight=None,

@@ -30,11 +30,22 @@ Names in a device trace (``start_profiler`` or a bare
 * device, in each operation's ``op_name`` path (``jax.named_scope``):
   ``embed``, ``attn`` (inner ``ln``, ``qkv``, ``core``, ``out``), ``mlp``
   (inner ``ln``, ``up``, ``down``) and ``head_loss`` from ``models/gpt.py``
-  and ``models/bert.py``, ``optimizer`` from
+  and ``models/bert.py``; from ``models/nemotron_h.py`` the same ``embed``,
+  ``attn`` and ``head_loss``, its expert layer under ``mlp`` (inner ``ln``,
+  ``router``, ``latent_down``, ``dispatch``, ``experts``, ``combine``,
+  ``latent_up``, ``shared``; ``nn/functional/moe.py``) and its Mamba-2
+  layer under ``ssm`` (inner ``ln``, ``in_proj``, ``conv``, ``scan``,
+  ``gate_norm``, ``out``; ``nn/functional/ssm.py``); ``optimizer`` from
   ``jit.apply_functional_update``, ``grad_exchange`` where a step reduces
   gradients itself (``parallel/zero.py``).  jax adds the pass: ``jvp(`` is
   the forward, ``transpose(`` the backward, ``rematted_computation`` the
   forward that ``jax.checkpoint`` runs again.
+* counters (``framework.monitor``), added once per traced call, so
+  trace-time counts: ``flash_subtiles_computed_total`` / ``_skipped_total``
+  (``ops/pallas/flash_attention.py``), ``moe_calls_traced_total``,
+  ``moe_expert_rows_computed_total``, ``moe_expert_rows_expected_total``
+  (``nn/functional/moe.py``), ``ssm_chunks_traced_total``
+  (``nn/functional/ssm.py``).
 """
 from __future__ import annotations
 
