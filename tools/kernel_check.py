@@ -27,13 +27,23 @@ the module's own reference.  What it runs, with what the repo already has:
 * ``fused_ce`` at (N=8192, H=1024, V=50304): loss, dh, dW;
 * ``fused_adam`` on one 1024x4096 leaf;
 * ``ring_quant._kernel_quant`` at int8 and int4 on (4096, 1024) rows
-  against ``wire.quantize_rows_traced``.
+  against ``wire.quantize_rows_traced``;
+* ``grouped_matmul`` at the hybrid cell's shapes (a buffer of 34816 rows
+  for 4096 tokens, 8 experts of 1024 x 2688) and one skewed load (838
+  rows down to none): the gather plain and gated, the grouped matmul's
+  four variants, both weight gradients and the scatter, against float32
+  matmuls a group at the highest precision, over the tiles the load
+  fills.
+
+``python tools/kernel_check.py grouped`` runs the rows of one family
+(``flash``, ``tail``, ``other``, ``grouped``) alone.
 
 Exits non-zero when a kernel does not compile or leaves its tolerance.
 Needs the TPU: one process, no children.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -49,6 +59,9 @@ ROWS = []
 CE_SHAPE = (8192, 1024, 50304)       # GPT-2 345M head at batch 8 x 1024
 ADAM_SHAPE = (1024, 4096)
 QUANT_SHAPE = (4096, 1024)
+GROUPED_SHAPE = (34816, 8, 1024, 2688)   # rows, experts, latent, inner
+GROUPED_TOKENS = 4096
+GROUPED_LOAD = (838, 400, 200, 100, 50, 20, 5, 0)
 
 
 def check(name, run_kernel, run_reference, labels, tol=None, run_also=None):
@@ -246,6 +259,92 @@ def other_rows():
               ("q", "scale"), tol=[1.0 / qmax, 1e-6])
 
 
+def grouped_rows():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    rows, held, lat, mid = GROUPED_SHAPE
+    tokens = GROUPED_TOKENS
+    tile = gmm.TILE_ROWS
+    rng = np.random.default_rng(0)
+    tiles = [max(1, -(-n // tile)) for n in GROUPED_LOAD]
+    used = sum(tiles)
+    group = np.repeat(np.arange(held), tiles)
+    tile_group = jnp.asarray(
+        np.r_[group, np.full(rows // tile - used, held - 1)], jnp.int32)
+    tiles_used = jnp.asarray([used], jnp.int32)
+    # a row is real where it lies within its group's load; a row of
+    # padding carries the token ``tokens``, a gate of zero, and zero in
+    # the operand the weight gradient contracts over
+    real = np.concatenate([np.arange(t * tile) < n
+                           for t, n in zip(tiles, GROUPED_LOAD)])
+    real = np.r_[real, np.zeros(rows - used * tile, bool)]
+    row_token = jnp.asarray(np.where(
+        real, rng.integers(0, tokens, rows), tokens), jnp.int32)
+    gate = jnp.asarray(np.where(real, rng.random(rows) + 0.5, 0)[:, None],
+                       jnp.float32)
+    real = real[:, None]
+    bf16 = jnp.bfloat16
+    src = jnp.asarray(rng.standard_normal((tokens, lat)), jnp.float32)
+    r = jnp.asarray(np.abs(rng.standard_normal((rows, mid))), bf16)
+    dy = jnp.asarray(np.where(real, rng.standard_normal((rows, lat)), 0),
+                     bf16)
+    w1 = jnp.asarray(rng.standard_normal((held, lat, mid)) * 0.03, bf16)
+    w2 = jnp.asarray(rng.standard_normal((held, mid, lat)) * 0.03, bf16)
+    in_use = jnp.asarray(np.arange(rows)[:, None] < used * tile)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+
+    def kernels(src, r, dy, w1, w2):
+        x = gmm.gather_rows(src, row_token, tiles_used, out_dtype=bf16)
+        rel = gmm.group_rows(x, w1, tile_group, tiles_used, epilogue="relu")
+        y2 = gmm.group_rows(r, w2, tile_group, tiles_used, square_x=True,
+                            out_dtype=jnp.float32)
+        dy2, dgate = gmm.gather_rows(src, row_token, tiles_used, gate=gate,
+                                     other=y2, out_dtype=bf16)
+        dpre = gmm.group_rows(dy, w2, tile_group, tiles_used,
+                              transpose_w=True, epilogue="times_2m", m=r)
+        dx = gmm.group_rows(r, w1, tile_group, tiles_used, transpose_w=True,
+                            out_dtype=jnp.float32)
+        dw2 = gmm.group_weights(r, dy, tile_group, tiles_used, held,
+                                square_x=True)
+        dw1 = gmm.group_weights(dy, r, tile_group, tiles_used, held)
+        y = gmm.scatter_rows(y2, gate, row_token, tiles_used, tokens)
+        return tuple(jnp.where(in_use, t, 0)
+                     for t in (x, rel, y2, dy2, dgate, dpre, dx)) \
+            + (dw2, dw1, y)
+
+    def reference(src, r, dy, w1, w2):
+        dot = functools.partial(jnp.matmul,
+                                precision=jax.lax.Precision.HIGHEST)
+        of_row = jnp.repeat(tile_group, tile)[:, None]
+        x = jnp.where(in_use, src[jnp.minimum(row_token, tokens - 1)], 0)
+        xs, rs, dys = f32(x.astype(bf16)), f32(r), f32(dy)
+        rel = y2 = dpre = dx = 0.0
+        dw2, dw1 = [], []
+        for e in range(held):             # one dense product a group
+            mine = jnp.logical_and(of_row == e, in_use)
+            a1, a2 = f32(w1[e]), f32(w2[e])
+            rel += jnp.where(mine, jax.nn.relu(dot(xs, a1)), 0)
+            dx += jnp.where(mine, dot(rs, a1.T), 0)
+            y2 += jnp.where(mine, dot(rs * rs, a2), 0)
+            dpre += jnp.where(mine, dot(dys, a2.T) * 2 * rs, 0)
+            dw2.append(dot(jnp.where(mine, rs * rs, 0).T, dys))
+            dw1.append(dot(jnp.where(mine, dys, 0).T, rs))
+        y = jnp.zeros((tokens, lat), jnp.float32).at[row_token].add(
+            y2 * gate, mode="drop")
+        return (x, rel, y2, x * gate, jnp.sum(x * y2, 1, keepdims=True),
+                dpre, dx, jnp.stack(dw2), jnp.stack(dw1), y)
+
+    check(f"grouped_matmul rows={rows} experts={held} {lat}x{mid} "
+          f"tokens={tokens} load={GROUPED_LOAD}",
+          functools.partial(jax.jit(kernels), src, r, dy, w1, w2),
+          functools.partial(jax.jit(reference), src, r, dy, w1, w2),
+          ("gather", "relu", "y2", "gather_gated", "row_dot", "dpre", "dx",
+           "dw2", "dw1", "scatter"))
+
+
 def main() -> int:
     import jax
 
@@ -258,9 +357,10 @@ def main() -> int:
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}  "
           f"jax {jax.__version__}", flush=True)
-    flash_rows()
-    tail_rows()
-    other_rows()
+    families = {"flash": flash_rows, "tail": tail_rows, "other": other_rows,
+                "grouped": grouped_rows}
+    for name in sys.argv[1:] or families:
+        families[name]()
     bad = [r["kernel"] for r in ROWS
            if not (r["compiled"] and r["within_tolerance"])]
     out = os.path.join(REPO, "chiprun_out")
