@@ -251,26 +251,6 @@ class GradScaler:
         monitor.stat_set("amp_loss_scale", self._scale)
         self._found_inf = False
 
-    def tighten_growth(self, factor: float = 4.0) -> dict:
-        """Slow scale growth after a collapse: multiply the growth
-        interval (``incr_every_n_steps``) by ``factor`` and cap the
-        current scale at its present value as the new ceiling is
-        re-approached more cautiously.  Returns the previous growth
-        state (``incr_every_n_steps`` + ``good_steps``) so the caller
-        — the autopilot's rollback guard — can undo the action via
-        :meth:`restore_growth` if it did not help."""
-        prev = {"incr_every_n_steps": self._incr_every,
-                "good_steps": self._good_steps}
-        self._incr_every = max(1, int(self._incr_every * factor))
-        self._good_steps = 0
-        return prev
-
-    def restore_growth(self, prev: dict) -> None:
-        """Undo a :meth:`tighten_growth` with the dict it returned."""
-        self._incr_every = max(1, int(
-            prev.get("incr_every_n_steps", self._incr_every)))
-        self._good_steps = int(prev.get("good_steps", self._good_steps))
-
     def state_dict(self):
         return {"scale": self._scale, "incr_ratio": self._incr_ratio,
                 "decr_ratio": self._decr_ratio,

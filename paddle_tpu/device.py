@@ -19,8 +19,8 @@ __all__ = ["set_device", "get_device", "device_count",
 def use_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its
     directory.  Every entry point that compiles for the chip calls this
-    before its first compile (``chip_smoke.py``, ``bench.py``, the
-    ``tools/`` and ``perf/`` scripts); the library and the tests do not.
+    before its first compile (``chip_smoke.py``, ``benchmarks/run.py``,
+    the ``tools/`` scripts); the library and the tests do not.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that
     directory and this names no other.  Otherwise the cache is

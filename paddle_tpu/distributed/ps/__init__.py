@@ -488,10 +488,6 @@ class PSTrainStep:
                  transfer_dtype="bfloat16",
                  prefetch_depth: Optional[int] = None):
         from paddle_tpu.framework.flags import flag
-        from paddle_tpu.framework.autopilot import maybe_apply_tuned_profile
-        # tuned startup profile first: the prefetch_depth default two
-        # lines down reads the flag the profile may override
-        maybe_apply_tuned_profile(source="PSTrainStep")
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -541,21 +537,6 @@ class PSTrainStep:
         uniq_p = _np.zeros((cap,), _np.int64)
         uniq_p[:len(uniq)] = uniq
         return uniq, inv, uniq_p
-
-    def set_prefetch_depth(self, depth: int) -> int:
-        """Retarget the pipeline depth live (autopilot actuator).
-        Returns the previous depth.  The new cap governs the next
-        issue; the worker pool is resized lazily at the first moment
-        the pipeline is empty (an in-flight window keeps its old pool
-        — correctness unaffected, only when the extra concurrency
-        arrives)."""
-        prev = self.prefetch_depth
-        self.prefetch_depth = max(0, int(depth))
-        if self._prefetch_pool is not None and not self._inflight \
-                and self.prefetch_depth != prev:  # pta: disable=PTA404 (train-loop thread only: same single-consumer contract as _issue_prefetch; with nothing in flight no pool task can race the swap)
-            self._prefetch_pool.shutdown(wait=True)
-            self._prefetch_pool = None
-        return prev
 
     def prefetch(self, ids):
         """Announce the ids of an upcoming batch.  The actual shard

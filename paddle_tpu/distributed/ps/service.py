@@ -101,7 +101,7 @@ class TransportStats:
     RPC count, wire bytes each way, and a per-op latency histogram —
     wired into the process-wide monitor registry (``ps_<role>_*`` stats
     and histograms) so the observability layer sees every peer, while
-    each instance keeps its own numbers so e.g. bench.py can report the
+    each instance keeps its own numbers, so a caller can report the
     *measured* wire MB/step of one client rather than the analytic
     formula."""
 
@@ -865,18 +865,6 @@ class PsClient:
         with self._seq_lock:
             self._seq += 1
             return self._seq
-
-    def set_wire_dtype(self, wire_dtype: str) -> str:
-        """Flip the client's preferred wire encoding live (autopilot
-        actuator: bf16→f32 numerics retreat, f32→bf16 bandwidth
-        advance).  Clears the per-server negotiated push cache so the
-        next push to each server re-runs the ``hello`` handshake under
-        the new preference; in-flight RPCs finish under the old one.
-        Returns the previous preference."""
-        prev = self.wire_dtype
-        self.wire_dtype = normalize_wire(wire_dtype)
-        self._push_wires.clear()
-        return prev
 
     def _push_wire(self, s: int) -> str:
         """Negotiated dtype for rows this client SENDS to server ``s``

@@ -109,16 +109,6 @@ Fault points shipped in-tree (grep for ``fault_point(`` to audit):
                         train loop is bit-identical to a collector-less
                         run; ``mode="latency"`` a slow collector the
                         sender thread absorbs off the training path
-``autopilot.act``       head of every autopilot actuator application
-                        (framework/autopilot.py Controller._apply,
-                        armed via FLAGS_autopilot) — ``mode="error"``
-                        is a faulting actuator the controller must
-                        swallow and count
-                        (``autopilot_act_errors_total`` + an
-                        ``autopilot.act_error`` flight event): the
-                        controller must never crash the run it
-                        steers; ``mode="latency"`` a slow actuator
-                        the evaluation interval simply absorbs
 ``parity.observe``      head of every replica-parity probe observation
                         (parallel/parity.py ParityProbe.observe, armed
                         via FLAGS_replica_parity) — ``mode="error"`` is
@@ -190,8 +180,8 @@ FAULT_POINTS = ("ps.rpc", "ps.pipeline", "data.pipeline", "fs.write",
                 "elastic.lease", "elastic.worker_hang",
                 "health.detector", "zero.collective",
                 "numerics.observe", "runlog.observe", "collector.rpc",
-                "locks.observe", "parity.observe", "autopilot.act",
-                "pallas.verify", "incident.capture")
+                "locks.observe", "parity.observe", "pallas.verify",
+                "incident.capture")
 _known_points = set(FAULT_POINTS)
 # points whose fault_point() call carries a payload (the only ones where
 # mode="nan" can transform anything)

@@ -63,8 +63,9 @@ def flag(name: str):
 def overrides() -> Dict[str, Any]:
     """Every flag whose current value differs from its registered
     default — whether env-seeded (FLAGS_<name>) or set at runtime
-    (set_flags).  This is what bench.py stamps into its artifact so a
-    regression is attributable to the configuration that produced it."""
+    (set_flags).  This is what runlog.run_meta stamps into a RunRecord
+    so a regression is attributable to the configuration that produced
+    it."""
     with _lock:
         return {n: v for n, v in _registry.items()
                 if n in _defaults and v != _defaults[n]}
@@ -366,69 +367,9 @@ define_flag("runlog_dir", "",
             "directory of the persistent run ledger "
             "(<runlog_dir>/ledger.jsonl, append-only JSONL).  Non-empty "
             "arms the implicit producers — TrainEpochRange appends a "
-            "RunRecord when an epoch range completes; bench.py and the "
-            "tool CLIs (--ledger) take an explicit path and work either "
-            "way.  Empty = implicit run recording off")
-# autopilot tier (framework/autopilot.py runtime controller +
-# tools/autotune.py offline knob search):
-define_flag("autopilot", False,
-            "arm the runtime autopilot controller "
-            "(framework/autopilot.py): telemetry the planes already "
-            "publish (health anomalies, blame summaries, straggler "
-            "scores, numerics.scale_collapse / train.nan_skip flight "
-            "events) maps through the declarative policy table onto "
-            "the bounded actuator registry (prefetch depth, wire "
-            "dtype, GradScaler growth, snapshot+restore, straggler "
-            "shrink).  Off (default): attach() returns None and the "
-            "train loop pays one flag lookup")
-define_flag("autopilot_dry_run", False,
-            "autopilot decisions are logged (flight events + ledger "
-            "action records) but NO actuator fires — the trajectory "
-            "is bitwise identical to an autopilot-off run")
-define_flag("autopilot_interval_steps", 8,
-            "steps between autopilot evaluation intervals: tick() is "
-            "called per train step, signals are read and policies "
-            "evaluated every Nth tick")
-define_flag("autopilot_hysteresis", 2,
-            "consecutive confirming evaluation intervals before a "
-            "policy's action fires (per-policy override in the "
-            "policy table); a one-interval blip never actuates")
-define_flag("autopilot_cooldown_s", 30.0,
-            "per-action cooldown: after an actuator fires (or is "
-            "reverted), the same action is suppressed for this many "
-            "seconds (injectable clock)")
-define_flag("autopilot_max_actions", 4,
-            "global action budget: at most this many actions taken "
-            "per autopilot_window_s rolling window; excess decisions "
-            "are suppressed and recorded (reason='budget')")
-define_flag("autopilot_window_s", 300.0,
-            "rolling window (s) for the autopilot_max_actions budget")
-define_flag("autopilot_rollback_intervals", 1,
-            "evaluation intervals after an action before the rollback "
-            "guard re-measures its objective (step interval mean + "
-            "anomaly/NaN rate) and reverts an action that made "
-            "things worse")
-define_flag("autopilot_rollback_tolerance", 0.25,
-            "relative objective worsening the rollback guard "
-            "tolerates before reverting (0.25 = step time may grow "
-            "25% before the action is judged harmful; any anomaly/"
-            "NaN-rate increase reverts regardless)")
-define_flag("autopilot_max_prefetch_depth", 4,
-            "ceiling the prefetch.deepen actuator will never push "
-            "PSTrainStep.prefetch_depth past")
-define_flag("autopilot_straggler_deadline", 60.0,
-            "seconds a collector-flagged straggler must stay flagged "
-            "(stale-checked) before the elastic.shrink actuator may "
-            "invoke ElasticAgent.enforce_straggler_policy")
-define_flag("autotune_profile", "",
-            "path of a tuned-knob profile JSON emitted by "
-            "tools/autotune.py; non-empty makes TrainStep/PSTrainStep/"
-            "bench.py apply the profile's knobs (ps_prefetch_depth, "
-            "ps_wire_dtype, zero_wire_dtype) via set_flags once per "
-            "process at first step construction — the runtime "
-            "controller then starts from a tuned operating point.  A "
-            "missing/corrupt profile degrades to a counted "
-            "autopilot.profile_error flight event, never a crash")
+            "RunRecord when an epoch range completes; the tool CLIs "
+            "(--ledger) take an explicit path and work either way.  "
+            "Empty = implicit run recording off")
 # flight-recorder incident-storm guard (framework/observability.py):
 define_flag("flight_storm_window", 1.0,
             "seconds within which identical (kind, attrs) flight "
@@ -461,8 +402,7 @@ define_flag("incident_kinds", "",
             "comma-separated flight kinds that trigger incident "
             "capture; empty = the built-in subscription "
             "(train.nan_skip, health.anomaly, numerics.scale_collapse, "
-            "parity.divergence, pallas.divergence, autopilot.action, "
-            "autopilot.revert)")
+            "parity.divergence, pallas.divergence)")
 define_flag("incident_dir", "",
             "directory incident bundles land under "
             "(incident_<NNNNNN>/ per capture, monotonic id from a "

@@ -62,8 +62,8 @@ is swallowed and counted (``health_observe_errors_total``) — detection
 must never crash the training loop it watches.
 
 ``tools/health_check.py`` renders all of this (plus a trace summary)
-as a health report and exits nonzero on tripped detectors — CI and the
-future autotuner share one decision surface.
+as a health report and exits nonzero on tripped detectors — the
+decision surface CI gates on.
 """
 from __future__ import annotations
 
@@ -236,8 +236,8 @@ class Detector:
 
     def last_value(self) -> Optional[float]:
         """Most recent observed value, or ``None`` before the first
-        :meth:`observe` — the read half consumers (autopilot policies)
-        use instead of reaching into detector internals."""
+        :meth:`observe` — the read half consumers use instead of
+        reaching into detector internals."""
         with self._lock:
             return self.last
 
@@ -256,8 +256,7 @@ class Detector:
         counters — equivalent to a freshly constructed detector.
         Distinct from the automatic rebaseline (which keeps lifetime
         counters); callers use this at deliberate regime changes, e.g.
-        after an autopilot action rewrites the knob the signal
-        measures."""
+        after the knob the signal measures was rewritten."""
         with self._lock:
             self._values.clear()
             self._warm_left = self.warmup

@@ -2,11 +2,11 @@
 
 Every observe/analyze plane in this repo ends at a flight event: a
 ``train.nan_skip`` names the first bad leaf, a ``parity.divergence``
-names the first divergent one, the autopilot records what it actuated —
-and then the step's inputs, rng stream, and pre-step state are gone, so
-*reproducing* the flagged step means rerunning the whole job.  The
-reference's own postmortem story is the same log-line dead end
-(FLAGS_check_nan_inf prints and aborts).  This module closes the loop:
+names the first divergent one — and then the step's inputs, rng
+stream, and pre-step state are gone, so *reproducing* the flagged step
+means rerunning the whole job.  The reference's own postmortem story is
+the same log-line dead end (FLAGS_check_nan_inf prints and aborts).
+This module closes the loop:
 
 * **ring** — with ``FLAGS_incident`` armed, :func:`maybe_note` (hooked
   at the head of ResilientTrainStep / PSTrainStep) keeps the last
@@ -21,8 +21,8 @@ reference's own postmortem story is the same log-line dead end
 * **capture** — a subscribed flight kind firing
   (``FLAGS_incident_kinds``; default ``train.nan_skip``,
   ``health.anomaly``, ``numerics.scale_collapse``,
-  ``parity.divergence``, ``pallas.divergence``, ``autopilot.action``,
-  ``autopilot.revert``) assembles a crash-safe **incident bundle**
+  ``parity.divergence``, ``pallas.divergence``) assembles a crash-safe
+  **incident bundle**
   under ``FLAGS_incident_dir``: the input ring, an inline params/opt
   snapshot below ``FLAGS_incident_state_cap_mb`` (or a ``{root,
   generation}`` ref to the newest verified checkpoint generation),
@@ -78,12 +78,11 @@ MANIFEST_NAME = "manifest.json"
 COMMIT_NAME = "COMMIT"
 STATE_DIRNAME = "state"
 
-#: the built-in subscription — every plane that names a step/leaf/action
+#: the built-in subscription — every plane that names a step/leaf
 #: worth reproducing offline
 DEFAULT_KINDS = ("train.nan_skip", "health.anomaly",
                  "numerics.scale_collapse", "parity.divergence",
-                 "pallas.divergence", "autopilot.action",
-                 "autopilot.revert")
+                 "pallas.divergence")
 
 
 def enabled() -> bool:
@@ -552,8 +551,8 @@ def maybe_note(step, inputs) -> None:
 
 def install() -> None:
     """Subscribe the capture listener without waiting for a first armed
-    step — for processes whose subscribed kinds (autopilot.action) can
-    fire before any ringed step."""
+    step — for processes whose subscribed kinds can fire before any
+    ringed step."""
     recorder.install()
 
 

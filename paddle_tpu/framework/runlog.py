@@ -2,11 +2,8 @@
 
 Every process in this repo already measures itself (tracer spans,
 ``monitor.snapshot()``, flight events, health anomalies, bench legs) and
-then throws the measurement away when it exits: ``BENCH_r*.json`` files
-are disconnected snapshots nobody compares, and the GDP-style
-auto-tuning loop on the ROADMAP is blocked on exactly the artifact that
-never gets built — a queryable history of measured runs.  This module
-closes measurement into memory:
+then throws the measurement away when it exits.  This module closes
+measurement into memory:
 
 * :class:`RunLedger` — a schema-versioned, append-only JSONL store
   (``<FLAGS_runlog_dir>/ledger.jsonl`` by convention).  Appends are
@@ -29,10 +26,9 @@ closes measurement into memory:
   compare`` detects regressions over (step-time p99, RPC p99, input
   stall, compile counts, anomaly totals).
 
-Producers in-tree: ``bench.py`` (every completed leg),
-``tools/op_bench.py`` (``--ledger``), ``tools/health_check.py
---mini-train`` (``--ledger``), and ``TrainEpochRange`` (when
-``FLAGS_runlog_dir`` is set).  ``tools/perf_report.py`` is the
+Producers in-tree: ``tools/op_bench.py`` (``--ledger``),
+``tools/health_check.py --mini-train`` (``--ledger``), and
+``TrainEpochRange`` (when ``FLAGS_runlog_dir`` is set).  ``tools/perf_report.py`` is the
 consumer: ``attribute`` joins a merged trace with the PTA106 analytic
 cost model, ``compare`` runs ``health.Detector`` over ledger series and
 exits nonzero on named regressions.
@@ -48,8 +44,7 @@ from paddle_tpu.framework import chaos, locks, monitor
 from paddle_tpu.framework.flags import flag
 
 __all__ = ["SCHEMA_VERSION", "LEDGER_NAME", "RunLedger", "run_meta",
-           "capture", "default_ledger_path", "bench_record_to_legs",
-           "import_bench_file"]
+           "capture", "default_ledger_path"]
 
 #: bump when the RunRecord shape changes incompatibly; readers must keep
 #: accepting records stamped with a DIFFERENT version (known fields are
@@ -199,7 +194,7 @@ def capture(kind: str, label: Optional[str] = None,
     :meth:`RunLedger.append`).
 
     ``kind`` names the producer (``bench`` / ``op_bench`` /
-    ``health_check`` / ``train_epoch`` / ``imported_bench``); ``label``
+    ``health_check`` / ``train_epoch``); ``label``
     distinguishes variants of one producer (compare only builds series
     within one ``(kind, label)`` group).  ``legs`` are bench-style
     ``{"metric", "value", "unit", ...}`` rows; ``trace_dir`` folds in
@@ -393,57 +388,3 @@ class RunLedger:
     def __len__(self) -> int:
         return len(self.read())
 
-
-# ---------------------------------------------------------------------------
-# historical BENCH_r*.json import
-# ---------------------------------------------------------------------------
-
-def bench_record_to_legs(text: str) -> List[dict]:
-    """Parse bench output lines (one JSON object per line, ``{"metric",
-    "value", "unit", "vs_baseline"}``) out of free text — the driver's
-    BENCH artifacts keep them inside a captured-stdout ``tail`` that
-    also holds warnings and partial lines."""
-    legs = []
-    for ln in text.splitlines():
-        ln = ln.strip()
-        if not ln.startswith("{"):
-            continue
-        try:
-            rec = json.loads(ln)
-        except ValueError:
-            continue
-        if isinstance(rec, dict) and "metric" in rec and "value" in rec:
-            legs.append(rec)
-    return legs
-
-
-def import_bench_file(path: str) -> Optional[dict]:
-    """One historical ``BENCH_r*.json`` driver artifact → one
-    ``imported_bench`` RunRecord (None when the file holds no parseable
-    bench legs).  The record's ``label`` is ``"BENCH"`` so the imported
-    rounds form ONE compare series; ``run`` keeps the round."""
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if isinstance(doc, dict):
-        legs = bench_record_to_legs(str(doc.get("tail", "")))
-        n = doc.get("n")
-    else:
-        legs, n = [], None
-    if not legs:
-        return None
-    return {
-        "schema_version": SCHEMA_VERSION,
-        "kind": "imported_bench",
-        "label": "BENCH",
-        "run_id": os.path.basename(path),
-        "run": n,
-        "ts": None,
-        "meta": {"source": os.path.basename(path)},
-        "summary": {},
-        "snapshot": None,
-        "flight_events": {},
-        "legs": legs,
-    }

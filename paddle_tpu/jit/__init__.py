@@ -329,10 +329,6 @@ class TrainStep:
                  amp_level: Optional[str] = None, amp_dtype="bfloat16",
                  accumulate_steps: int = 1, donate: bool = True,
                  recompute: bool = False):
-        # tuned startup profile (FLAGS_autotune_profile) lands before
-        # any flag-derived knob is read; no-op when unset
-        from paddle_tpu.framework.autopilot import maybe_apply_tuned_profile
-        maybe_apply_tuned_profile(source="TrainStep")
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -716,8 +712,8 @@ class TrainStep:
             donate_argnums=tuple(range(n_donated)), **analyze_kwargs)
 
     def compiled_text(self) -> str:
-        """Backend-optimized HLO of the most recent step signature (perf
-        ledgers / fusion inspection; see perf/resnet50_ledger.py).
+        """Backend-optimized HLO of the most recent step signature (fusion
+        inspection).
         lower().compile() builds a fresh executable — the XLA compile
         cache usually makes it fast, but budget a compile on first use."""
         if getattr(self, "_last_fn", None) is None:

@@ -19,15 +19,6 @@ def _src(*rel):
         return f.read()
 
 
-def _bench_module():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_test", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 # -- one process per chip ----------------------------------------------------
 
 def test_server_boot_pins_cpu_before_package_import():
@@ -38,11 +29,10 @@ def test_server_boot_pins_cpu_before_package_import():
 
 
 def test_ps_spawners_use_server_boot():
-    assert "SERVER_BOOT" in _src("bench.py")
-    assert "SERVER_BOOT" in _src("tests", "test_ps_service.py")
+    src = _src("tests", "test_ps_service.py")
+    assert "SERVER_BOOT" in src
     # no one spawns the raw -m module (which imports the package first)
-    for f in (("bench.py",), ("tests", "test_ps_service.py")):
-        assert "-m\", \"paddle_tpu.distributed.ps" not in _src(*f)
+    assert "-m\", \"paddle_tpu.distributed.ps" not in src
 
 
 def test_dataloader_workers_pin_cpu_before_package_import():
@@ -114,13 +104,6 @@ def test_dryrun_multichip_raises_with_recipe_when_devices_short():
         graft_entry.dryrun_multichip(n)
     assert f"--xla_force_host_platform_device_count={n}" in str(e.value)
     assert "JAX_PLATFORMS=cpu" in str(e.value)
-
-
-def test_bench_main_refuses_non_tpu_backend():
-    bench = _bench_module()
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert isinstance(e.value.code, str) and "refusing" in e.value.code
 
 
 # -- a compile cache placed from outside -------------------------------------

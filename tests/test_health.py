@@ -132,8 +132,8 @@ class TestDetector:
         assert d.last_value() is None and d.baseline() is None
         assert d.n == 0 and d.anomalies == 0 and d.last_z == 0.0
         # warmup restarts: a post-reset extreme is baseline, not anomaly
-        # (the deliberate regime-change semantics an autopilot action
-        # needs after rewriting the knob the signal measures)
+        # (the deliberate regime-change semantics: the knob the signal
+        # measures was rewritten)
         assert d.update(1000.0) is None
         assert d.last_value() == 1000.0
 
@@ -537,37 +537,21 @@ class TestHealthCheck:
 
 
 # ---------------------------------------------------------------------------
-# bench artifact metadata
+# run metadata
 # ---------------------------------------------------------------------------
 
-class TestBenchMeta:
+class TestRunMeta:
     def test_run_meta_stamped(self):
-        import bench
-        bench._META = None
+        from paddle_tpu.framework import runlog
         old = get_flags("health_z_threshold")
         set_flags({"health_z_threshold": 99.0})
         try:
-            meta = bench._run_meta()
+            meta = runlog.run_meta(refresh=True)
             assert meta["host"] and meta["python"]
             assert meta["git_sha"] is None or len(meta["git_sha"]) == 40
             assert meta["flags_overrides"]["health_z_threshold"] == 99.0
         finally:
             set_flags(old)
-            bench._META = None
-
-    def test_artifact_carries_meta(self, tmp_path, monkeypatch):
-        import bench
-        bench._META = None
-        monkeypatch.setattr(bench, "_ARTIFACT",
-                            str(tmp_path / "art.json"))
-        monkeypatch.setattr(bench, "_LEDGER",
-                            str(tmp_path / "ledger.jsonl"))
-        monkeypatch.setattr(bench, "_RECORDS", [])
-        bench._emit("m", 1.0, "u", 1.0)
-        art = json.loads((tmp_path / "art.json").read_text())
-        assert art["meta"]["host"] and art["records"] and \
-            art["complete"] is False
-        bench._META = None
 
 
 # ---------------------------------------------------------------------------

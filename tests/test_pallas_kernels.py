@@ -3,7 +3,7 @@
 On the CPU test mesh the TPU kernels can't execute natively; kernel
 *logic* is validated via pallas interpret mode, and the dispatch gating
 (supported()) plus the XLA fallback numerics are covered directly.  Real
-chip timing/validation runs in the verify drives and bench.py.
+chip timing/validation runs in ``tools/kernel_check.py`` and the benchmark.
 """
 import numpy as np
 import pytest

@@ -4,7 +4,7 @@ fallback, quantize/dequantize parity, the PSTrainStep prefetch pipeline
 (pull/compute overlap + push/pull coalescing) incl. determinism under
 injected ``ps.rpc``/``ps.pipeline`` faults and survival of an elastic
 ``reform()`` mid-prefetch, push (worker, seq) retry dedup, the cached
-table dim, and the measured transport counters bench.py now reports."""
+table dim, and the measured transport counters (``TransportStats``)."""
 import time
 
 import numpy as np

@@ -288,72 +288,6 @@ class TestCapture:
 
 
 # ---------------------------------------------------------------------------
-# bench.py ledger/schema satellites
-# ---------------------------------------------------------------------------
-
-class TestBenchEmit:
-    def test_emit_stamps_schema_and_leg_duration(self, tmp_path,
-                                                 monkeypatch):
-        import bench
-        art = str(tmp_path / "artifact.json")
-        led = str(tmp_path / "ledger.jsonl")
-        monkeypatch.setattr(bench, "_ARTIFACT", art)
-        monkeypatch.setattr(bench, "_LEDGER", led)
-        monkeypatch.setattr(bench, "_RECORDS", [])
-        bench._emit("metric_one", 1.5, "x", 1.0)
-        bench._emit("metric_two", 2.5, "x", 1.0)
-        bench._finalize_artifact()
-        with open(art) as f:
-            doc = json.load(f)
-        assert doc["complete"] is True
-        assert doc["schema_version"] == bench.BENCH_SCHEMA_VERSION
-        assert len(doc["records"]) == 2
-        for r in doc["records"]:
-            assert r["schema_version"] == bench.BENCH_SCHEMA_VERSION
-            assert r["leg_s"] >= 0.0
-        recs = runlog.RunLedger(led).read()
-        assert [r["legs"][0]["metric"] for r in recs] == \
-            ["metric_one", "metric_two"]
-        assert all(r["kind"] == "bench" for r in recs)
-        # per-leg bench records are snapshot-free: process-cumulative
-        # counters ramp WITHIN a multi-leg run and would self-flag as
-        # cross-run regressions in compare
-        assert all(r["snapshot"] is None and r["summary"] == {}
-                   for r in recs)
-
-    def test_multi_leg_bench_run_does_not_self_flag(self, tmp_path,
-                                                    monkeypatch):
-        """A healthy multi-leg bench run whose jit compile counter
-        ramps leg over leg (3, 6, 9, ...) must compare CLEAN — the
-        per-leg records carry no cumulative summary series."""
-        import bench
-        led = str(tmp_path / "ledger.jsonl")
-        monkeypatch.setattr(bench, "_ARTIFACT",
-                            str(tmp_path / "artifact.json"))
-        monkeypatch.setattr(bench, "_LEDGER", led)
-        monkeypatch.setattr(bench, "_RECORDS", [])
-        for i in range(6):
-            monitor.stat_set("jit_compiles_total", 3 * (i + 1))
-            bench._emit(f"model_{i}_samples_per_sec", 100.0, "x/s", 1.0)
-        res = perf_report.compare_records(runlog.RunLedger(led).read())
-        assert res["regressions"] == []
-
-    def test_artifact_failure_degrades_to_flight_event(self, tmp_path,
-                                                       monkeypatch):
-        import bench
-        # artifact path whose parent is a file -> os.replace fails
-        (tmp_path / "blocked").write_text("x")
-        monkeypatch.setattr(bench, "_ARTIFACT",
-                            str(tmp_path / "blocked" / "a.json"))
-        monkeypatch.setattr(bench, "_LEDGER",
-                            str(tmp_path / "ledger.jsonl"))
-        monkeypatch.setattr(bench, "_RECORDS", [])
-        bench._emit("still_emits", 1.0, "x", 1.0)   # must not raise
-        evs = flight.recent(10, kind="bench.artifact_error")
-        assert evs, "artifact write failure left no flight event"
-
-
-# ---------------------------------------------------------------------------
 # trace_merge satellites
 # ---------------------------------------------------------------------------
 
@@ -644,53 +578,6 @@ class TestCompare:
         # --max-regressions tolerance path
         assert perf_report.main(["compare", "--ledger", led.path,
                                  "--max-regressions", "1"]) == 0
-
-
-# ---------------------------------------------------------------------------
-# historical BENCH import
-# ---------------------------------------------------------------------------
-
-class TestBenchImport:
-    def test_import_parses_tail_lines(self, tmp_path):
-        art = tmp_path / "BENCH_r42.json"
-        art.write_text(json.dumps({
-            "n": 42, "rc": 0,
-            "tail": ('WARNING: noise line\n'
-                     '{"metric": "a_per_sec", "value": 10.0, '
-                     '"unit": "x/s", "vs_baseline": 1.0}\n'
-                     '{"truncated": \n'
-                     '{"metric": "b_ms", "value": 2.0, "unit": "ms", '
-                     '"vs_baseline": 1.0}\n')}))
-        rec = runlog.import_bench_file(str(art))
-        assert rec["kind"] == "imported_bench"
-        assert rec["label"] == "BENCH" and rec["run"] == 42
-        assert [leg["metric"] for leg in rec["legs"]] == \
-            ["a_per_sec", "b_ms"]
-
-    def test_import_real_history_and_compare(self, tmp_path):
-        paths = sorted(
-            os.path.join(REPO, f) for f in os.listdir(REPO)
-            if f.startswith("BENCH_r0") and f.endswith(".json"))
-        assert len(paths) >= 2
-        led = str(tmp_path / "hist.jsonl")
-        rc = perf_report.main(["import", *paths, "--ledger", led])
-        assert rc == 0
-        recs = runlog.RunLedger(led).read()
-        assert len(recs) == len(paths)
-        assert all(r["kind"] == "imported_bench" for r in recs)
-        # the trajectory compares without crashing, deterministically
-        r1 = perf_report.compare_records(recs)
-        r2 = perf_report.compare_records(recs)
-        assert r1 == r2
-        assert r1["groups"][0]["runs"] == len(paths)
-
-    def test_import_garbage_file_skipped(self, tmp_path):
-        bad = tmp_path / "BENCH_r99.json"
-        bad.write_text("not json at all")
-        led = str(tmp_path / "hist.jsonl")
-        rc = perf_report.main(["import", str(bad), "--ledger", led])
-        assert rc == 1
-        assert runlog.RunLedger(led).read() == []
 
 
 # ---------------------------------------------------------------------------

@@ -2,9 +2,8 @@
 """Health report CLI — the perf health plane's decision surface.
 
 Renders one report (text or JSON) from a metrics snapshot and/or a
-trace directory, and exits nonzero when a gate trips — so CI, a
-launcher wrapper, and the future autotuner all consume the same
-verdict the detectors produce:
+trace directory, and exits nonzero when a gate trips — so CI and a
+launcher wrapper consume the same verdict the detectors produce:
 
 * **anomalies** — ``health_anomalies_total`` (+ the per-signal
   ``health_anomaly_<signal>_total`` split) from the streaming
@@ -181,29 +180,13 @@ def load_metrics(path: str) -> dict:
 # report assembly
 # ---------------------------------------------------------------------------
 
-def _tail_spans(spans: list, n: int, step_span: str = "train.step"):
-    """Spans from the last ``n`` train steps only: everything starting
-    at or after the n-th-from-last step span's start.  The autopilot
-    lane's recovery gate reads blame over the TAIL — an injected storm
-    the controller fixed mid-run must not dominate the verdict through
-    the cumulative average."""
-    steps = sorted((s for s in spans if s.get("name") == step_span),
-                   key=lambda s: s["ts"])
-    if n <= 0 or len(steps) <= n:
-        return spans
-    t0 = steps[-n]["ts"]
-    return [s for s in spans if s["ts"] >= t0]
-
-
 def build_report(snap: dict, trace_dir: Optional[str] = None,
                  health_snapshot: Optional[dict] = None,
-                 blame_tail: Optional[int] = None,
                  step_span: str = "train.step") -> dict:
     """Fold a metrics snapshot (+ optional trace dir and live health
     state) into the report dict the gates and renderers consume.
-    ``blame_tail=N`` computes blame over only the last N steps' spans
-    (see :func:`_tail_spans`); ``step_span`` names the per-step span
-    blame anchors on (``zero.step`` for the ZeRO leg)."""
+    ``step_span`` names the per-step span blame anchors on
+    (``zero.step`` for the ZeRO leg)."""
     stats = snap.get("stats", {})
     hists = snap.get("histograms", {})
 
@@ -268,16 +251,6 @@ def build_report(snap: dict, trace_dir: Optional[str] = None,
             "input_stall_pct": stats.get("input_stall_pct"),
         },
     }
-    from paddle_tpu.framework.observability import flight as _flight
-    prof_evs = _flight.recent(5, kind="autopilot.profile_applied")
-    if prof_evs:
-        # a tuned profile was consumed at startup (FLAGS_autotune_profile
-        # -> maybe_apply_tuned_profile at TrainStep/PSTrainStep ctor) —
-        # surface it so CI can gate on the whole chain end to end
-        attrs = prof_evs[-1].get("attrs") or {}
-        report["tuned_profile"] = {"path": attrs.get("path"),
-                                   "source": attrs.get("source"),
-                                   "knobs": attrs.get("knobs")}
     if health_snapshot is not None:
         report["detectors"] = health_snapshot.get("signals", {})
         report["compiles"]["sites"] = health_snapshot.get("compile", {})
@@ -292,9 +265,6 @@ def build_report(snap: dict, trace_dir: Optional[str] = None,
                 trace_merge.merge(paths))
         from paddle_tpu.framework import blame
         spans = blame.load_trace_dir(trace_dir)
-        if blame_tail:
-            spans = _tail_spans(spans, int(blame_tail),
-                                step_span=step_span)
         res = blame.compute_blame(spans, step_span=step_span)
         if res["n_steps"]:
             # the FULL result (edges trimmed): evaluate_gates reads
@@ -328,9 +298,7 @@ def evaluate_gates(report: dict, max_anomalies: int = 0,
                    max_steady_recompiles: int = 0,
                    max_input_stall: Optional[float] = None,
                    max_grad_anomalies: Optional[int] = None,
-                   max_blame: Optional[dict] = None,
-                   expect_actions: Optional[list] = None,
-                   max_actions: Optional[int] = None) -> list:
+                   max_blame: Optional[dict] = None) -> list:
     """Returns the list of tripped-gate descriptions (empty = healthy)."""
     tripped = []
     n_anom = report["anomalies"]["total"]
@@ -374,26 +342,6 @@ def evaluate_gates(report: dict, max_anomalies: int = 0,
                         f"blame share {cat}: {pct:.2f}% > {limit}% "
                         f"({bl.get('per_step_ms', {}).get(cat)} "
                         f"ms/step)")
-    if expect_actions or max_actions is not None:
-        auto = report.get("autopilot")
-        if auto is None:
-            tripped.append("autopilot gate set but no autopilot "
-                           "section (run with --autopilot)")
-        else:
-            taken = [d["action"] for d in auto.get("decisions", ())
-                     if d.get("kind") == "taken"]
-            for name in expect_actions or ():
-                if name not in taken:
-                    tripped.append(
-                        f"expected autopilot action {name!r} was not "
-                        f"taken (taken: {taken or 'none'})")
-            if max_actions is not None and len(taken) > max_actions:
-                tripped.append(
-                    f"autopilot actions: {len(taken)} > {max_actions} "
-                    f"({taken})")
-            if auto.get("act_errors"):
-                tripped.append(
-                    f"autopilot actuator errors: {auto['act_errors']}")
     return tripped
 
 
@@ -470,24 +418,6 @@ def format_report(report: dict, tripped: list) -> str:
         if bl.get("unresolved_links"):
             lines.append(
                 f"  UNRESOLVED LINKS: {bl['unresolved_links']}")
-    tp = report.get("tuned_profile")
-    if tp:
-        lines.append(f"tuned profile applied: source={tp.get('source')} "
-                     f"knobs={tp.get('knobs')}  ({tp.get('path')})")
-    auto = report.get("autopilot")
-    if auto:
-        snap_ = auto.get("snapshot") or {}
-        lines.append(
-            f"autopilot: evals={snap_.get('evals')} "
-            f"decisions={snap_.get('decisions') or {}} "
-            f"dry_run={snap_.get('dry_run')} "
-            f"prefetch_depth={snap_.get('prefetch_depth')} "
-            f"wire={snap_.get('wire_dtype')}"
-            + (f"  act_errors={auto['act_errors']}"
-               if auto.get("act_errors") else ""))
-        for d in auto.get("decisions", ())[:12]:
-            lines.append(f"  [{d.get('kind')}] step {d.get('step')}: "
-                         f"{d.get('action')} — {d.get('reason')}")
     if report.get("spans"):
         import trace_merge
         lines.append("-- span summary --")
@@ -504,34 +434,10 @@ def format_report(report: dict, tripped: list) -> str:
 # self-contained mini-train mode (the CI health lane)
 # ---------------------------------------------------------------------------
 
-def _make_controller(trace_dir=None, ledger_path=None, dry_run=None,
-                     **targets):
-    """Build the autopilot controller the ``--autopilot`` mini-train
-    legs tick: targets from the leg, blame from the leg's own trace
-    dir (cumulative — fine for a mini run), audit records onto the
-    same ledger the run record goes to."""
-    from paddle_tpu.framework import autopilot as autopilot_mod
-    from paddle_tpu.framework import blame as blame_mod
-    from paddle_tpu.framework import runlog
-    blame_source = None
-    if trace_dir is not None:
-        def blame_source():
-            return blame_mod.compute_blame(
-                blame_mod.load_trace_dir(trace_dir))
-    return autopilot_mod.Controller(
-        blame_source=blame_source,
-        ledger=runlog.RunLedger(ledger_path) if ledger_path else None,
-        dry_run=dry_run, **targets)
-
-
 def mini_train(n_steps: int, trace_dir: str, numerics: bool = False,
-               nan_step: Optional[int] = None, nan_times: int = 1,
-               autopilot: bool = False,
-               autopilot_ledger: Optional[str] = None,
-               autopilot_dry_run: Optional[bool] = None):
+               nan_step: Optional[int] = None):
     """Run a traced, health-armed N-step mini train and return
-    ``(monitor.snapshot(), provenance-or-None, controller-or-None)``.
-    Fixed seeds and
+    ``(monitor.snapshot(), provenance-or-None)``.  Fixed seeds and
     shapes: a healthy run compiles exactly once per jit site and trips
     zero detectors — which is precisely what the CI gate asserts.
 
@@ -545,12 +451,7 @@ def mini_train(n_steps: int, trace_dir: str, numerics: bool = False,
     ``train.nan_skip`` flight event named that leaf (``aux_w``), the
     run must still finish on finite losses (skip-and-restore), and the
     grad-norm detector's baseline stays clean — the CI numerics lane's
-    seeded-NaN leg.  ``nan_times=K`` widens the poison into a K-step
-    storm (``every=1``) — the autopilot lane's trigger: with
-    ``autopilot=True`` a controller (scaler + resilient targets; a
-    ``GradScaler`` with ``decr_every=1`` is attached so the storm
-    produces a ``numerics.scale_collapse``) ticks every step, and its
-    decisions land on ``autopilot_ledger``."""
+    seeded-NaN leg."""
     import jax
     jax.config.update("jax_platforms", "cpu")
     import numpy as np
@@ -561,14 +462,12 @@ def mini_train(n_steps: int, trace_dir: str, numerics: bool = False,
     from paddle_tpu.framework import numerics as numerics_mod
     from paddle_tpu.framework.flags import get_flags, set_flags
     from paddle_tpu.framework.observability import flight, tracer
-    from paddle_tpu.framework.resilient import ResilientTrainStep
     from paddle_tpu.jit import TrainStep
 
     for signal, kw in health.DEFAULT_SIGNALS.items():
         health.watch(signal, **dict(kw))
     saved_flags = get_flags("numerics")
     provenance = None
-    ctl = None
     tracer.enable(trace_dir, label="health_check")
     try:
         paddle.seed(0)
@@ -590,54 +489,22 @@ def mini_train(n_steps: int, trace_dir: str, numerics: bool = False,
             params = net.parameters()
         else:
             set_flags({"numerics": True})
-            scaler = None
-            if autopilot:
-                # decr_every=1: every bad step downscales, so a
-                # >=4-step storm fires numerics.scale_collapse — the
-                # scaler.tighten policy's trigger; streak budget must
-                # outlast the storm so the CONTROLLER recovers, not a
-                # train.abort
-                from paddle_tpu.amp import GradScaler
-                net = _two_branch_net()
-                opt = paddle.optimizer.SGD(learning_rate=0.05,
-                                           parameters=net.parameters())
-                scaler = GradScaler(init_loss_scaling=2.0 ** 10,
-                                    decr_every_n_nan_or_inf=1)
-                step = ResilientTrainStep(
-                    TrainStep(net, _two_branch_loss, opt), scaler=scaler,
-                    max_consecutive_bad=max(10, nan_times * 2))
-                ctl = _make_controller(
-                    ledger_path=autopilot_ledger,
-                    dry_run=autopilot_dry_run,
-                    scaler=scaler, resilient=step)
-            else:
-                # the replay builder — incidents captured off this step
-                # carry the health_check:build_incident_step descriptor
-                step = build_incident_step(seed=0, lr=0.05)
-                net = step.step.model
+            # the replay builder — incidents captured off this step
+            # carry the health_check:build_incident_step descriptor
+            step = build_incident_step(seed=0, lr=0.05)
+            net = step.step.model
             x = paddle.to_tensor(rng.standard_normal((16, 8))
                                  .astype(np.float32))
             z = paddle.to_tensor(rng.standard_normal((4,))
                                  .astype(np.float32))
             y = paddle.to_tensor(rng.standard_normal((16, 4))
                                  .astype(np.float32))
-            if nan_step is not None and nan_times == 1:
+            if nan_step is not None:
                 # poison ONLY the aux branch's input (payload index 1 =
                 # z): the NaN reaches exactly aux_w's gradient
                 chaos.arm("train.step_grads", mode="nan",
                           nth=int(nan_step), n_times=1, payload_index=1)
-            losses = []
-            for i in range(n_steps):
-                if nan_step is not None and nan_times > 1 and \
-                        i + 1 == nan_step:
-                    # storm variant, armed AT step K (nth+every don't
-                    # compose into "start at K"): every step from here
-                    # poisons the aux input, nan_times times
-                    chaos.arm("train.step_grads", mode="nan", every=1,
-                              n_times=int(nan_times), payload_index=1)
-                losses.append(float(step(x, z, y)))
-                if ctl is not None:
-                    ctl.tick()
+            losses = [float(step(x, z, y)) for _ in range(n_steps)]
             assert np.isfinite(losses[-1]), \
                 f"mini train did not recover: {losses[-5:]}"
             if nan_step is not None:
@@ -653,7 +520,7 @@ def mini_train(n_steps: int, trace_dir: str, numerics: bool = False,
                               "nan_skips": len(skips),
                               "grad_anomalies": ga,
                               "ok": bool(skips) and got == "aux_w"
-                              and step.skipped_steps == int(nan_times)
+                              and step.skipped_steps == 1
                               and ga >= 1}
             params = net.parameters()
         health.memory.sample(tags={
@@ -664,13 +531,10 @@ def mini_train(n_steps: int, trace_dir: str, numerics: bool = False,
             set_flags(saved_flags)
             chaos.disarm("train.step_grads")
             numerics_mod.reset()
-    return monitor.snapshot(), provenance, ctl
+    return monitor.snapshot(), provenance
 
 
-def mini_train_ps(n_steps: int, trace_dir: str,
-                  autopilot: bool = False,
-                  autopilot_ledger: Optional[str] = None,
-                  autopilot_dry_run: Optional[bool] = None):
+def mini_train_ps(n_steps: int, trace_dir: str):
     """PS-backed mini-train leg: the same decision surface as
     :func:`mini_train`, but the embedding rows live on an in-process
     ``PsServer`` reached over localhost TCP, so the run exercises (and
@@ -680,13 +544,7 @@ def mini_train_ps(n_steps: int, trace_dir: str,
     warmup adopts it (this run's gates stay green), and only the
     cross-run ledger compare (``tools/perf_report.py compare``) can see
     it — which is exactly what that lane proves.  Deterministic: fixed
-    seeds, fixed shapes, sync mode, no prefetch.
-
-    ``autopilot=True`` attaches a controller over the PS step (prefetch
-    + wire actuators, blame from this leg's own trace dir) and ticks it
-    every step; the loop also ANNOUNCES the next batch's ids each step,
-    so a controller that deepens prefetch mid-run actually engages the
-    pipeline (a no-op while depth stays 0)."""
+    seeds, fixed shapes, sync mode, no prefetch."""
     import jax
     jax.config.update("jax_platforms", "cpu")
     import numpy as np
@@ -717,14 +575,9 @@ def mini_train_ps(n_steps: int, trace_dir: str,
         emb = DistributedEmbedding(
             256, 9, mode="sync",
             table=RemoteEmbeddingTable(cli, "emb", 9))
-        # autopilot leg: a model heavy enough (~5ms compute/step on
-        # CPU) that a RECOVERED step is compute-dominated — the
-        # --blame-tail gate can then tell "storm hidden" from "storm
-        # still raging" by the ps_wait share alone
-        hidden = (256, 256, 256) if autopilot else (16,)
-        bs = 256 if autopilot else 8
+        bs = 8
         model = WideDeepHost(embedding_dim=8, num_fields=4, dense_dim=3,
-                             hidden=hidden)
+                             hidden=(16,))
         opt = optimizer.Adam(learning_rate=1e-2,
                              parameters=model.parameters())
 
@@ -734,25 +587,13 @@ def mini_train_ps(n_steps: int, trace_dir: str,
 
         step = PSTrainStep(model, loss_fn, opt, emb,
                            transfer_dtype="float32", prefetch_depth=0)
-        ctl = None
-        if autopilot:
-            ctl = _make_controller(trace_dir=trace_dir,
-                                   ledger_path=autopilot_ledger,
-                                   dry_run=autopilot_dry_run,
-                                   step=step, client=cli)
         rng = np.random.default_rng(3)
         ids = rng.integers(0, 256,
                            size=(n_steps, bs, 4)).astype(np.int64)
         x = paddle.to_tensor(rng.standard_normal((bs, 3))
                              .astype(np.float32))
         y = paddle.to_tensor(rng.random((bs, 1)).astype(np.float32))
-        losses = []
-        for n in range(n_steps):
-            if ctl is not None and n + 1 < n_steps:
-                step.prefetch(ids[n + 1])
-            losses.append(float(step(ids[n], x, y)))
-            if ctl is not None:
-                ctl.tick()
+        losses = [float(step(ids[n], x, y)) for n in range(n_steps)]
         assert all(np.isfinite(losses)), \
             f"PS mini train diverged: {losses[-5:]}"
         step.flush()
@@ -762,7 +603,7 @@ def mini_train_ps(n_steps: int, trace_dir: str,
         finally:
             srv.shutdown()
             tracer.disable()
-    return monitor.snapshot(), None, ctl
+    return monitor.snapshot(), None
 
 
 def mini_train_zero(n_steps: int, trace_dir: str, wire: str = "f32",
@@ -824,7 +665,7 @@ def mini_train_zero(n_steps: int, trace_dir: str, wire: str = "f32",
                           for p in model.parameters())})
     finally:
         tracer.disable()
-    return monitor.snapshot(), None, None
+    return monitor.snapshot(), None
 
 
 def main(argv=None) -> int:
@@ -871,32 +712,6 @@ def main(argv=None) -> int:
                     help="--zero option: use the fused chunked-ring "
                          "collectives (parallel/ring.py) instead of "
                          "the native psum_scatter/all_gather pair")
-    ap.add_argument("--nan-storm", type=int, default=None, metavar="T",
-                    help="mini-train option (with --nan-step K): widen "
-                         "the poison into a T-step storm starting at "
-                         "K — the autopilot lane's numerics trigger")
-    ap.add_argument("--autopilot", action="store_true",
-                    help="mini-train option: attach the runtime "
-                         "controller (framework/autopilot.py) to the "
-                         "leg's targets and tick it every step; its "
-                         "snapshot + decision audit join the report")
-    ap.add_argument("--autopilot-dry-run", action="store_true",
-                    help="autopilot option: compute and record "
-                         "decisions but mutate nothing")
-    ap.add_argument("--expect-action", action="append", default=None,
-                    metavar="NAME",
-                    help="gate (repeatable): the autopilot must have "
-                         "TAKEN an action with this name, e.g. "
-                         "--expect-action prefetch.deepen")
-    ap.add_argument("--max-actions", type=int, default=None,
-                    help="gate: tolerated autopilot actions taken "
-                         "(0 = a clean run must leave the knobs "
-                         "alone)")
-    ap.add_argument("--blame-tail", type=int, default=None, metavar="N",
-                    help="compute blame over only the last N steps' "
-                         "spans — gates recovery (did the top category "
-                         "return to compute AFTER the controller "
-                         "acted) instead of the cumulative average")
     ap.add_argument("--ledger", default=None, metavar="PATH",
                     help="append a RunRecord (runlog.capture) for this "
                          "mini train to the run ledger at PATH — the "
@@ -952,41 +767,25 @@ def main(argv=None) -> int:
                  "mini-train legs — run them as separate invocations")
     if (a.zero_ring or a.zero_wire != "f32") and not a.zero:
         ap.error("--zero-wire/--zero-ring are --zero options")
-    if a.autopilot and a.zero:
-        ap.error("--autopilot has no actuators on the --zero leg")
     if a.ledger is not None and a.mini_train is None:
         ap.error("--ledger records a mini train; pass --mini-train")
-    if a.autopilot and a.mini_train is None:
-        ap.error("--autopilot is a mini-train option")
-    if a.autopilot and not (a.ps or a.numerics):
-        ap.error("--autopilot needs targets: run the --ps leg "
-                 "(prefetch/wire actuators) or the --numerics leg "
-                 "(scaler/resilient actuators)")
-    if a.nan_storm is not None and a.nan_step is None:
-        ap.error("--nan-storm widens --nan-step; pass both")
 
     health_snapshot = None
     provenance = None
-    ctl = None
     if a.mini_train is not None:
         if a.trace_dir is None:
             tmp = tempfile.TemporaryDirectory(prefix="health_check_")
             a.trace_dir = tmp.name          # kept alive by the local ref
         if a.ps:
-            snap, provenance, ctl = mini_train_ps(
-                a.mini_train, a.trace_dir, autopilot=a.autopilot,
-                autopilot_ledger=a.ledger,
-                autopilot_dry_run=a.autopilot_dry_run or None)
+            snap, provenance = mini_train_ps(a.mini_train, a.trace_dir)
         elif a.zero:
-            snap, provenance, ctl = mini_train_zero(
+            snap, provenance = mini_train_zero(
                 a.mini_train, a.trace_dir, wire=a.zero_wire,
                 ring=a.zero_ring)
         else:
-            snap, provenance, ctl = mini_train(
+            snap, provenance = mini_train(
                 a.mini_train, a.trace_dir, numerics=a.numerics,
-                nan_step=a.nan_step, nan_times=a.nan_storm or 1,
-                autopilot=a.autopilot, autopilot_ledger=a.ledger,
-                autopilot_dry_run=a.autopilot_dry_run or None)
+                nan_step=a.nan_step)
         from paddle_tpu.framework import health
         health_snapshot = health.snapshot()
     else:
@@ -994,26 +793,16 @@ def main(argv=None) -> int:
 
     report = build_report(snap, trace_dir=a.trace_dir,
                           health_snapshot=health_snapshot,
-                          blame_tail=a.blame_tail,
                           step_span="zero.step" if a.zero
                           else "train.step")
     if provenance is not None:
         report["numerics"]["provenance"] = provenance
-    if ctl is not None:
-        from paddle_tpu.framework import monitor as monitor_mod
-        report["autopilot"] = {
-            "snapshot": ctl.snapshot(),
-            "decisions": list(ctl.decisions),
-            "act_errors": int(monitor_mod.get_stat(
-                "autopilot_act_errors_total") or 0)}
     tripped = evaluate_gates(
         report, max_anomalies=a.max_anomalies,
         max_steady_recompiles=a.max_steady_recompiles,
         max_input_stall=a.max_input_stall,
         max_grad_anomalies=a.max_grad_anomalies,
-        max_blame=max_blame,
-        expect_actions=a.expect_action,
-        max_actions=a.max_actions)
+        max_blame=max_blame)
     report["tripped"] = tripped
     if a.ledger is not None:
         # one RunRecord per mini train, appended AFTER the gates ran so

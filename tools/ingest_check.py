@@ -35,9 +35,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 # op_bench-style thresholds: explicit, asserted, sized for a noisy
-# 2-core CI host (bench.py measured 6.8x cache speedup and 0.36% stall
-# on this box — these floors catch a broken cache or a serialized
-# pipeline, not run-to-run jitter)
+# 2-core CI host: these floors catch a broken cache or a serialized
+# pipeline, not run-to-run jitter
 CACHE_SPEEDUP_MIN = 1.3   # epoch-2 rate / epoch-1 rate
 STALL_PCT_MAX = 25.0      # consumer wait share with compute overlapped
 N_IMAGES, IMG_SIZE, CROP, BATCH = 48, 96, 64, 8

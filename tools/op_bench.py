@@ -16,8 +16,7 @@ Usage:
 
 Config entries: {"name", "op" (dotted path under paddle_tpu),
 "args" ([{shape, dtype, low?, high?} or scalar]), "kwargs"?, "grad"?}.
-Timings use a device->host fetch as the execution fence (see bench.py
-_sync).
+Timings use a device->host fetch as the execution fence.
 """
 from __future__ import annotations
 
@@ -880,9 +879,8 @@ def main(argv=None):
                     help="allowed relative slowdown vs baseline")
     ap.add_argument("--thresholds",
                     help="per-op threshold JSON ({op: allowed_slowdown}, "
-                         "sized from a measured run-to-run distribution — "
-                         "see perf/variance_study.py); falls back to "
-                         "--threshold for ops not listed")
+                         "sized from a measured run-to-run distribution); "
+                         "falls back to --threshold for ops not listed")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--repeats", type=int, default=3,
                     help="timing passes per op; the min is reported "
@@ -949,11 +947,10 @@ def main(argv=None):
     if a.ledger:
         # ms per op plus wire_mb where measured; RunLedger.append never
         # raises, so the gate below still runs on a broken ledger disk.
-        # Label per suite VARIANT and skip the registry snapshot (the
-        # bench.py discipline): the legs are the cross-run series, and
-        # a process-cumulative counter snapshot would differ wildly
-        # between variants sharing one ledger — a self-flagged
-        # "regression" on a healthy machine
+        # Label per suite VARIANT and skip the registry snapshot: the
+        # legs are the cross-run series, and a process-cumulative
+        # counter snapshot would differ wildly between variants sharing
+        # one ledger — a self-flagged "regression" on a healthy machine
         from paddle_tpu.framework import runlog
         variant = "ps_transport" if a.ps_transport else \
             "zero_collectives" if a.zero_collectives else \

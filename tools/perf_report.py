@@ -11,7 +11,7 @@ run ledger (``paddle_tpu/framework/runlog.py``) records for:
   op-profile: per span name count / mean / p99 ms, and for the step
   program an achieved FLOP/s + bytes/s against the analytic totals,
   with the top-k PTA106 ops carrying a measured ms attributed from the
-  step span by flop share.  Emitted as JSON (the autotune input) and a
+  step span by flop share.  Emitted as JSON and a
   roofline-style text table.  ``--mini-train N`` is the self-contained
   form (traced N-step train + ``analyze()`` in-process); ``--trace-dir
   + --cost-json`` joins existing artifacts.  ``--check`` gates that
@@ -44,10 +44,6 @@ run ledger (``paddle_tpu/framework/runlog.py``) records for:
   (``blame_<cat>_ms`` series from each record's summary), so a
   bottleneck SHIFT at flat step time is a named regression.
 
-* ``import`` — fold historical driver ``BENCH_r*.json`` artifacts into
-  a ledger as ``imported_bench`` records, so the bench trajectory
-  becomes a first-class compare series.
-
 * ``incidents`` — the postmortem plane's index: list ``kind=incident``
   ledger records (one per auto-captured bundle —
   ``framework/incident.py``) joined by incident id with the
@@ -62,7 +58,6 @@ Usage::
     python tools/perf_report.py blame --mini-train 12 --check
     python tools/perf_report.py blame --trace-dir /tmp/tr --expect-top ps_wait
     python tools/perf_report.py compare --ledger runs/ledger.jsonl
-    python tools/perf_report.py import BENCH_r0*.json --ledger runs/hist.jsonl
     python tools/perf_report.py incidents --ledger runs/ledger.jsonl --json inc.json
 """
 from __future__ import annotations
@@ -653,26 +648,6 @@ def _cmd_compare(a) -> int:
     return 1 if len(result["regressions"]) > a.max_regressions else 0
 
 
-def _cmd_import(a) -> int:
-    from paddle_tpu.framework.runlog import (RunLedger,
-                                             import_bench_file)
-    ledger = RunLedger(a.ledger)
-    imported = 0
-    for path in a.files:
-        rec = import_bench_file(path)
-        if rec is None:
-            print(f"perf_report import: {path}: no parseable bench "
-                  "legs — skipped", file=sys.stderr)
-            continue
-        if ledger.append(rec):
-            imported += 1
-            print(f"imported {os.path.basename(path)}: "
-                  f"{len(rec['legs'])} leg(s)")
-    print(f"perf_report import: {imported}/{len(a.files)} file(s) -> "
-          f"{a.ledger}")
-    return 0 if imported else 1
-
-
 def incident_rows(records: List[dict],
                   kind: Optional[str] = None) -> List[dict]:
     """Join ``kind=incident`` ledger records (the capture plane's index)
@@ -770,8 +745,7 @@ def main(argv=None) -> int:
     at.add_argument("--top-k", type=int, default=5,
                     help="op rows to attribute (default 5)")
     at.add_argument("--json", default=None, metavar="PATH",
-                    help="write the joined profile JSON here (the "
-                         "autotune input)")
+                    help="write the joined profile JSON here")
     at.add_argument("--check", action="store_true",
                     help="gate: every top-k op must have a positive "
                          "measured ms and finite achieved FLOP/s")
@@ -835,13 +809,6 @@ def main(argv=None) -> int:
     inc.add_argument("--json", default=None, metavar="PATH",
                      help="write the joined rows JSON here")
 
-    im = sub.add_parser("import",
-                        help="fold historical BENCH_r*.json artifacts "
-                             "into a ledger as imported_bench records")
-    im.add_argument("files", nargs="+", help="BENCH_r*.json paths")
-    im.add_argument("--ledger", required=True,
-                    help="run ledger JSONL to append into")
-
     a = ap.parse_args(argv)
     if a.cmd == "attribute":
         return _cmd_attribute(a)
@@ -849,9 +816,7 @@ def main(argv=None) -> int:
         return _cmd_blame(a)
     if a.cmd == "compare":
         return _cmd_compare(a)
-    if a.cmd == "incidents":
-        return _cmd_incidents(a)
-    return _cmd_import(a)
+    return _cmd_incidents(a)
 
 
 if __name__ == "__main__":

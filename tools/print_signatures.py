@@ -82,7 +82,6 @@ MODULES = [
     "paddle_tpu.framework.numerics",
     "paddle_tpu.framework.runlog",
     "paddle_tpu.framework.collector",
-    "paddle_tpu.framework.autopilot",
     "paddle_tpu.framework.incident",
     "paddle_tpu.framework.locks",
     "paddle_tpu.framework.analysis.concurrency",
