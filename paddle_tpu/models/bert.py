@@ -196,10 +196,17 @@ def _bert_forward(cfg, has_tt, has_mask, wte, wpe, wtt, emb_ln_w, emb_ln_b,
                     # mask rides as (B,1,1,S) bias tiles so padded
                     # batches stay O(S·D)
                     from paddle_tpu.ops.pallas import flash_attention as _fa
-                    a = _fa.flash_attention(
-                        q.reshape(b, s, nh, hd), k.reshape(b, s, nh, hd),
-                        v.reshape(b, s, nh, hd), scale=scale, bias=bias,
-                        bias_grad=False)
+                    from paddle_tpu.parallel.mesh import per_device
+                    kernel = partial(_fa.flash_attention, scale=scale,
+                                     bias=bias, bias_grad=False)
+                    if bias is None:
+                        # GSPMD cannot partition a Mosaic call: under a
+                        # mesh each device runs its own batch x heads
+                        kernel = per_device(kernel, get_mesh(),
+                                            ("dp", None, "mp", None))
+                    a = kernel(q.reshape(b, s, nh, hd),
+                               k.reshape(b, s, nh, hd),
+                               v.reshape(b, s, nh, hd))
                     a = a.reshape(b, s, H)
                 else:
                     q = q.reshape(b, s, nh, hd).transpose(0, 2, 1, 3)

@@ -107,6 +107,15 @@ monitor.describe("flash_subtiles_skipped_total",
                  "that the flash kernels' two-level loop nest leaves out, "
                  "over batch x heads, added once per traced kernel call (a "
                  "trace-time count)")
+monitor.describe("flash_dispatch_kernel_total",
+                 "attention calls that flash_attention.supported() sent to "
+                 "the Pallas kernels, added once per decision, which a "
+                 "model takes while it is traced (a trace-time count)")
+monitor.describe("flash_dispatch_xla_for_speed_total",
+                 "attention calls the Pallas kernels could have computed "
+                 "that flash_attention.supported() left to XLA because it "
+                 "is faster at their shape (not: incapable), added once "
+                 "per decision (a trace-time count)")
 
 
 def _canon_bias_shape(bias_shape, b, h, sq, sk):
@@ -127,14 +136,11 @@ def _canon_bias_shape(bias_shape, b, h, sq, sk):
     return (bb, hb, sqb, skb)
 
 
-def supported(q_shape, k_shape, no_mask: bool = True, causal: bool = False,
-              bias_shape=None, segments: bool = False) -> bool:
-    """Can the Pallas kernel serve this attention call?
-
-    ``no_mask`` is the legacy round-2 argument: a mask used to force the
-    XLA fallback.  Now a mask is fine as long as it is expressible as a
-    canonical additive bias (``bias_shape``) and/or segment ids.
-    """
+def _capable(q_shape, k_shape, no_mask, causal, bias_shape,
+             segments) -> bool:
+    """Can the kernels compute this call at all?  Backend, rank, head
+    width, a mask they can express, block divisibility of a masked call;
+    nothing here is about speed."""
     if not no_mask and bias_shape is None and not segments:
         return False
     if not (backend_is_tpu() or _INTERPRET):
@@ -146,15 +152,6 @@ def supported(q_shape, k_shape, no_mask: bool = True, causal: bool = False,
     if causal and sq > sk:
         # end-aligned causal with more queries than keys leaves rows with
         # no visible key; semantics degenerate — use the XLA path
-        return False
-    if not _INTERPRET and not causal and sq < 1024 and sk < 1024:
-        # empirical dispatch crossover (BERT-base class, bf16, one chip):
-        # XLA's fused attention wins short non-causal sequences (S=128:
-        # 146k vs 97k tok/s in-model; S=512: 104k vs 97k), the kernel wins
-        # from S≈2048 (58.8k vs 53.4k) and dominates at 8k+ where the XLA
-        # path hits its O(S²) HBM cliff.  Causal configs always take the
-        # kernel (S=1024 in-model win); how much of the masked half it
-        # skips depends on the tile: see ``_sub_tiles``.
         return False
     if d % 128 != 0 and d not in (64,):
         return False
@@ -175,6 +172,70 @@ def supported(q_shape, k_shape, no_mask: bool = True, causal: bool = False,
     # backward contractions); sub-block sequences still fall back to XLA
     return sq >= _MIN_BLOCK and sk >= _MIN_BLOCK
 
+
+# The sequence lengths at which a square, mask-free, non-causal call of
+# d = 64 runs faster on the kernels than on XLA's attention with a
+# materialised score tensor.  Measured in the model (my chip run, PR 31:
+# BERT-base, bf16, per-layer remat, 16,384 tokens a step on one v5e,
+# ``tools/flash_gate_sweep.py``; XLA against the kernels, ms a step; 42 and
+# 21 sequences, 16,128 tokens, at S = 384 and 768):
+#
+#     S    step             attn core        tokens/s
+#   128   106.9 / 157.5     8.3 / 60.9      152,994 / 103,813
+#   256   119.0 / 146.9    18.0 / 50.0      137,404 / 111,256
+#   384   132.3 / 145.5    34.5 / 49.6      121,655 / 110,618
+#   512   146.0 / 141.9    45.8 / 44.3      111,986 / 115,247
+#   768   163.6 / 165.1    66.0 / 68.8       98,400 /  97,497
+#
+# The kernels' own time at S = 512 is 29.0 ms of that core (forward 0.50,
+# dq 0.64, dk/dv 0.77 ms a layer at the table's (512, 512) tiles); the
+# other 15 ms are the ``_fold`` / ``_unfold`` transposes and ``delta``
+# around them, which do not shrink with S, and a head's grid step, which
+# S = 128 pays 1,536 times a call.  S = 768 has no tile that covers its
+# square ((256, 256), which at S = 512 costs 1.6 times the (512, 512)
+# one): tune it before it is listed.
+_FULL_D64_FASTER_AT = (512,)
+
+
+def _faster_than_xla(sq: int, sk: int, d: int, causal: bool,
+                     masked: bool) -> bool:
+    """Is the kernel the faster of the two paths for a call it can
+    compute?  A pure function of what the call shows when it is traced.
+    Causal calls and calls with 1024 queries or keys or more keep the
+    kernel (the GPT-2 and hybrid cells; XLA's O(S^2) score tensor only
+    grows).  Below that, only what ``_FULL_D64_FASTER_AT`` was measured
+    for: a bias or segment ids, a tail (a ViT's S = 197), sq != sk and
+    d = 128 under 1024 were not swept and stay on XLA, as before PR 31."""
+    if causal or sq >= 1024 or sk >= 1024:
+        return True
+    return not masked and sq == sk and d == 64 \
+        and sq in _FULL_D64_FASTER_AT
+
+
+def supported(q_shape, k_shape, no_mask: bool = True, causal: bool = False,
+              bias_shape=None, segments: bool = False) -> bool:
+    """Should the Pallas kernels serve this attention call?  They must be
+    able to (``_capable``) and be the faster path (``_faster_than_xla``);
+    each decision on speed is counted, once per call of this function,
+    which the models make while they are traced.
+
+    ``no_mask`` is the legacy round-2 argument: a mask used to force the
+    XLA fallback.  Now a mask is fine as long as it is expressible as a
+    canonical additive bias (``bias_shape``) and/or segment ids.
+    Interpret mode (the CPU tests) takes every capable call to the
+    kernels, whatever its size.
+    """
+    if not _capable(q_shape, k_shape, no_mask, causal, bias_shape,
+                    segments):
+        return False
+    if _INTERPRET:
+        return True
+    faster = _faster_than_xla(q_shape[1], k_shape[1], q_shape[3],
+                              bool(causal),
+                              bias_shape is not None or bool(segments))
+    monitor.stat_add("flash_dispatch_kernel_total" if faster
+                     else "flash_dispatch_xla_for_speed_total", 1)
+    return faster
 
 
 def _pick_block(pref: int, seq: int) -> int:

@@ -42,6 +42,7 @@ Names in a device trace (``start_profiler`` or a bare
   forward that ``jax.checkpoint`` runs again.
 * counters (``framework.monitor``), added once per traced call, so
   trace-time counts: ``flash_subtiles_computed_total`` / ``_skipped_total``
+  and ``flash_dispatch_kernel_total`` / ``_xla_for_speed_total``
   (``ops/pallas/flash_attention.py``), ``moe_calls_traced_total``,
   ``moe_expert_rows_computed_total``, ``moe_expert_rows_expected_total``
   (``nn/functional/moe.py``), ``ssm_chunks_traced_total``

@@ -163,6 +163,90 @@ def test_bert_forward_and_train():
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
 
 
+def test_bert_remat_scan_flash_kernel_matches_xla_attention(monkeypatch):
+    """BERT's layers run under ``lax.scan`` with per-layer remat, so the
+    flash kernels are called from a scanned, checkpointed body: forward,
+    forward again, dq, dk/dv.  The loss and every gradient agree with
+    the XLA attention path (the kernels interpreted, non-causal, on the
+    two-level nest)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    set_mesh(make_mesh({"dp": 1}))
+    calls = []
+    kernel = fa.flash_attention
+
+    def spy(q, *a, **kw):
+        calls.append(tuple(q.shape))
+        return kernel(q, *a, **kw)
+
+    monkeypatch.setattr(fa, "flash_attention", spy)
+    B, S = 2, 128
+    rng = np.random.default_rng(3)
+    ids = _batch(256, B=B, S=S)
+    mlm = np.where(rng.random((B, S)) < 0.15, ids, -100).astype(np.int32)
+    nsp = rng.integers(0, 2, size=(B,)).astype(np.int32)
+
+    def loss_and_grads(interpret):
+        monkeypatch.setattr(fa, "_INTERPRET", interpret)
+        model = Bert(bert_tiny(hidden_size=128, num_heads=2, num_layers=2,
+                               max_seq_len=S, remat=True, seed=5))
+        loss = bert_pretrain_loss(model, paddle.to_tensor(ids),
+                                  paddle.to_tensor(mlm),
+                                  paddle.to_tensor(nsp))
+        loss.backward()
+        return float(loss), {n: p.grad.numpy()
+                             for n, p in model.named_parameters()}
+
+    want, want_grads = loss_and_grads(False)
+    assert not calls                         # the CPU: XLA's attention
+    got, got_grads = loss_and_grads(True)
+    assert calls and set(calls) == {(B, S, 2, 64)}
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert got_grads.keys() == want_grads.keys()
+    for name, g in want_grads.items():
+        np.testing.assert_allclose(got_grads[name], g, rtol=2e-3,
+                                   atol=2e-5 * max(1.0, np.abs(g).max()),
+                                   err_msg=name)
+
+
+def test_bert_flash_kernel_runs_per_device_under_a_mesh(monkeypatch):
+    """Since PR 31 BERT's mask-free call at S = 512 goes to the kernels on
+    the chip, so under dp > 1 it must run on each device's batch shard,
+    as GPT's does, and train like one-device XLA attention."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+    local_batches = []
+    kernel = fa.flash_attention
+
+    def spy(q, *a, **kw):
+        local_batches.append(q.shape[0])
+        return kernel(q, *a, **kw)
+
+    monkeypatch.setattr(fa, "flash_attention", spy)
+    B, S = 4, 128
+    rng = np.random.default_rng(4)
+    ids = _batch(256, B=B, S=S)
+    mlm = np.where(rng.random((B, S)) < 0.15, ids, -100).astype(np.int32)
+    nsp = rng.integers(0, 2, size=(B,)).astype(np.int32)
+
+    def losses(mesh_axes, flash):
+        mesh = make_mesh(mesh_axes)
+        set_mesh(mesh)
+        model = Bert(bert_tiny(hidden_size=128, num_heads=2, num_layers=1,
+                               max_seq_len=S, remat=True,
+                               use_flash_attention=flash))
+        opt = optimizer.AdamW(learning_rate=1e-3,
+                              parameters=model.parameters())
+        step = ShardedTrainStep(model, bert_pretrain_loss, opt, mesh=mesh)
+        return [float(step(paddle.to_tensor(ids), paddle.to_tensor(mlm),
+                           paddle.to_tensor(nsp))) for _ in range(2)]
+
+    want = losses({"dp": 1}, flash=False)
+    assert not local_batches
+    got = losses({"dp": 2}, flash=True)
+    assert local_batches and set(local_batches) == {B // 2}
+    np.testing.assert_allclose(got, want, rtol=2e-3)
+
+
 def test_gpt_hlo_has_hybrid_collectives():
     mesh = make_mesh({"dp": 2, "mp": 4})
     set_mesh(mesh)

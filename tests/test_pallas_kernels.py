@@ -554,3 +554,167 @@ class TestTwoLevelTiling:
             assert traced(grad=True) == (3 + 10 + 36, 1 + 6 + 28)
         text = monitor.export_prometheus()
         assert "above the causal diagonal" in text
+
+
+def _parent_faster(sq, sk, causal):
+    """The speed gate as it stood before PR 31: every non-causal call
+    under 1024 x 1024 to XLA, whatever else it shows."""
+    return causal or sq >= 1024 or sk >= 1024
+
+
+# (what the call is, q shape, k shape, supported()'s other arguments, the
+# answer).  The first block is what PR 31's in-model sweep measured (BERT-
+# base, 16,384 tokens a step, one v5e chip; PERF.md section 6); every other
+# call answers as before it.
+_BERT = lambda s: (16384 // s, s, 12, 64)
+_GATE_CASES = [
+    ("bert_s512_full", _BERT(512), _BERT(512), {}, True),
+    ("bert_s128_full", _BERT(128), _BERT(128), {}, False),
+    ("bert_s256_full", _BERT(256), _BERT(256), {}, False),
+    ("bert_s384_full", _BERT(384), _BERT(384), {}, False),
+    ("bert_s768_full", _BERT(768), _BERT(768), {}, False),
+    ("full_s1024", (8, 1024, 16, 64), (8, 1024, 16, 64), {}, True),
+    ("gpt2_causal_s1024", (8, 1024, 16, 64), (8, 1024, 16, 64),
+     {"causal": True}, True),
+    ("hybrid_causal_s4096_d128", (1, 4096, 4, 128), (1, 4096, 4, 128),
+     {"causal": True}, True),
+    ("causal_s512", (4, 512, 4, 64), (4, 512, 4, 64), {"causal": True},
+     True),
+    ("padding_bias_s512", (32, 512, 12, 64), (32, 512, 12, 64),
+     {"no_mask": False, "bias_shape": (32, 1, 1, 512)}, False),
+    ("padding_bias_s2048", (2, 2048, 12, 64), (2, 2048, 12, 64),
+     {"no_mask": False, "bias_shape": (2, 1, 1, 2048)}, True),
+    ("segments_s512", (32, 512, 12, 64), (32, 512, 12, 64),
+     {"no_mask": False, "segments": True}, False),
+    ("vit_tail_s197", (64, 197, 12, 64), (64, 197, 12, 64), {}, False),
+    ("tail_s500", (8, 500, 12, 64), (8, 500, 12, 64), {}, False),
+    ("cross_256_512", (8, 256, 12, 64), (8, 512, 12, 64), {}, False),
+    ("cross_512_2048", (8, 512, 12, 64), (8, 2048, 12, 64), {}, True),
+    ("d128_s512_full", (8, 512, 8, 128), (8, 512, 8, 128), {}, False),
+]
+
+
+class TestDispatchGate:
+    """``supported()`` on the chip as a pure function of the call's shape
+    and mask class: ``backend_is_tpu`` patched to true, interpret mode
+    off.  The capability half is covered above; here the speed half."""
+
+    @pytest.fixture(autouse=True)
+    def on_the_chip(self, monkeypatch):
+        monkeypatch.setattr(fa, "backend_is_tpu", lambda: True)
+        monkeypatch.setattr(fa, "_INTERPRET", False)
+
+    @pytest.mark.parametrize("q,k,kw,want", [c[1:] for c in _GATE_CASES],
+                             ids=[c[0] for c in _GATE_CASES])
+    def test_the_answer_at_a_shape(self, q, k, kw, want):
+        assert fa.supported(q, k, **kw) is want
+
+    def test_calls_the_sweep_did_not_cover_answer_as_the_parent(self):
+        """Every capable call outside (non-causal, no mask, square, d = 64,
+        S a multiple of 128 under 1024) answers as before PR 31."""
+        import itertools
+        for s_q, s_k, d, causal, mask in itertools.product(
+                (128, 197, 256, 320, 384, 512, 640, 768, 896, 1024, 2048),
+                (128, 256, 512, 768, 1024, 2048), (64, 128), (False, True),
+                ("none", "bias", "segments")):
+            q, k = (2, s_q, 4, d), (2, s_k, 4, d)
+            args = (mask == "none", causal,
+                    (1, 1, 1, s_k) if mask == "bias" else None,
+                    mask == "segments")
+            swept = not causal and mask == "none" and s_q == s_k \
+                and d == 64 and s_q % 128 == 0
+            if not fa._capable(q, k, *args):
+                assert not fa.supported(q, k, *args)
+            elif not swept:
+                assert fa.supported(q, k, *args) == \
+                    _parent_faster(s_q, s_k, causal), (q, k, args)
+
+    def test_the_gate_reads_shapes_only(self):
+        """No flag, environment variable or batch size moves the answer."""
+        import inspect
+        source = inspect.getsource(fa._faster_than_xla)
+        assert "environ" not in source and "get_flag" not in source
+        assert list(inspect.signature(fa._faster_than_xla).parameters) == [
+            "sq", "sk", "d", "causal", "masked"]
+        assert list(inspect.signature(fa.supported).parameters) == [
+            "q_shape", "k_shape", "no_mask", "causal", "bias_shape",
+            "segments"]
+        for batch in (1, 32, 1024):
+            assert fa.supported((batch, 512, 12, 64), (batch, 512, 12, 64))
+
+    def test_counters_move_once_per_traced_decision(self):
+        from paddle_tpu.framework import monitor
+        names = ("flash_dispatch_kernel_total",
+                 "flash_dispatch_xla_for_speed_total")
+        read = lambda: tuple(monitor.get_stat(n) for n in names)
+
+        @jax.jit
+        def layer(x):
+            # what a model does while it is traced
+            fa.supported(x.shape, x.shape)
+            return x + 1
+
+        before = read()
+        x512 = jnp.zeros((2, 512, 2, 64), jnp.bfloat16)
+        layer(x512), layer(x512)              # traced once, run twice
+        assert read() == (before[0] + 1, before[1])
+        layer(jnp.zeros((2, 128, 2, 64), jnp.bfloat16))
+        assert read() == (before[0] + 1, before[1] + 1)
+        # a call the kernels cannot compute is no decision on speed
+        assert not fa.supported((2, 512, 2, 100), (2, 512, 2, 100))
+        assert not fa.supported((2, 64, 2, 64), (2, 64, 2, 64))
+        assert read() == (before[0] + 1, before[1] + 1)
+        text = monitor.export_prometheus()
+        assert "left to XLA because it is faster" in text
+
+
+class TestBertCellGeometry:
+    """The non-causal two-level nest at the tiles ``flash_blocks.json``
+    ships for BERT-base's call at S = 512 (``512x512:d64:bfloat16:full:
+    nobias`` and its ``:bwd`` / ``:dkv`` entries), a few heads, interpret
+    mode: forward and dq / dk / dv against the float32 reference."""
+
+    def setup_method(self):
+        fa._INTERPRET = True
+
+    def teardown_method(self):
+        fa._INTERPRET = False
+
+    def test_the_table_has_verified_tiles_for_the_call(self):
+        from paddle_tpu.ops.pallas import autotune
+        table = autotune._load()
+        for suffix in ("", ":bwd", ":dkv"):
+            entry = table["512x512:d64:bfloat16:full:nobias" + suffix]
+            assert entry["verified"] is True
+            bq, bk = entry["blocks"]
+            assert 512 % bq == 0 and 512 % bk == 0
+            assert fa._two_level(512, 512, 64, jnp.bfloat16, bq, bk, False)
+
+    @pytest.mark.parametrize("direction", ["forward", "backward"])
+    def test_matches_the_reference_at_the_shipped_tiles(self, direction):
+        rng = np.random.default_rng(31)
+        B, S, H, D = 1, 512, 3, 64
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in _rand_qkv(rng, B, S, S, H, D))
+        scale = 1.0 / np.sqrt(D)
+        f32 = lambda x: np.asarray(x, dtype=np.float32)
+        flash = lambda q, k, v: fa.flash_attention(q, k, v, False, scale)
+        ref = lambda q, k, v: fa._xla_reference(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), scale, False)
+        loss = lambda attn: lambda q, k, v: (
+            attn(q, k, v).astype(jnp.float32) ** 2).sum()
+        if direction == "forward":
+            run = lambda attn: (attn(q, k, v),)
+            tol = dict(rtol=3e-2, atol=3e-2)
+        else:
+            run = lambda attn: jax.grad(loss(attn), argnums=(0, 1, 2))(
+                q, k, v)
+            tol = dict(rtol=5e-2, atol=1e-1)
+        grids = _kernel_grids(lambda: run(flash))
+        n = 1 if direction == "forward" else 3
+        # the nest: two grid axes, (batch x heads, blocks of the table)
+        assert len(grids) == n and all(len(g) == 2 for g in grids), grids
+        for a, b, name in zip(run(flash), run(ref),
+                              ("out",) if n == 1 else "qkv"):
+            np.testing.assert_allclose(f32(a), f32(b), err_msg=name, **tol)

@@ -4,6 +4,8 @@ the winners into paddle_tpu/ops/pallas/flash_blocks.json.
 
     python tools/flash_autotune.py                  # bench/model configs
     python tools/flash_autotune.py --sq 4096 --sk 4096 --d 128 --causal
+    python tools/flash_autotune.py --sq 512 --sk 512 --d 64 --split \
+        --batch 32 --heads 12                       # at a model's own call
 
 The shipped json is the measured cache the kernels consult at trace
 time; re-run this on new hardware generations.
@@ -23,6 +25,7 @@ DEFAULT_CONFIGS = [
     (8192, 8192, 128, "bfloat16", True, False),   # longseq 8k leg
     (2048, 2048, 64, "bfloat16", False, True),    # masked BERT-class
     (8192, 8192, 128, "bfloat16", True, True),    # packed longseq
+    (512, 512, 64, "bfloat16", False, False),     # BERT-base at S = 512
 ]
 
 
@@ -35,6 +38,8 @@ def main(argv=None) -> int:
     ap.add_argument("--causal", action="store_true")
     ap.add_argument("--biased", action="store_true")
     ap.add_argument("--iters", type=int, default=3)
+    ap.add_argument("--batch", type=int, default=1)
+    ap.add_argument("--heads", type=int, default=8)
     ap.add_argument("--split", action="store_true",
                     help="tune fwd, bwd and (two-level nest) dk/dv block "
                          "sizes independently, each kernel by its own "
@@ -64,6 +69,7 @@ def main(argv=None) -> int:
         rejected = {}
         if a.split:
             out = autotune.measure_split(sq, sk, d, dt, causal, biased,
+                                         batch=a.batch, heads=a.heads,
                                          iters=a.iters, verbose=True,
                                          rejected=rejected)
             if out is None:
@@ -74,6 +80,7 @@ def main(argv=None) -> int:
                       (f", dkv {dkv[0]}" if dkv else ""))
         else:
             out = autotune.measure(sq, sk, d, dt, causal, biased,
+                                   batch=a.batch, heads=a.heads,
                                    iters=a.iters, verbose=True,
                                    rejected=rejected)
             if out is None:

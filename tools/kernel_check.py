@@ -16,7 +16,8 @@ the module's own reference.  What it runs, with what the repo already has:
   the table shows what XLA's own bf16 path loses on the same input.  An
   output passes when its max abs error is within ``verify.SCALE_TOL`` of
   the largest reference value;
-* the benchmark's own call, (8, 16, 1024, 64) causal bf16, the same way;
+* the benchmark's own calls, (8, 16, 1024, 64) causal and (32, 12, 512,
+  64) non-causal bf16, the same way;
 * the two-level nest at the edge of its VMEM budget, S=1024 d=128 float32;
 * ``verify.check_flash_candidate`` (compiled vs interpret vs reference on
   ``verify.boundary_corpus``: the non-divisible tail paths and a square of
@@ -158,6 +159,10 @@ def flash_rows():
     # the benchmark's GPT-2 cells call the kernels at exactly this shape
     case("flash GPT-2 345M cell shape (8,16,1024,64) causal", 1024, 1024, 64,
          True, b=8, h=16)
+    # and bert_base.pretrain_b32_s512 at this one: no mask, the nest with
+    # no diagonal, at the table's 512x512:d64:full tiles
+    case("flash BERT-base cell shape (32,12,512,64) non-causal", 512, 512,
+         64, False, b=32, h=12)
     # the most the two-level nest keeps resident (its VMEM budget's edge)
     case("flash two-level nest at its budget: S=1024 d128 float32 causal",
          1024, 1024, 128, True, dtype=jnp.float32)
