@@ -185,11 +185,27 @@ def build_model(config: dict, sizes: dict, seed: int):
         **kwargs))
 
 
+class Feed:
+    """The cell's resident batches in turn: every unpacking, as in
+    ``step(*feed)``, hands the next one, round and round; with one batch,
+    the same one every time."""
+
+    def __init__(self, batches: list):
+        self.batches, self.turn = batches, 0
+
+    def __iter__(self):
+        batch = self.batches[self.turn % len(self.batches)]
+        self.turn += 1
+        return iter(batch)
+
+
 def _build(cell: dict, seed: int, devices):
     """Model, optimizer and step exactly as the configuration and traffic
     files say, on the cell's mesh; weights from the program's own
-    initialiser under ``seed``; one batch from ``seed`` resident on the
-    device (the input pipeline is bypassed on purpose)."""
+    initialiser under ``seed``; the traffic file's ``resident_batches``
+    (one, where it names none) from ``seed`` in one draw, resident on the
+    device (the input pipeline is bypassed on purpose).  Returns the
+    first batch's arrays, which the reference reads, and the feed."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -207,12 +223,14 @@ def _build(cell: dict, seed: int, devices):
         step_kwargs[traffic["step"]["mesh_kwarg"]] = mesh
     step = resolve(traffic["step"]["class"])(
         model, resolve(config["loss"]), optimizer, **step_kwargs)
-    arrays = resolve(config["inputs"])(seed, traffic["batch"],
-                                       traffic["seq"], sizes)
+    rows, count = traffic["batch"], traffic.get("resident_batches", 1)
+    drawn = resolve(config["inputs"])(seed, rows * count, traffic["seq"],
+                                      sizes)
     on_mesh = NamedSharding(mesh, PartitionSpec(tuple(traffic["batch_axes"])))
-    batch = [paddle.to_tensor(jax.device_put(a, on_mesh)) for a in arrays]
-    jax.block_until_ready([t.data for t in batch])
-    return model, step, arrays, batch
+    batches = [[paddle.to_tensor(jax.device_put(a[i:i + rows], on_mesh))
+                for a in drawn] for i in range(0, rows * count, rows)]
+    jax.block_until_ready([t.data for batch in batches for t in batch])
+    return model, step, tuple(a[:rows] for a in drawn), Feed(batches)
 
 
 def _warm_up(step, batch, compiles, phase) -> list:
@@ -270,22 +288,34 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(compiles)
     phase("import")
 
-    model, step, arrays, batch = _build(cell, seed, devices)
+    model, step, arrays, feed = _build(cell, seed, devices)
     tokens_per_step = traffic["batch"] * traffic["seq"]
     phase("build")
 
     reference = resolve(config["reference"])
-    reference_loss = reference.loss(
-        {n: p.data for n, p in model.named_parameters()}, arrays, sizes,
-        traffic["reference_block"])
+    params = {n: p.data for n, p in model.named_parameters()}
+    prepared = {}
+    if "router_bias" in config:
+        # what a checkpoint of such a router brings and a fresh draw
+        # lacks: solved on the reference's one walk, which goes on to
+        # the loss under it; the model gets it as a checkpoint would
+        reference_loss, solved, routing, prepared = resolve(
+            config["router_bias"])(reference, params, arrays, sizes,
+                                   traffic["reference_block"])
+        model.set_state_dict(solved)
+        say("routing: " + json.dumps(routing))
+    else:
+        reference_loss = reference.loss(params, arrays, sizes,
+                                        traffic["reference_block"])
+    del params
     phase("reference")
 
-    warmup = _warm_up(step, batch, compiles, phase)
+    warmup = _warm_up(step, feed, compiles, phase)
     misplaced = _state_on_chips(model, step, devices, platform)
 
     compiles_before = compiles.count
     setup_s = time.perf_counter() - t0
-    window = run_loop(step, batch, seconds)
+    window = run_loop(step, feed, seconds)
     compiled_in_window = compiles.count - compiles_before
     peak_bytes = 0 if rehearse else max(map(_peak_bytes, devices))
     if not rehearse:
@@ -296,12 +326,25 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
 
     first, losses = warmup[0]["loss"], window["losses"]
     rel = abs(first - reference_loss) / abs(reference_loss)
-    checks = {
-        "reference": rel <= reference.TOLERANCE_REL,
-        "finite_and_falling": all(map(math.isfinite, losses))
-        and losses[-1] < first,
-        "no_compile_in_window": compiled_in_window == 0,
-        "state_on_chips": misplaced is None}
+    failed = sum(not math.isfinite(x) for x in losses)
+    # each number compared beside its limit: `correct`, the `correct:`
+    # line and the result's `compared` all come from here.  A limit is
+    # the largest value that holds, but the last loss's, which is strict:
+    # on one resident batch a step that returns its state unchanged reads
+    # exactly 1 there; a cell fed fresh batches reads 1 give or take the
+    # batches' own difference, and names a limit under that
+    compared = {
+        "first_loss_rel_diff": (rel, reference.TOLERANCE_REL),
+        **prepared,
+        "last_loss_over_first": (
+            losses[-1] / first,
+            traffic.get("last_loss_over_first_limit", 1.0)),
+        "nonfinite_losses": (failed, 0),
+        "compiled_in_window": (compiled_in_window, 0),
+        "state_off_its_chips": (int(misplaced is not None), 0)}
+    checks = {name: (value < limit if name == "last_loss_over_first"
+                     else value <= limit)
+              for name, (value, limit) in compared.items()}
     say("set-up: " + json.dumps({
         "setup_s": round(setup_s, 3), "phases": phases, "warmup": warmup}))
     say("window: " + json.dumps({
@@ -316,13 +359,12 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
         **checks, "first_loss": first, "reference_loss": reference_loss,
         "rel_diff": float(f"{rel:.3g}"),
         "tolerance_rel": reference.TOLERANCE_REL, "last_loss": losses[-1],
-        "compiled_in_window": compiled_in_window, "misplaced": misplaced}))
+        "misplaced": misplaced}))
 
     device = {"platform": devices[0].platform, "kind": kind,
               "count": len(devices), "memory_peak_bytes": peak_bytes}
     result = {"correct": all(checks.values()),
-              "attempted": window["dispatched"],
-              "failed": sum(not math.isfinite(x) for x in losses),
+              "attempted": window["dispatched"], "failed": failed,
               "metrics": {}, "device": device}
     run = {"sizes": sizes, "traffic": traffic,
            "tokens_per_step": tokens_per_step, "peak": peak,
@@ -331,7 +373,7 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
            "peak_bytes": peak_bytes, "say": say}
     tr = None
     if trace:
-        tr = _traced_stretch(step, batch, workload, say)
+        tr = _traced_stretch(step, feed, workload, say)
         first_chip = tr.chips[0]
         lo, hi = tr.window[first_chip]
         if not rehearse:
@@ -349,6 +391,10 @@ def measure(workload: str, seed: int, seconds: float, trace: bool,
         if value is not None:
             result["metrics"][m["name"]] = {"value": float(value),
                                             "unit": m["unit"]}
+    # each number compared beside its limit, last in the line
+    result["compared"] = {
+        name: {"value": value, "limit": limit, "holds": checks[name]}
+        for name, (value, limit) in compared.items()}
     if rehearse:
         # a CPU number is never printed under the name of a device metric
         say("rehearsal on the CPU, not device numbers: "

@@ -4,10 +4,13 @@ over the time the three kernels took per step.
 
 Operations: FlashAttention-2's count.  Forward: Q K^T and P V, 2 matmuls;
 backward: recompute S, dP = dO V^T, dV = P^T dO, dK = dS^T Q, dQ = dS K,
-5 matmuls; each 2 * S * S * d FLOPs a head, halved for a causal mask.
+5 matmuls; each 2 * S * S * d FLOPs a head, halved for a causal mask and
+whole for full attention (BERT's mask-free call, PR 31).
 The program's backward is split into a dq and a dk/dv kernel that each
 recompute S and dP (9 matmuls executed); like recomputation in ``mfu``,
-the two extra are not counted, so the share is of the algorithm's need.
+the two extra are not counted, so the share is of the algorithm's need;
+nor is the forward kernel's second run where a layer is recomputed
+(``bert_base``: its time is in the divisor, its operations are not).
 Bytes: forward reads Q, K, V and writes O (bf16) and the log-sum-exp
 (float32); backward reads Q, K, V, O, dO and the log-sum-exp and writes
 dQ, dK, dV.  The softmax's exponentials are not counted."""

@@ -116,3 +116,11 @@ def flops_per_token(sizes: dict, seq: int) -> float:
                + sizes["vocab_size"] * h)
     attention = sizes["num_hidden_layers"] * 12 * seq * h
     return 6.0 * weights + attention
+
+
+def attention_shape(sizes: dict, batch: int, seq: int) -> tuple:
+    """(B, H, S, d, causal, layers) of the attention calls of one step:
+    full attention, no mask (the inputs carry no padding)."""
+    heads = sizes["num_attention_heads"]
+    return (batch, heads, seq, sizes["hidden_size"] // heads, False,
+            sizes["num_hidden_layers"])

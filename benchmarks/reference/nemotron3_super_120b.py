@@ -131,11 +131,21 @@ def attention(u, p, sizes):
     return (probs @ v).transpose(1, 0, 2).reshape(s, heads * d) @ p["a_o_w"]
 
 
+def _scores(u, p):
+    return 1.0 / (1.0 + jnp.exp(-(u @ p["e_router_w"].T)))
+
+
+def router_scores(x, p, sizes):
+    """``s`` (S, router width) of an ``E`` layer for the residual stream
+    ``x`` (S, hidden) that enters it: the scores before any bias."""
+    return _scores(_rms(x, p["e_norm"], sizes["norm_eps"]), p)
+
+
 def experts(u, p, sizes):
     """``f(u)`` of an ``E`` layer for ``u`` (S, hidden): the part the
     held experts give, plus the shared expert."""
     top_k, scale = sizes["num_experts_per_tok"], sizes["routed_scaling_factor"]
-    s = 1.0 / (1.0 + jnp.exp(-(u @ p["e_router_w"].T)))
+    s = _scores(u, p)
     _, sel = jax.lax.top_k(s + p["e_router_bias"], top_k)
     picked = jnp.take_along_axis(s, sel, axis=-1)
     g = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
@@ -172,13 +182,29 @@ def _own(p, kind, i):
     return {k: p[k][i] for k in _KINDS[kind]}
 
 
+def _walk(p: dict, ids_all, sizes: dict, layer, router_bias=None) -> list:
+    """The final hidden states (after the last norm), one a sequence of
+    ``ids_all``, layer by layer: the one walk of the pattern.
+
+    ``router_bias``, where given, is asked at every ``E`` layer, in the
+    pattern's order, for that layer's ``b_corr``: it gets the scores ``s``
+    (the batch's tokens, router width) the layer's router gives under the
+    biases of the layers before it, and what it returns takes the place
+    of the row ``p`` brings."""
+    xs, seen = [p["embed"][ids] for ids in ids_all], dict.fromkeys(_KINDS, 0)
+    for kind in sizes["hybrid_override_pattern"]:
+        own = _own(p, kind, seen[kind])
+        seen[kind] += 1
+        if kind == "E" and router_bias is not None:
+            own["e_router_bias"] = jnp.asarray(router_bias(jnp.concatenate(
+                [router_scores(x, own, sizes) for x in xs])), jnp.float32)
+        xs = [layer(kind, x, own, sizes) for x in xs]
+    return [_rms(x, p["norm_f"], sizes["norm_eps"]) for x in xs]
+
+
 def hidden(p: dict, ids, sizes: dict, layer=_layer):
     """The final hidden state (after the last norm) of one sequence."""
-    x, seen = p["embed"][ids], dict.fromkeys(_KINDS, 0)
-    for kind in sizes["hybrid_override_pattern"]:
-        x = layer(kind, x, _own(p, kind, seen[kind]), sizes)
-        seen[kind] += 1
-    return _rms(x, p["norm_f"], sizes["norm_eps"])
+    return _walk(p, [ids], sizes, layer)[0]
 
 
 def _loss_sum(h, head_w, ids):
@@ -186,10 +212,12 @@ def _loss_sum(h, head_w, ids):
     return -jnp.take_along_axis(logp, ids[1:, None], axis=-1).sum()
 
 
-def loss(params: dict, batch: tuple, sizes: dict, block: int) -> float:
+def loss(params: dict, batch: tuple, sizes: dict, block: int,
+         router_bias=None) -> float:
     """Mean next-token cross entropy of ``batch`` = (ids, labels) under
-    ``params``, a sequence at a time (``block`` is the harness's number
-    of sequences a block; every layer here takes one)."""
+    ``params``, layer by layer, within a layer a sequence at a time
+    (``block`` is the harness's number of sequences a block; every layer
+    here takes one).  ``router_bias``: see ``_walk``."""
     ids_all, labels_all = (np.asarray(a) for a in batch)
     if not np.array_equal(ids_all, labels_all):
         raise ValueError("the causal-LM batch uses its ids as labels")
@@ -197,13 +225,12 @@ def loss(params: dict, batch: tuple, sizes: dict, block: int) -> float:
     jitted = {kind: jax.jit(functools.partial(_layer, kind, sizes=sizes))
               for kind in BLOCKS}
     head = jax.jit(_loss_sum)
-    total = 0.0
     with jax.default_matmul_precision("highest"):
         p = _float32(params, sizes)
-        for ids in ids_all:
-            h = hidden(p, jnp.asarray(ids), sizes,
-                       lambda kind, x, own, _: jitted[kind](x, own))
-            total += float(head(h, p["head_w"], jnp.asarray(ids)))
+        hs = _walk(p, ids_all, sizes,
+                   lambda kind, x, own, _: jitted[kind](x, own), router_bias)
+        total = sum(float(head(h, p["head_w"], jnp.asarray(ids)))
+                    for h, ids in zip(hs, ids_all))
     return total / (ids_all.shape[0] * (ids_all.shape[1] - 1))
 
 

@@ -5,8 +5,10 @@
         --trace <0|1>
 
 Prints, last, one JSON object with the keys ``correct``, ``attempted``,
-``failed``, ``metrics`` and ``device`` (and ``breakdown`` when traced);
-everything else worth reading is on earlier lines.  With ``--trace 0`` the
+``failed``, ``metrics`` and ``device`` (and ``breakdown`` when traced),
+then ``compared``: each number ``correct`` rests on beside its limit and
+whether it holds, as on the last lines of standard error; everything else
+worth reading is on earlier lines.  With ``--trace 0`` the
 metrics are the cell's end-to-end metrics, with ``--trace 1`` its per-layer
 metrics.  Without a TPU, or with fewer chips than the cell asks for, it
 exits non-zero and prints no result: it never falls back to the CPU.
@@ -43,6 +45,9 @@ def main(argv=None) -> int:
     except measure.Refused as e:
         print(f"benchmarks/run.py: refused: {e}", file=sys.stderr)
         return 1
+    for name, pair in result["compared"].items():
+        print(f"compared: {name} {pair['value']!r} limit {pair['limit']!r}",
+              file=sys.stderr, flush=True)
     say(json.dumps(result))
     return 0
 

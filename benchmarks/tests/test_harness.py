@@ -163,14 +163,54 @@ def test_flops_per_token_against_a_hand_count():
     assert bert.flops_per_token(sizes, 16) == 14400 + 3072
 
 
-def test_flash_flops_and_bytes_against_a_hand_count():
+@pytest.mark.parametrize("causal, flops", [(True, 215040), (False, 430080)])
+def test_flash_flops_and_bytes_against_a_hand_count(causal, flops):
     reader = measure._reader("layer_metrics", "flash_roofline")
-    flops, nbytes = reader.flash_flops_and_bytes(2, 3, 16, 4, True, 5)
     # one matmul: 2 x (2 x 3) x 16 x 16 x 4 = 12288, causal 6144; 7 of
-    # them in 5 layers = 215040.  A tensor: 2 x 3 x 16 x 4 x 2 B = 768 B,
-    # 12 passes = 9216; log-sum-exp 2 x 3 x 16 x 4 B = 384, written once
-    # and read once = 768; (9216 + 768) x 5 = 49920
-    assert (flops, nbytes) == (215040, 49920)
+    # them in 5 layers = 430080, causal 215040.  A tensor: 2 x 3 x 16 x 4
+    # x 2 B = 768 B, 12 passes = 9216; log-sum-exp 2 x 3 x 16 x 4 B = 384,
+    # written once and read once = 768; (9216 + 768) x 5 = 49920
+    assert reader.flash_flops_and_bytes(2, 3, 16, 4, causal, 5) \
+        == (flops, 49920)
+
+
+def test_bert_s512_flash_count_against_a_hand_count():
+    """The non-causal call of ``bert_base.pretrain_b32_s512``: 12 layers
+    of (32, 12, 512, 64), nothing halved."""
+    bert = importlib.import_module("benchmarks.reference.bert_base")
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "bert_base.json")) as f:
+        sizes = json.load(f)["sizes"]
+    shape = bert.attention_shape(sizes, 32, 512)
+    assert shape == (32, 12, 512, 64, False, 12)
+    reader = measure._reader("layer_metrics", "flash_roofline")
+    flops, nbytes = reader.flash_flops_and_bytes(*shape)
+    # a matmul 2 x 32 x 12 x 512 x 512 x 64 = 12,884,901,888; x 7 x 12
+    assert flops == 12884901888 * 84 == 1082331758592
+    # a tensor 32 x 12 x 512 x 64 x 2 B = 25,165,824; lse 786,432
+    assert nbytes == 12 * (12 * 25165824 + 2 * 786432)
+    # the reader on a trace whose three kernels took 28.97 ms a step (the
+    # cell's, PR 31): 1.0823e12 / 197e12 = 5.494 ms, bound by compute
+    class Trace:
+        chips = [0]
+
+        def per_step(self, chip, pattern):
+            return 28.97e-3
+
+    said = []
+    share = reader.reduce(Trace(), {
+        "reference": bert, "sizes": sizes, "say": said.append,
+        "traffic": {"batch": 32, "seq": 512},
+        "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}})
+    assert share == pytest.approx(100 * 5.494 / 28.97, rel=1e-3)
+    assert "bound by compute" in said[0]
+    # and the cells the metric is read in are those whose step runs the
+    # kernels: both GPT-2 cells and, since PR 31, BERT at S = 512
+    for name in ("flash_ms_per_step", "flash_roofline"):
+        listed = next(m for m in BENCH["per_layer"] if m["name"] == name)
+        assert listed["workloads"] == [
+            "gpt2_345m.train_b8_s1024", "gpt2_345m.zero1_dp4_b32_s1024",
+            "bert_base.pretrain_b32_s512"]
 
 
 # -- the references against the program's models, float32, tiny ---------------
@@ -248,14 +288,18 @@ def test_what_the_reference_check_can_tell_apart(config_name, fault, seen,
 
 # -- the command itself, rehearsed --------------------------------------------
 
-def _rehearse(cell, trace):
+def _rehearsal(cell, trace):
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
          "--workload", cell, "--seed", "3000000019", "--seconds", "1",
          "--trace", str(trace), "--rehearse"],
         capture_output=True, text=True, timeout=600, cwd=ROOT)
     assert out.returncode == 0, out.stderr[-3000:]
-    return out.stdout.strip().splitlines()
+    return out
+
+
+def _rehearse(cell, trace):
+    return _rehearsal(cell, trace).stdout.strip().splitlines()
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -263,8 +307,13 @@ def test_rehearsal_prints_the_contracts_result_line(cell):
     chips = next(w["chips"] for w in BENCH["workloads"] if w["name"] == cell)
     lines = _rehearse(cell, trace=1)
     result = json.loads(lines[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device", "breakdown"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "breakdown", "compared"]
+    for pair in result["compared"].values():
+        assert set(pair) == {"value", "limit", "holds"} and pair["holds"]
+    assert result["compared"]["first_loss_rel_diff"]["value"] \
+        <= result["compared"]["first_loss_rel_diff"]["limit"]
+    assert result["compared"]["last_loss_over_first"]["value"] < 1.0
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 10
     assert result["device"]["platform"] == "cpu"
@@ -296,10 +345,14 @@ def test_rehearsal_prints_the_contracts_result_line(cell):
 
 
 def test_untraced_rehearsal_and_refusals():
-    lines = _rehearse(CELLS[0], trace=0)
-    result = json.loads(lines[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    out = _rehearsal(CELLS[0], trace=0)
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
+    # the same numbers beside their limits end standard error
+    last = out.stderr.strip().splitlines()[-len(result["compared"]):]
+    assert [ln.split()[:2] for ln in last] == [
+        ["compared:", name] for name in result["compared"]]
     assert result["metrics"] == {}           # all three are device numbers
     # without --rehearse there is no TPU here: no result line, exit != 0
     for args in (["--workload", CELLS[0]], ["--workload", "no.such_cell",
