@@ -38,7 +38,12 @@ deployment (the ``model-configs`` guide, section 4): the counts given to
 
 Parameters are stacked per block type on a leading axis (``m_*``,
 ``a_*``, ``e_*``), the i-th ``M`` of the pattern reading row i of every
-``m_*``; each layer is its own ``jax.checkpoint`` where ``remat``.
+``m_*``.  Where ``remat``, each layer is its own ``jax.checkpoint`` and
+keeps nothing but its input, except an ``E`` layer's router: its choice
+``sel`` and the logits at ``sel`` (``moe.ROUTER_SAVED``, 2 x tokens x
+``top_k`` x 4 bytes a layer) stay, so the backward neither scores all
+``n_routed_experts`` nor takes the top-k a second time, and its row plan
+is built from the choice the forward made, not from one made again.
 
 Initialiser: normal(0, ``initializer_range``) for matrices, ``W_out``,
 ``W_o``, ``W2`` (routed and shared) and ``W_up`` divided by sqrt(number
@@ -58,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.core import Parameter, Tensor, apply1
+from paddle_tpu.framework import monitor
 from paddle_tpu.models.gpt import _attention
 from paddle_tpu.nn.functional import moe as _moe
 from paddle_tpu.nn.functional import ssm as _ssm
@@ -70,6 +76,13 @@ __all__ = ["NemotronHConfig", "NemotronH", "nemotron_h_loss",
 # bfloat16 here to show that the first-loss comparison sees it (PERF.md
 # section 6, PR 27).  Not an option.
 _CE_DTYPE = jnp.float32
+
+monitor.describe("moe_router_kept_blocks_total",
+                 "expert blocks wrapped in a jax.checkpoint that keeps the "
+                 "router's choice and picked logits (moe.ROUTER_SAVED) for "
+                 "the backward, added once per E block when a stack is "
+                 "traced under remat (a trace-time count); a stack traced "
+                 "without remat adds none")
 
 PUBLISHED_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -274,6 +287,8 @@ def _e_block(c: NemotronHConfig, x, p):
 
 
 _BLOCK = {"M": _m_block, "*": _a_block, "E": _e_block}
+_KEEP_ROUTER = jax.checkpoint_policies.save_only_these_names(
+    *_moe.ROUTER_SAVED)
 
 
 def _layers(c: NemotronHConfig, p: dict):
@@ -294,7 +309,12 @@ def _trunk(c: NemotronHConfig, p: dict, ids):
         x = p["embed"][ids]
     for kind, own in _layers(c, p):
         block = partial(_BLOCK[kind], c)
-        x = (jax.checkpoint(block) if c.remat else block)(x, own)
+        if c.remat:
+            # only an E block holds the names; M and * keep nothing
+            block = jax.checkpoint(block, policy=_KEEP_ROUTER)
+            if kind == "E":
+                monitor.stat_add("moe_router_kept_blocks_total", 1)
+        x = block(x, own)
     return x
 
 

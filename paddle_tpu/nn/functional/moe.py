@@ -50,6 +50,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.framework import monitor
 from paddle_tpu.ops.pallas.common import traced_once
@@ -60,6 +61,12 @@ __all__ = ["route_top_k", "held_gates", "latent_moe"]
 # chip sets bfloat16 here to show that the hidden-state comparison sees
 # it (PERF.md section 6, PR 27).  Not an option.
 _ROUTER_DTYPE = jnp.float32
+
+# what a caller's ``jax.checkpoint`` keeps of the router
+# (``save_only_these_names(*ROUTER_SAVED)``, ``models/nemotron_h.py``), so
+# that its backward neither scores nor chooses a second time: the choice,
+# and the logits at the chosen places (``route_top_k`` says why those).
+ROUTER_SAVED = ("router_sel", "router_logits")
 
 monitor.describe("moe_calls_traced_total",
                  "calls of latent_moe, added once per traced call (a "
@@ -87,14 +94,26 @@ def route_top_k(u, router_w, router_bias, top_k: int, scale: float):
     top_k(s + router_bias)`` (the bias only chooses; no gradient reaches
     it); ``g = scale * s[sel] / (sum s[sel] + 1e-20)``.  Returns ``(sel,
     g)``, both (..., top_k); ``g`` is normalised over all ``top_k``
-    whether the experts are held here or not."""
+    whether the experts are held here or not.
+
+    ``sel`` and the logits at ``sel`` carry the names ``ROUTER_SAVED``
+    (identities outside a ``jax.checkpoint`` whose policy names them).
+    The logits and not the scores, because the sigmoid's derivative reads
+    the sigmoid's own result: with the scores kept the backward would
+    still ask for the logits and run the einsum over all experts again;
+    from the kept logits it takes ``top_k`` sigmoids a token.  ``s[sel]``
+    is computed as ``sigmoid(logits[sel])``, the same function of the
+    same numbers."""
     logits = jnp.einsum("...d,ed->...e", u, router_w,
                         precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=_ROUTER_DTYPE)
     s = jax.nn.sigmoid(logits)
     _, sel = jax.lax.top_k(
         s + jax.lax.stop_gradient(router_bias).astype(s.dtype), top_k)
-    picked = jnp.take_along_axis(s, sel, axis=-1)
+    sel_name, logits_name = ROUTER_SAVED
+    sel = checkpoint_name(sel, sel_name)
+    picked = jax.nn.sigmoid(checkpoint_name(
+        jnp.take_along_axis(logits, sel, axis=-1), logits_name))
     g = scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     return sel, g
 

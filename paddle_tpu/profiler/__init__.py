@@ -45,7 +45,10 @@ Names in a device trace (``start_profiler`` or a bare
   and ``flash_dispatch_kernel_total`` / ``_xla_for_speed_total``
   (``ops/pallas/flash_attention.py``), ``moe_calls_traced_total``,
   ``moe_expert_rows_computed_total``, ``moe_expert_rows_expected_total``
-  (``nn/functional/moe.py``), ``ssm_chunks_traced_total``
+  (``nn/functional/moe.py``), ``moe_router_kept_blocks_total`` (expert
+  blocks whose ``jax.checkpoint`` keeps the router's choice for the
+  backward, one per ``E`` block of a stack traced under ``remat``;
+  ``models/nemotron_h.py``), ``ssm_chunks_traced_total``
   (``nn/functional/ssm.py``).
 """
 from __future__ import annotations

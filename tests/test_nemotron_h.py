@@ -5,7 +5,8 @@ weights, tiny sizes, on the CPU.  Each block and the whole model, loss
 and the gradient of every parameter; the chunked scan against the
 sequential recurrence; skewed routing; the three share tests of the
 ``model-configs`` guide's section 4 (the shares add up to the uncut
-layer); ``TrainStep`` under AMP O2; scopes and counters.
+layer); ``TrainStep`` under AMP O2; scopes and counters; what each
+``E`` block's ``jax.checkpoint`` keeps of its router.
 """
 import functools
 import re
@@ -117,9 +118,8 @@ def test_block_and_its_gradients_match_the_reference(tiny, kind):
         assert_close(got_grads[1][name], want_grads[1][name], what=name)
 
 
-def test_model_loss_and_every_gradient_match_the_reference(tiny):
-    c, model = tiny
-    ids = batch_of(c)
+def _loss_of_params(model, ids):
+    """(``params -> loss`` on the batch ``ids``, the model's ``params``)."""
     params = {n: t._data for n, t in model.named_parameters()}
     buffers = {n: t._data for n, t in model.named_buffers()}
 
@@ -128,6 +128,13 @@ def test_model_loss_and_every_gradient_match_the_reference(tiny):
             model, nemotron_h_loss, params, buffers, jax.random.PRNGKey(0),
             [jnp.asarray(ids), jnp.asarray(ids)])[0]
 
+    return program, params
+
+
+def test_model_loss_and_every_gradient_match_the_reference(tiny):
+    c, model = tiny
+    ids = batch_of(c)
+    program, params = _loss_of_params(model, ids)
     got, got_grads = jax.jit(jax.value_and_grad(program))(params)
     want, want_grads = ref.loss_and_grads(params, (ids, ids), sizes_of(c))
     assert float(got) == pytest.approx(want, rel=2e-6)
@@ -414,13 +421,17 @@ INNER = {"ssm": ("ln", "in_proj", "conv", "scan", "gate_norm", "out"),
                  "combine", "latent_up", "shared")}
 
 
+def _pass_of(path):
+    return ("recompute" if "rematted_computation" in path else
+            "bwd" if "transpose(" in path else "fwd")
+
+
 def test_scopes_of_every_block_in_every_pass(stepped):
     _, step, _, _ = stepped
     seen = {}
     for path in re.findall(r'op_name="([^"]*)"', step.compiled_text()):
         tokens = [t for t in re.split(r"[/()]", path) if t]
-        which = ("recompute" if "rematted_computation" in tokens else
-                 "bwd" if "transpose(" in path else "fwd")
+        which = _pass_of(path)
         region = next((t for t in tokens if t in INNER), None)
         if region:
             after = tokens[tokens.index(region) + 1:]
@@ -429,7 +440,10 @@ def test_scopes_of_every_block_in_every_pass(stepped):
     for region, inner in INNER.items():
         for which in ("fwd", "bwd", "recompute"):
             # a matmul whose result only joins the residual sum is not
-            # run again: the backward reads its operands, not its result
+            # run again: the backward reads its operands, not its result.
+            # ``router`` stays in the recompute's set though its choice
+            # and picked logits are kept: their sigmoid and the gates'
+            # normalisation run again (what does not, the test below)
             last = {"out", "latent_up"} if which == "recompute" else set()
             assert set(inner) - last <= seen[(which, region)], \
                 (which, region)
@@ -451,11 +465,131 @@ def test_counters_of_the_traced_step(stepped):
     assert stats["moe_expert_rows_expected_total"] / calls == \
         tokens * c.num_experts_per_tok * c.experts_held / c.n_routed_experts
     assert stats["ssm_chunks_traced_total"] >= 2 * 2 * (64 // c.chunk_size)
+    # every E block of the traced stack keeps its router under remat ...
+    assert stats["moe_router_kept_blocks_total"] == calls
+    # ... and a stack traced without remat wraps none
+    before = dict(monitor.all_stats())
+    NemotronH(nemotron_h_tiny(remat=False))(
+        paddle.to_tensor(batch_of(c, shape=(1, 16))))
+    after = monitor.all_stats()
+    assert after["moe_calls_traced_total"] \
+        - before["moe_calls_traced_total"] == layers
+    assert after["moe_router_kept_blocks_total"] \
+        == before["moe_router_kept_blocks_total"]
     text = monitor.export_prometheus()
     for name in ("moe_calls_traced_total", "moe_expert_rows_computed_total",
                  "moe_expert_rows_expected_total",
-                 "ssm_chunks_traced_total"):
+                 "moe_router_kept_blocks_total", "ssm_chunks_traced_total"):
         assert re.search(rf"# HELP \S*{name}", text), name
+        assert f"``{name}``" in paddle.profiler.__doc__, name
+
+
+# -- what the checkpoint keeps of the router ----------------------------------
+
+def _router_ops(text):
+    """{pass: the last token of every ``op_name`` under ``mlp/router``}
+    of a compiled step's text."""
+    ops = {}
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        if "mlp/router/" in path or "mlp)/router/" in path:
+            ops.setdefault(_pass_of(path), set()).add(path.rsplit("/", 1)[1])
+    return ops
+
+
+def test_recomputed_forward_neither_scores_nor_chooses_again(stepped):
+    """The compiled step: the router's einsum over all experts and its
+    top-k are in the forward, and the forward run again holds neither."""
+    _, step, _, _ = stepped
+    ops = _router_ops(step.compiled_text())
+    assert {"dot_general", "top_k"} <= ops["fwd"]
+    assert "dot_general" in ops["bwd"]           # the weight's gradient
+    # what is left: the picked logits' sigmoid and the gates
+    assert ops["recompute"] and not ops["recompute"] & {
+        "dot_general", "top_k", "sort"}, ops["recompute"]
+
+
+def _drop_the_policy(monkeypatch):
+    """``_trunk``'s checkpoint as it was before the names: a bare
+    ``jax.checkpoint(block)``, which keeps nothing."""
+    real = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint", lambda f, **_: real(f))
+
+
+def _loss_and_grads(c, router_w, bias, ids):
+    """((loss, every parameter's gradient), the jaxpr that computes them,
+    the model) of a new model of ``c`` under the given router."""
+    model = NemotronH(c)
+    _louder_experts(model._parameters)
+    model._parameters["e_router_w"]._data = jnp.asarray(router_w)
+    model.set_state_dict({"e_router_bias": bias})
+    program, params = _loss_of_params(model, ids)
+    fn = jax.value_and_grad(program)
+    return jax.jit(fn)(params), str(jax.make_jaxpr(fn)(params)), model
+
+
+@pytest.mark.parametrize("bias_of",
+                         ["zero", "nonzero", "tie_at_the_last_place"])
+@pytest.mark.parametrize("path", ["dense_mask", "sorted_rows"])
+def test_kept_router_gives_the_bare_checkpoints_gradients(path, bias_of,
+                                                          monkeypatch):
+    """Loss and every gradient with ``sel`` and the picked logits kept:
+    bit for bit those of a bare ``jax.checkpoint(block)``, which chooses
+    a second time (float32, CPU), and within the parity tolerance those
+    of ``remat=False``; one ``top_k`` an ``E`` layer in the gradient's
+    jaxpr where the bare checkpoint has two, and one einsum fewer."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    monkeypatch.setattr(gmm, "_INTERPRET", path == "sorted_rows")
+    sizes = dict(seed=5, hybrid_override_pattern="ME*E")
+    if path == "sorted_rows":        # lane multiples, a row tile of tokens
+        sizes.update(moe_latent_size=128, moe_intermediate_size=128)
+    c = nemotron_h_tiny(remat=True, **sizes)
+    layers = c.hybrid_override_pattern.count("E")
+    rng = np.random.default_rng(23)
+    ids = rng.integers(0, c.vocab_size, (2, 256 if path == "sorted_rows"
+                                         else 40)).astype(np.int32)
+    router_w = NemotronH(c)._parameters["e_router_w"].numpy().copy()
+    bias = np.zeros((layers, c.n_routed_experts), np.float32)
+    if bias_of != "zero":
+        bias += (0.05 * rng.standard_normal(bias.shape)).astype(np.float32)
+    if bias_of == "tie_at_the_last_place":
+        # expert 1 comes first for every token; experts 2 (held) and 5
+        # (absent) score alike to the bit and stand level for the last
+        # of the top_k places: the lower index takes it, everywhere
+        assert c.num_experts_per_tok == 2 and c.experts_held == 4
+        bias[:, 1], bias[:, 2], bias[:, 5] = 5.0, 2.0, 2.0
+        router_w[:, 5] = router_w[:, 2]
+
+    monitor.reset_all_stats()
+    (kept_loss, kept), kept_jaxpr, model = _loss_and_grads(
+        c, router_w, bias, ids)
+    stats = monitor.all_stats()
+    assert stats["moe_expert_rows_computed_total"] \
+        / stats["moe_calls_traced_total"] == c.experts_held * (
+            gmm.TILE_ROWS if path == "sorted_rows" else ids.size)
+    (plain_loss, plain), plain_jaxpr, _ = _loss_and_grads(
+        nemotron_h_tiny(remat=False, **sizes), router_w, bias, ids)
+    _drop_the_policy(monkeypatch)
+    (bare_loss, bare), bare_jaxpr, _ = _loss_and_grads(c, router_w, bias, ids)
+
+    def count(jaxpr, primitive):
+        return len(re.findall(rf"\b{primitive}\[", jaxpr))
+
+    assert count(plain_jaxpr, "top_k") == count(kept_jaxpr, "top_k") \
+        == layers
+    assert count(bare_jaxpr, "top_k") == 2 * layers
+    assert count(bare_jaxpr, "dot_general") \
+        - count(kept_jaxpr, "dot_general") == layers
+    assert float(kept_loss) == float(bare_loss)
+    assert float(kept_loss) == pytest.approx(float(plain_loss), rel=2e-6)
+    assert set(kept) == set(bare) == set(plain)
+    for name in kept:
+        assert np.abs(np.asarray(kept[name])).max() > 0, name
+        np.testing.assert_array_equal(kept[name], bare[name], err_msg=name)
+        assert_close(kept[name], plain[name], what=name)
+    if bias_of == "tie_at_the_last_place":
+        load = routing_load(model, ids)
+        assert (load[:, 1] == ids.size).all() \
+            and (load[:, 2] == ids.size).all(), load
 
 
 # -- the configuration --------------------------------------------------------
