@@ -10,7 +10,10 @@ einsum's subscripts, which jax writes into the path, are none either).
 Sums are of event durations on the first chip inside ``trace.window``,
 per step, control flow left out by opcode, as in ``scopes.reduce_ops``.
 Where the program names no such region, as before PR 27, the readers
-return None and raise nothing.
+return None and raise nothing.  A region that ``inner_regions.json`` does
+not list is read through the inner names its reader passes (``names``);
+a region known neither to the file nor to the caller reads None, as one
+the program does not name.
 """
 from __future__ import annotations
 
@@ -26,23 +29,34 @@ with open(os.path.join(scopes.HERE, "inner_regions.json")) as _f:
 _TOKEN = re.compile(r"[^/()]+")
 
 
-def inner_of(path: str, region: str):
+def _names(region: str, names):
+    """The region's inner names: ``names`` where the caller gives them,
+    else ``inner_regions.json``'s; None where neither knows the region."""
+    return INNER.get(region) if names is None else tuple(names)
+
+
+def inner_of(path: str, region: str, names=None):
     """The inner scope of ``region`` that ``path`` lies in: None where the
-    path is not under the region, "" where it is under none of the
-    region's inner scopes."""
+    path is not under the region or the region's inner names are unknown
+    (``names``: see ``_names``), "" where it is under none of them."""
+    names = _names(region, names)
     tokens = _TOKEN.findall(path or "")
-    if region not in tokens:
+    if names is None or region not in tokens:
         return None
     after = tokens[tokens.index(region) + 1:]
-    return next((t for t in after if t in INNER[region]), "")
+    return next((t for t in after if t in names), "")
 
 
-def sum_inner(rows, region: str, inner=None) -> float:
+def sum_inner(rows, region: str, inner=None, names=None):
     """Summed seconds of ``rows`` = [(path, seconds)] under ``region``,
-    in any of the inner scopes ``inner`` (None: the whole region)."""
+    in any of the inner scopes ``inner`` (None: the whole region); None
+    where the region's inner names are unknown."""
+    names = _names(region, names)
+    if names is None:
+        return None
     total = 0.0
     for path, seconds in rows:
-        found = inner_of(path, region)
+        found = inner_of(path, region, names)
         if found is not None and (inner is None or found in inner):
             total += seconds
     return total
@@ -81,11 +95,10 @@ def _read(trace):
     return rows
 
 
-def ms_per_step(trace, run, region: str, inner=None):
+def ms_per_step(trace, run, region: str, inner=None, names=None):
     """Summed ms a step, every pass, of the operations under ``region``
-    (in the inner scopes ``inner``); None where nothing matches."""
+    (in the inner scopes ``inner``, among the region's inner ``names``
+    where ``inner_regions.json`` lacks it); None where nothing matches."""
     rows = _rows(trace, run)
-    if rows is None:
-        return None
-    seconds = sum_inner(rows, region, inner)
-    return 1e3 * seconds if seconds > 0 else None
+    seconds = None if rows is None else sum_inner(rows, region, inner, names)
+    return 1e3 * seconds if seconds else None

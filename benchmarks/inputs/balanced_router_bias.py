@@ -17,8 +17,9 @@ pattern's order and with the biases of the layers before it in place,
 start from ``b = 0`` and repeat ``b <- b + gamma_t * sign(mean - load)``
 with ``gamma_t = max(GAMMA * DECAY**t, GAMMA_FLOOR)``, where ``load_e``
 counts the batch's tokens that have expert ``e`` among their ``top_k`` of
-``s + b`` and ``mean = tokens * top_k / experts``, until every expert's
-load lies within ``TOLERANCE`` of the mean (176 +- 16 rows at the timed
+``s + b`` (or that the reference's own choice gives ``e``: see below)
+and ``mean = tokens * top_k / experts``, until every expert's load lies
+within ``TOLERANCE`` of the mean (176 +- 16 rows at the timed
 sizes, where a row tile of the program's grouped matmuls holds 256: at
 most 192 rows leaves a held expert 64 rows inside its one tile) or
 ``CAP`` iterations are over.  The steps add up to ``GAMMA / (1 - DECAY)``
@@ -30,6 +31,16 @@ hands the harness the worst load's distance from the mean beside the
 tolerance, and the run's ``correct`` rests on it.  Deterministic: the
 scores come from the seed's weights and batch through the float32
 reference, and nothing here draws a number.
+
+**A router that chooses otherwise.**  Where the configuration's
+reference defines ``expert_choice(biased, top_k, sizes)``, the
+(tokens, experts) marks, 1 where a token takes an expert, of the choice
+its router makes from ``s + b`` (a group-limited router, DeepSeek-V3's
+``noaux_tc``, chooses otherwise than the plain top-k), the loads are
+counted through it, so the bias balances the choice the reference
+routes by, and the rule is written in the reference alone.  Without it,
+the plain ``top_k(s + b)`` above.  The solved bias goes to the buffer
+``e_router_bias``, the name an expert model registers it under.
 
 **What it does not do.**  The router keeps its seeded weights, its width
 and its experts a token, and goes on training in the window; the program
@@ -67,16 +78,19 @@ def held_experts(sizes: dict) -> slice:
                  sizes["expert_offset"] + sizes["n_routed_experts"])
 
 
-def loads(scores, bias, top_k: int):
+def loads(scores, bias, top_k: int, choose=None):
     """(experts,): how many rows of ``scores`` (tokens, experts) have each
-    expert among their ``top_k`` of ``scores + bias``."""
+    expert among their ``top_k`` of ``scores + bias``, or among those
+    ``choose(scores + bias, top_k)`` marks where it is given."""
     biased = scores + bias
+    if choose is not None:
+        return jnp.sum(choose(biased, top_k), axis=0, dtype=jnp.float32)
     kth = jax.lax.top_k(biased, top_k)[0][:, -1:]
     return jnp.sum(biased >= kth, axis=0, dtype=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames="top_k")
-def balance(scores, top_k: int):
+@functools.partial(jax.jit, static_argnames=("top_k", "choose"))
+def balance(scores, top_k: int, choose=None):
     """``(bias, loads under it, iterations)`` for ``scores`` (tokens,
     experts) by the rule of the module's text."""
     experts = scores.shape[1]
@@ -90,11 +104,12 @@ def balance(scores, top_k: int):
         t, bias, load = state
         gamma = jnp.maximum(GAMMA * DECAY ** t, GAMMA_FLOOR)
         bias = bias + gamma * jnp.sign(mean - load)
-        return t + 1, bias, loads(scores, bias, top_k)
+        return t + 1, bias, loads(scores, bias, top_k, choose)
 
     zero = jnp.zeros((experts,), jnp.float32)
     t, bias, load = jax.lax.while_loop(
-        unbalanced, update, (jnp.float32(0), zero, loads(scores, zero, top_k)))
+        unbalanced, update,
+        (jnp.float32(0), zero, loads(scores, zero, top_k, choose)))
     return bias, load, t
 
 
@@ -103,15 +118,19 @@ def solve(reference, params: dict, batch: tuple, sizes: dict, block: int):
     compared)`` for the configuration's model under ``params`` on
     ``batch``: the reference's loss under the solved bias, the bias for
     the model's buffer of that name, what a ``routing:`` line says of it
-    (the loads before and after, of every expert and of those held here),
-    and the number the run's ``correct`` holds the solve to, beside its
-    limit: the worst load's distance from the mean, and the tolerance."""
+    (the loads before and after, of every expert and of those held here,
+    counted as the reference chooses), and the number the run's
+    ``correct`` holds the solve to, beside its limit: the worst load's
+    distance from the mean, and the tolerance."""
     top_k, held = sizes["num_experts_per_tok"], held_experts(sizes)
+    choice = getattr(reference, "expert_choice", None)
+    # one object for every layer, so ``balance`` compiles once a solve
+    choose = choice and functools.partial(choice, sizes=sizes)
     solved, layers = [], []
 
     def at_expert_layer(scores):
-        before = loads(scores, 0.0, top_k)
-        bias, after, iterations = balance(scores, top_k)
+        before = loads(scores, 0.0, top_k, choose)
+        bias, after, iterations = balance(scores, top_k, choose)
         solved.append(np.asarray(bias))
         before, after = np.asarray(before), np.asarray(after)
         layers.append({

@@ -4,7 +4,6 @@ and ``BENCHMARK.json`` against the driver's rules."""
 import importlib
 import json
 import os
-import re
 import subprocess
 import sys
 
@@ -12,83 +11,22 @@ import numpy as np
 import pytest
 
 from benchmarks.harness import measure, roofline
+from benchmarks.tests import rehearsal, rules
 
 ROOT = measure.ROOT
-with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-    BENCH = json.load(f)
+BENCH = rules.load(ROOT)
 CELLS = [w["name"] for w in BENCH["workloads"]]
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
 # -- BENCHMARK.json against the contract -------------------------------------
 
 def test_benchmark_json_keeps_the_drivers_rules():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert BENCH["paths"] == ["benchmarks"]
-    assert 1 <= BENCH["run_seconds"] <= 51
-    # 2 + 14 x 24 runs of run_seconds + 60 s, 24 x 180 s to compile,
-    # 1200 s spare, inside 43200 s
-    assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
-        <= 43200
-    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
-    names = [m["name"] for m in metrics]
-    assert len(set(names)) == len(names)
-    for m in metrics:
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
-        assert m["better"] in ("lower", "higher")
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
-    for m in BENCH["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source",
-                          "workloads"}
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
-    assert "setup_s" in e2e
-    for m in BENCH["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["moves"] in e2e and 1 <= len(m["layer"]) <= 200
-    for c in BENCH["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(c["name"]) and len(c["source"]) <= 200
-        assert 1 <= len(c["why"]) <= 200
-        assert c["file"].startswith("benchmarks/")
-    for w in BENCH["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert all(NAME.match(w[k]) for k in ("name", "config", "traffic"))
-        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert four <= max(1, len(CELLS) // 4)
-    for dirpath, _, files in os.walk(os.path.join(ROOT, "benchmarks")):
-        if "__pycache__" in dirpath:
-            continue
-        for name in files:
-            assert re.match(r"^[A-Za-z0-9_.\-]+$", name), (dirpath, name)
+    rules.drivers_rules(BENCH, ROOT)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_resolves_to_its_files_and_imports(cell):
-    loaded = measure.load_cell(cell, rehearse=False)
-    config, traffic = loaded["config"], loaded["traffic"]
-    for name in (config["model"], config["model_config"], config["loss"],
-                 config["inputs"], config["optimizer"]["class"],
-                 traffic["step"]["class"]):
-        assert callable(measure.resolve(name)), name
-    reference = measure.resolve(config["reference"])
-    assert callable(reference.loss) and callable(reference.flops_per_token)
-    assert 0 < reference.TOLERANCE_REL <= 5e-4
-    assert loaded["end_to_end"] and loaded["per_layer"]
-    for folder in ("end_to_end", "per_layer"):
-        for m in loaded[folder]:
-            reader = measure._reader(
-                {"per_layer": "layer_metrics"}.get(folder, folder),
-                m["name"])
-            assert callable(reader.reduce), m["name"]
-    assert traffic["batch"] % traffic["chips"] == 0
+    rules.cell_resolves(cell)
 
 
 # -- the window's arithmetic --------------------------------------------------
@@ -204,13 +142,12 @@ def test_bert_s512_flash_count_against_a_hand_count():
         "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}})
     assert share == pytest.approx(100 * 5.494 / 28.97, rel=1e-3)
     assert "bound by compute" in said[0]
-    # and the cells the metric is read in are those whose step runs the
-    # kernels: both GPT-2 cells and, since PR 31, BERT at S = 512
-    for name in ("flash_ms_per_step", "flash_roofline"):
-        listed = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        assert listed["workloads"] == [
-            "gpt2_345m.train_b8_s1024", "gpt2_345m.zero1_dp4_b32_s1024",
-            "bert_base.pretrain_b32_s512"]
+    # and the cells the metric is read in include those whose step runs
+    # the kernels: both GPT-2 cells and, since PR 31, BERT at S = 512;
+    # every cell listed has a reference that gives the calls' shape
+    rules.flash_lists(BENCH, ROOT, (
+        "gpt2_345m.train_b8_s1024", "gpt2_345m.zero1_dp4_b32_s1024",
+        "bert_base.pretrain_b32_s512"))
 
 
 # -- the references against the program's models, float32, tiny ---------------
@@ -289,11 +226,7 @@ def test_what_the_reference_check_can_tell_apart(config_name, fault, seen,
 # -- the command itself, rehearsed --------------------------------------------
 
 def _rehearsal(cell, trace):
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
-         "--workload", cell, "--seed", "3000000019", "--seconds", "1",
-         "--trace", str(trace), "--rehearse"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT)
+    out = rehearsal.run(ROOT, cell, 3000000019, trace)
     assert out.returncode == 0, out.stderr[-3000:]
     return out
 

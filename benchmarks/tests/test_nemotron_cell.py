@@ -1,23 +1,23 @@
 """What PR 27 added to the yardstick: the reader of inner scopes on a
 hand-made path table, the five new readers in a CPU rehearsal of the new
 cell, ``flops_per_token`` against a hand count, and the configuration
-file against its own statement of the cut."""
+file against its own statement of the cut.  Since PR 38: a region that
+``inner_regions.json`` does not list, read through the names its caller
+gives, and one known to neither, which reads None."""
 import importlib
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 from benchmarks.harness import inner_scopes, measure
+from benchmarks.tests import rehearsal, rules
 
 ROOT = measure.ROOT
 CELL = "nemotron3_super_120b.train_b1_s4096"
 NEW = ("ssm_ms_per_step", "ssm_scan_ms_per_step", "moe_routed_ms_per_step",
        "moe_shared_ms_per_step", "moe_rows_computed_per_token")
-with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-    BENCH = json.load(f)
+BENCH = rules.load(ROOT)
 with open(os.path.join(ROOT, "benchmarks", "configs",
                        "nemotron3_super_120b.json")) as f:
     CONFIG = json.load(f)
@@ -65,6 +65,46 @@ def test_sums_over_a_hand_made_path_table():
     assert inner_scopes.sum_inner(ROWS[8:], "ssm") == 0.0
 
 
+# a block the file does not list, with the inner names its reader passes
+KDA = ("ln", "qkv", "conv", "gate", "scan", "out")
+KDA_ROWS = [
+    ("jit(step)/jvp(kda)/scan/closed_call/while/body/mul", 1.0),
+    ("jit(step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/kda/"
+     "conv/jit(silu)/neg", 2.0),
+    ("jit(step)/transpose(jvp(jvp()))/checkpoint/kda/gate/exp", 4.0),
+    ("jit(step)/jvp(kda)/add", 8.0),                     # the residual
+    # a primitive named like another region's inner scope is none
+    ("jit(step)/jvp(mlp)/router/scan/while/body/add", 16.0)]
+
+
+def test_a_region_the_file_lacks_is_read_through_the_callers_names():
+    assert "kda" not in inner_scopes.INNER
+    rows = ROWS + KDA_ROWS
+    assert inner_scopes.inner_of(KDA_ROWS[0][0], "kda", KDA) == "scan"
+    assert inner_scopes.inner_of(KDA_ROWS[3][0], "kda", KDA) == ""
+    assert inner_scopes.inner_of(KDA_ROWS[4][0], "kda", KDA) is None
+    assert inner_scopes.sum_inner(rows, "kda", None, KDA) == 15.0
+    assert inner_scopes.sum_inner(rows, "kda", ("scan",), KDA) == 1.0
+    assert inner_scopes.sum_inner(rows, "kda", ("conv", "gate"), KDA) == 6.0
+    assert inner_scopes.sum_inner(ROWS, "kda", None, KDA) == 0.0
+    # known to neither the file nor the caller: None, and nothing raised
+    assert inner_scopes.inner_of(KDA_ROWS[0][0], "kda") is None
+    assert inner_scopes.sum_inner(rows, "kda") is None
+    assert inner_scopes.sum_inner(rows, "kda", ("scan",)) is None
+
+    class Trace:
+        inner_scope_rows = rows
+
+    assert inner_scopes.ms_per_step(Trace(), {}, "kda") is None
+    assert inner_scopes.ms_per_step(Trace(), {}, "kda", ("gate",), KDA) \
+        == 4000.0
+    # the regions the file lists read as before, and a caller's names
+    # take the place of the file's
+    assert inner_scopes.sum_inner(rows, "ssm", ("scan",)) == 3.0
+    assert inner_scopes.sum_inner(rows, "mlp", None) == 480.0 + 16.0
+    assert inner_scopes.sum_inner(rows, "ssm", ("scan",), ("conv",)) == 0.0
+
+
 def test_readers_return_nothing_where_the_program_names_no_such_scope():
     """As on the parent of PR 27: no ``ssm`` region, no counters."""
     class Trace:
@@ -82,11 +122,7 @@ def test_readers_return_nothing_where_the_program_names_no_such_scope():
 # -- the new cell's metrics, rehearsed ----------------------------------------
 
 def test_the_five_new_readers_return_a_number_in_the_rehearsal():
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
-         "--workload", CELL, "--seed", "4000000007", "--seconds", "1",
-         "--trace", "1", "--rehearse"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT)
+    out = rehearsal.run(ROOT, CELL, 4000000007, 1)
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.strip().splitlines()
     said = json.loads(next(ln for ln in lines if ln.startswith(
@@ -107,12 +143,7 @@ def test_the_five_new_readers_return_a_number_in_the_rehearsal():
 
 
 def test_the_new_metrics_list_the_new_cell_alone():
-    per_layer = {m["name"]: m for m in BENCH["per_layer"]}
-    assert [m["name"] for m in BENCH["per_layer"]][-5:] == list(NEW)
-    for name in NEW:
-        assert per_layer[name]["workloads"] == [CELL]
-        assert per_layer[name]["moves"] == "tokens_per_s"
-        assert per_layer[name]["layer"] == "model"
+    rules.metrics_read_in(BENCH, NEW, [CELL], "tokens_per_s", "model")
 
 
 # -- flops_per_token ----------------------------------------------------------

@@ -10,11 +10,15 @@ on the sorted rows (kernels in interpret mode), the expert layer alone
 and the whole ``NemotronH``; a solve that ends at its cap makes the
 run's ``correct`` false; and the cell's feed: a fresh batch every step
 from one draw of the seed, the first of them the batch the reference
-reads, and a step that leaves its state unchanged is not ``correct``."""
-import glob
+reads, and a step that leaves its state unchanged is not ``correct``.
+Since PR 38: the solve counts loads through the reference's own choice
+where it gives one, and without one is bit for bit what it was; a
+configuration whose router limits groups needs a reference that chooses
+by them."""
 import json
 import os
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -24,8 +28,10 @@ import pytest
 from benchmarks.harness import measure
 from benchmarks.inputs import balanced_router_bias as brb
 from benchmarks.reference import nemotron3_super_120b as ref
+from benchmarks.tests import rules
 
 ROOT = measure.ROOT
+BENCH = rules.load(ROOT)
 CELL = "nemotron3_super_120b.train_b1_s4096"
 SEEDS = (3, 1200000007, 1300000021, 1400000033, 1500000041, 1600000057,
          2147483777, 4000000007)
@@ -108,27 +114,19 @@ def test_balance_on_scores_with_a_heavy_common_part():
         after, np.bincount(np.asarray(sel).ravel(), minlength=64))
 
 
-def test_only_the_expert_configuration_names_a_solve():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    named = {}
-    for entry in bench["configs"]:
-        with open(os.path.join(ROOT, entry["file"])) as f:
-            named[entry["name"]] = json.load(f)
-    assert {name for name, config in named.items()
-            if "router_bias" in config} == {"nemotron3_super_120b"}
+def test_a_configuration_names_a_solve_exactly_when_it_routes():
+    routed = rules.solves_follow_the_router(BENCH, ROOT)
+    assert "nemotron3_super_120b" in routed
+    assert not routed & {"gpt2_345m", "bert_base"}
+    named = rules.configurations(BENCH, ROOT)
     config = named["nemotron3_super_120b"]
     assert measure.resolve(config["router_bias"]) is brb.solve
-    assert "router_bias" in config["assumed"]
     for word in ("DeepSeek-V3", "checkpoint", "stays as solved", "176 +- 16"):
         assert word in config["assumed"]["router_bias"], word
     # the rate is the one the other configurations train at
-    assert {c["optimizer"]["kwargs"]["learning_rate"]
-            for c in named.values()} == {1e-4}
-    files = sorted(os.path.basename(p) for p in glob.glob(
-        os.path.join(ROOT, "benchmarks", "configs", "*.json")))
-    assert files == sorted(os.path.basename(e["file"])
-                           for e in bench["configs"])
+    assert {named[name]["optimizer"]["kwargs"]["learning_rate"]
+            for name in ("gpt2_345m", "bert_base", "nemotron3_super_120b")
+            } == {1e-4}
 
 
 @pytest.mark.parametrize("with_key", [True, False])
@@ -181,9 +179,10 @@ def test_the_cell_runs_correct_with_and_without_the_key(with_key,
 def test_a_solve_that_ends_at_its_cap_is_not_a_correct_run(monkeypatch):
     """The cell's ``why`` says its loads are balanced as built: a run whose
     solve gave up is not that cell, and says so through ``correct``."""
-    def gives_up(scores, top_k):
+    def gives_up(scores, top_k, choose=None):
         zero = jnp.zeros((scores.shape[1],), jnp.float32)
-        return zero, brb.loads(scores, zero, top_k), jnp.float32(brb.CAP)
+        return (zero, brb.loads(scores, zero, top_k, choose),
+                jnp.float32(brb.CAP))
 
     monkeypatch.setattr(brb, "balance", gives_up)
     result = measure.measure(CELL, SEEDS[4], 0.6, False, True,
@@ -193,6 +192,146 @@ def test_a_solve_that_ends_at_its_cap_is_not_a_correct_run(monkeypatch):
     assert all(pair["holds"] for name, pair in result["compared"].items()
                if name != "router_load_off_mean")
     assert result["correct"] is False
+
+
+# -- the router's choice is the reference's ----------------------------------
+
+def _tilted(biased, top_k, sizes):
+    """A choice other than the plain top-k: the even experts nudged up
+    by ``sizes["tilt"]`` before the top-k is taken."""
+    tilt = sizes["tilt"] * (jnp.arange(biased.shape[1]) % 2 == 0)
+    kth = jax.lax.top_k(biased + tilt, top_k)[0][:, -1:]
+    return (biased + tilt >= kth).astype(jnp.float32)
+
+
+def _within_best_group(biased, top_k, sizes):
+    """A group-limited choice: the ``top_k`` of the one group that holds
+    the token's best expert."""
+    tokens, experts = biased.shape
+    n_group = sizes["n_group"]
+    grouped = biased.reshape(tokens, n_group, experts // n_group)
+    best = jnp.argmax(grouped.max(-1), -1)
+    kept = jnp.where((jnp.arange(n_group) == best[:, None])[..., None],
+                     grouped, -jnp.inf).reshape(tokens, experts)
+    kth = jax.lax.top_k(kept, top_k)[0][:, -1:]
+    return (kept >= kth).astype(jnp.float32)
+
+
+def test_the_solve_counts_loads_by_the_references_own_choice():
+    """A reference that gives ``expert_choice`` has its loads counted
+    through it: every layer inside the tolerance by that choice, and a
+    bias other than the one the plain top-k is balanced by."""
+    _, params, arrays, sizes = _built(SEEDS[0])
+    tilted = {**sizes, "tilt": 0.02}
+    own = types.SimpleNamespace(loss=ref.loss, expert_choice=_tilted)
+    scores = []
+
+    def keep(scores_of_layer):
+        scores.append(scores_of_layer)
+        return brb.balance(scores_of_layer, 2,
+                           lambda b, k: _tilted(b, k, tilted))[0]
+
+    ref.loss(params, arrays, tilted, 1, router_bias=keep)
+    _, solved, report, compared = brb.solve(own, params, arrays, tilted, 1)
+    worst, limit = compared["router_load_off_mean"]
+    assert worst <= limit == report["tolerance"]
+    mean = report["mean_load"]
+    for s, bias, layer in zip(scores, solved["e_router_bias"],
+                              report["layers"]):
+        assert 0 < layer["iterations"] < brb.CAP
+        by_choice = np.asarray(_tilted(s + bias, 2, tilted)).sum(0)
+        assert [by_choice.min(), by_choice.max()] == layer["loads"]
+        assert np.abs(by_choice - mean).max() <= limit
+    plain = brb.solve(ref, params, arrays, sizes, 1)[1]["e_router_bias"]
+    assert solved["e_router_bias"].tobytes() != plain.tobytes()
+
+
+def _routed_config(**published):
+    named = rules.configurations(BENCH, ROOT)
+    config = json.loads(json.dumps(named["nemotron3_super_120b"]))
+    config.update(published)
+    return config
+
+
+def test_a_group_limited_router_needs_a_reference_that_chooses_by_it():
+    """ISSUE 38's probe, the hybrid under ``n_group`` 2, is refused: its
+    reference routes by the plain top-k, so the solve would balance a
+    rule neither side runs.  So is a published ``n_group`` the sizes do
+    not restate, and a choice that strays outside its groups; a
+    reference whose choice keeps to them passes."""
+    config = _routed_config()
+    rules.router_is_the_references("hybrid", config)
+    config["sizes"].update(n_group=2, topk_group=1)
+    with pytest.raises(AssertionError, match="no expert_choice"):
+        rules.router_is_the_references("probe", config)
+    with pytest.raises(AssertionError, match="no expert_choice"):
+        rules.router_is_the_references(
+            "published", _routed_config(n_group=8, topk_group=4))
+    strays = types.SimpleNamespace(expert_choice=_tilted)
+    with pytest.raises(AssertionError, match="outside its groups"):
+        rules.router_is_the_references(
+            "probe", {**config, "sizes": {**config["sizes"], "tilt": 0.0}},
+            strays)
+    keeps = types.SimpleNamespace(expert_choice=_within_best_group)
+    rules.router_is_the_references("probe", config, keeps)
+
+
+def _loads_before(scores, bias, top_k: int):
+    """``loads`` as PR 33 wrote it, kept as the reference the one-group
+    path is held to bit for bit."""
+    biased = scores + bias
+    kth = jax.lax.top_k(biased, top_k)[0][:, -1:]
+    return jnp.sum(biased >= kth, axis=0, dtype=jnp.float32)
+
+
+@jax.jit
+def _balance_before(scores):
+    """``balance`` as PR 33 wrote it, at the hybrid's rehearsal top-k."""
+    top_k = 2
+    experts = scores.shape[1]
+    mean, tolerance = brb.target(scores.shape[0], experts, top_k)
+
+    def unbalanced(state):
+        t, _, load = state
+        return (t < brb.CAP) & (jnp.max(jnp.abs(load - mean)) > tolerance)
+
+    def update(state):
+        t, bias, load = state
+        gamma = jnp.maximum(brb.GAMMA * brb.DECAY ** t, brb.GAMMA_FLOOR)
+        bias = bias + gamma * jnp.sign(mean - load)
+        return t + 1, bias, _loads_before(scores, bias, top_k)
+
+    zero = jnp.zeros((experts,), jnp.float32)
+    return jax.lax.while_loop(
+        unbalanced, update,
+        (jnp.float32(0), zero, _loads_before(scores, zero, top_k)))
+
+
+def test_at_one_group_the_solve_is_what_it_was_bit_for_bit():
+    """On the hybrid's rehearsal sizes, at every ``E`` layer of the walk:
+    today's ``balance`` (no choice of the reference's, by default and
+    named) against PR 33's, and the solved bias the model gets against
+    the one PR 33's rule gives."""
+    _, params, arrays, sizes = _built(SEEDS[6])
+    assert sizes["num_experts_per_tok"] == 2
+    assert not hasattr(ref, "expert_choice")
+    before = []
+
+    def at_expert_layer(scores):
+        t, bias, load = _balance_before(scores)
+        for got in (brb.balance(scores, 2), brb.balance(scores, 2, None)):
+            assert np.asarray(got[0]).tobytes() == np.asarray(bias).tobytes()
+            assert np.asarray(got[1]).tobytes() == np.asarray(load).tobytes()
+            assert int(got[2]) == int(t)
+        np.testing.assert_array_equal(brb.loads(scores, 0.0, 2),
+                                      _loads_before(scores, 0.0, 2))
+        before.append(np.asarray(bias))
+        return bias
+
+    loss = ref.loss(params, arrays, sizes, 1, router_bias=at_expert_layer)
+    solved_loss, solved, _, _ = brb.solve(ref, params, arrays, sizes, 1)
+    assert solved_loss == loss and list(solved) == ["e_router_bias"]
+    assert solved["e_router_bias"].tobytes() == np.stack(before).tobytes()
 
 
 # -- the cell's feed: a fresh batch every step ---------------------------------

@@ -4,12 +4,11 @@ sums on a small synthetic trace, the guard against another run's file,
 and the rehearsal printing the new metrics of a cell and no other."""
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 from benchmarks.harness import measure, scopes, trace_reduce
+from benchmarks.tests import rehearsal
 
 ROOT = measure.ROOT
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -242,11 +241,7 @@ def test_another_runs_file_is_refused(tmp_path, monkeypatch):
 @pytest.mark.parametrize("cell", ["gpt2_345m.train_b8_s1024",
                                   "bert_base.pretrain_b128_s128"])
 def test_rehearsal_prints_the_new_metrics_of_the_cell_and_no_other(cell):
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
-         "--workload", cell, "--seed", "2147483659", "--seconds", "1",
-         "--trace", "1", "--rehearse"],
-        capture_output=True, text=True, timeout=600, cwd=ROOT)
+    out = rehearsal.run(ROOT, cell, 2147483659, 1)
     assert out.returncode == 0, out.stderr[-3000:]
     lines = out.stdout.strip().splitlines()
     said = json.loads(next(ln for ln in lines if ln.startswith(
