@@ -15,4 +15,7 @@ from paddle_tpu.models.bert import (  # noqa: F401
 from paddle_tpu.models.nemotron_h import (  # noqa: F401
     NemotronH, NemotronHConfig, nemotron_h_loss, nemotron_h_tiny,
     routing_load)
+from paddle_tpu.models.bailing_hybrid import (  # noqa: F401
+    BailingHybrid, BailingHybridConfig, bailing_hybrid_loss,
+    bailing_hybrid_tiny)
 from paddle_tpu.models.rank import WideDeep, DeepFM, WideDeepHost  # noqa: F401

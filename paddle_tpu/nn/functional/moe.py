@@ -1,4 +1,4 @@
-"""A latent mixture-of-experts layer that is told which experts it holds.
+"""Mixture-of-experts layers that are told which experts they hold.
 
 The reference framework has no expert layer.  This is expert
 parallelism's layer as one rank sees it (the ``model-configs`` guide,
@@ -8,12 +8,20 @@ experts ``expert_offset .. expert_offset + held`` and computes their part
 of the result.  What the absent experts would have added is left out; on
 one chip there is no exchange, and nothing stands in for it.  The partial
 results of all the shares, with the shared expert counted once, add up to
-the uncut layer (``tests/test_nemotron_h.py``).
+the uncut layer (``tests/test_nemotron_h.py``,
+``tests/test_bailing_hybrid.py``).
+
+Two layers: ``latent_moe``, squared-ReLU experts in a latent narrower
+than the hidden state (``models/nemotron_h.py``), and ``swiglu_moe``,
+SwiGLU experts at the hidden width, ``(silu(u W1_e) * u W3_e) W2_e``, with
+``W1_e`` and ``W3_e`` side by side in one ``w13`` (``models/
+bailing_hybrid.py``).  The router may keep a token to some groups of
+experts (``route_top_k``'s ``n_group``).
 
 Raw arrays, differentiable by jax.  The device scopes ``router``,
 ``latent_down``, ``dispatch``, ``experts``, ``combine``, ``latent_up`` and
-``shared`` are set here, in the backward too, the region around them
-(``mlp``) by the caller.
+``shared`` (``swiglu_moe``: no latent) are set here, in the backward too,
+the region around them (``mlp``) by the caller.
 
 **No token is dropped, at any load.**  Top-k picks distinct experts, so a
 token reaches a held expert at most once and an expert can be reached by
@@ -30,8 +38,10 @@ chosen from the call's static shapes alone:
   in which every expert starts on a row-tile boundary (``dispatch``: sums,
   comparisons and one sort along the tokens, inside the step, no program
   of its own; the rows are gathered by a kernel); ``relu(rows W1_e)`` and
-  ``relu^2 W2_e`` are grouped matmuls (``experts``, ``combine``); the
-  rows are gated and added back to their tokens in float32 by a kernel.
+  ``relu^2 W2_e`` are grouped matmuls (``experts``, ``combine``; SwiGLU:
+  ``rows W13_e`` into ``[a | b]``, and ``silu(a) * b`` taken on its way
+  into ``W2_e``); the rows are gated and added back to their tokens in
+  float32 by a kernel.
   The backward is the same mechanism transposed, and the weight gradients
   arrive in the weights' own ``(held, in, out)`` layout.  The buffer has
   room for the worst routing (``min(top_k, held)`` rows a token, the
@@ -55,7 +65,8 @@ from jax.ad_checkpoint import checkpoint_name
 from paddle_tpu.framework import monitor
 from paddle_tpu.ops.pallas.common import traced_once
 
-__all__ = ["route_top_k", "held_gates", "latent_moe"]
+__all__ = ["route_top_k", "held_gates", "latent_moe", "swiglu_moe",
+           "swiglu"]
 
 # what the router's scores are computed in; the builder's check on the
 # chip sets bfloat16 here to show that the hidden-state comparison sees
@@ -69,32 +80,55 @@ _ROUTER_DTYPE = jnp.float32
 ROUTER_SAVED = ("router_sel", "router_logits")
 
 monitor.describe("moe_calls_traced_total",
-                 "calls of latent_moe, added once per traced call (a "
-                 "trace-time count)")
+                 "calls of latent_moe or swiglu_moe, added once per traced "
+                 "call (a trace-time count)")
 monitor.describe("moe_expert_rows_computed_total",
                  "rows (token x expert) that the held routed experts' "
                  "matmuls execute, added once per traced call of "
-                 "latent_moe on the path that call takes (a trace-time "
-                 "count): tokens x held on the dense mask; on the sorted "
-                 "rows, whose kernels skip tiles by a run-time count, the "
+                 "latent_moe or swiglu_moe on the path that call takes (a "
+                 "trace-time count): tokens x held on the dense mask; on "
+                 "the sorted rows, whose kernels skip tiles by a run-time "
+                 "count, the "
                  "rows visited at the expected load, each held expert's "
                  "tokens x top_k / n_routed rounded up to row tiles")
 monitor.describe("moe_expert_rows_expected_total",
                  "rows the routed load needs on average: tokens x top_k x "
                  "held / n_routed, added once per traced call of "
-                 "latent_moe (a trace-time count)")
+                 "latent_moe or swiglu_moe (a trace-time count)")
 
 
 def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def route_top_k(u, router_w, router_bias, top_k: int, scale: float):
+def swiglu(x, w13):
+    """``silu(x W1) * x W3`` for ``w13 = [W1 | W3]`` (in, 2 x width)."""
+    a, b = jnp.split(x @ w13, 2, axis=-1)
+    return jax.nn.silu(a) * b
+
+
+def _keep_groups(biased, n_group: int, topk_group: int):
+    """DeepSeek-V3's group limit (``noaux_tc``): the experts cut into
+    ``n_group`` groups of consecutive ids, each group scored by the sum of
+    its two best ``biased`` scores; the ``topk_group`` best groups keep
+    their scores, every other expert reads -inf."""
+    grouped = biased.reshape(*biased.shape[:-1], n_group, -1)
+    best_two = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(best_two, topk_group)
+    keep = jnp.any(kept[..., :, None] == jnp.arange(n_group), axis=-2)
+    return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+        biased.shape)
+
+
+def route_top_k(u, router_w, router_bias, top_k: int, scale: float,
+                n_group: int = 1, topk_group: int = 1):
     """``s = sigmoid(float32(u) W_r^T)`` over all experts; ``sel =
     top_k(s + router_bias)`` (the bias only chooses; no gradient reaches
-    it); ``g = scale * s[sel] / (sum s[sel] + 1e-20)``.  Returns ``(sel,
-    g)``, both (..., top_k); ``g`` is normalised over all ``top_k``
-    whether the experts are held here or not.
+    it), among the ``topk_group`` best of ``n_group`` groups of experts
+    where ``n_group`` is above 1 (``_keep_groups``); ``g = scale * s[sel]
+    / (sum s[sel] + 1e-20)``.  Returns ``(sel, g)``, both (..., top_k);
+    ``g`` is normalised over all ``top_k`` whether the experts are held
+    here or not.
 
     ``sel`` and the logits at ``sel`` carry the names ``ROUTER_SAVED``
     (identities outside a ``jax.checkpoint`` whose policy names them).
@@ -108,8 +142,10 @@ def route_top_k(u, router_w, router_bias, top_k: int, scale: float):
                         precision=jax.lax.Precision.HIGHEST,
                         preferred_element_type=_ROUTER_DTYPE)
     s = jax.nn.sigmoid(logits)
-    _, sel = jax.lax.top_k(
-        s + jax.lax.stop_gradient(router_bias).astype(s.dtype), top_k)
+    biased = s + jax.lax.stop_gradient(router_bias).astype(s.dtype)
+    if n_group > 1:
+        biased = _keep_groups(biased, n_group, topk_group)
+    _, sel = jax.lax.top_k(biased, top_k)
     sel_name, logits_name = ROUTER_SAVED
     sel = checkpoint_name(sel, sel_name)
     picked = jax.nn.sigmoid(checkpoint_name(
@@ -134,6 +170,17 @@ def _dense_experts(z, w1, w2, gates):
     with jax.named_scope("combine"):
         return jnp.einsum("tef,efd->td",
                           inner * gates.astype(z.dtype)[..., None], w2)
+
+
+def _dense_swiglu(x, w13, w2, gates):
+    """``_dense_experts`` for SwiGLU experts: ``x`` (tokens, hidden),
+    ``w13`` (held, hidden, 2 x inner), ``w2`` (held, inner, hidden)."""
+    with jax.named_scope("experts"):
+        a, b = jnp.split(jnp.einsum("td,edf->tef", x, w13), 2, axis=-1)
+        inner = jax.nn.silu(a) * b
+    with jax.named_scope("combine"):
+        return jnp.einsum("tef,efd->td",
+                          inner * gates.astype(x.dtype)[..., None], w2)
 
 
 def _buffer_tiles(tokens: int, held: int, top_k: int, tile_rows: int) -> int:
@@ -248,7 +295,7 @@ def _sorted_fwd_traced(z, w1, w2, gates, hit, top_k, interpret):
     with jax.named_scope("experts"):
         r = gmm.group_rows(x, w1, tile_group, used, epilogue="relu")
     with jax.named_scope("combine"):
-        y2 = gmm.group_rows(r, w2, tile_group, used, square_x=True)
+        y2 = gmm.group_rows(r, w2, tile_group, used, prologue="square")
         y = gmm.scatter_rows(y2, gate, row_token, used, tokens)
     return y.astype(z.dtype), (w1, w2, hit, tile_group, used, tile_start,
                                key, row_token, gate, x, r, y2)
@@ -267,7 +314,7 @@ def _sorted_bwd_traced(saved, dy, interpret):
         dpre = gmm.group_rows(dy2, w2, tile_group, used, transpose_w=True,
                               epilogue="times_2m", m=r)
         dw2 = gmm.group_weights(r, dy2, tile_group, used, held,
-                                square_x=True, out_dtype=w2.dtype)
+                                prologue="square", out_dtype=w2.dtype)
     with jax.named_scope("experts"):
         dx = gmm.group_rows(dpre, w1, tile_group, used, transpose_w=True)
         dw1 = gmm.group_weights(x, dpre, tile_group, used, held,
@@ -280,6 +327,89 @@ def _sorted_bwd_traced(saved, dy, interpret):
 
 
 _sorted_experts.defvjp(_sorted_fwd, _sorted_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _sorted_swiglu(x, w13, w2, gates, hit, top_k: int):
+    """``_sorted_experts`` for SwiGLU experts: ``sum_e gates[:, e] *
+    (silu(x W1_e) * x W3_e) W2_e`` over the routed pairs ``hit`` alone,
+    on the same row buffer: ``rows W13_e`` into ``[a | b]`` (``experts``),
+    ``silu(a) * b`` taken on its way into ``W2_e`` (``combine``)."""
+    return _swiglu_fwd(x, w13, w2, gates, hit, top_k)[0]
+
+
+def _swiglu_fwd(x, w13, w2, gates, hit, top_k):
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    return _swiglu_fwd_traced(x, w13, w2, gates, hit, top_k, gmm._INTERPRET)
+
+
+def _swiglu_bwd(top_k, saved, dy):
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    return (*_swiglu_bwd_traced(saved, dy, gmm._INTERPRET), None)
+
+
+@traced_once(static_argnums=(5, 6), inline=False)
+def _swiglu_fwd_traced(x, w13, w2, gates, hit, top_k, interpret):
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    tokens, held = hit.shape
+    with jax.named_scope("dispatch"):
+        tile_group, used, tile_start, key, row_token, gate = _row_plan(
+            hit, gates, gmm.TILE_ROWS,
+            _buffer_tiles(tokens, held, top_k, gmm.TILE_ROWS))
+        rows = gmm.gather_rows(x.astype(jnp.float32), row_token, used,
+                               out_dtype=x.dtype)
+    with jax.named_scope("experts"):
+        ab = gmm.group_rows(rows, w13, tile_group, used)
+    with jax.named_scope("combine"):
+        y2 = gmm.group_rows(ab, w2, tile_group, used, prologue="swiglu")
+        y = gmm.scatter_rows(y2, gate, row_token, used, tokens)
+    return y.astype(x.dtype), (w13, w2, hit, tile_group, used, tile_start,
+                               key, row_token, gate, rows, ab, y2)
+
+
+@traced_once(static_argnums=(2,), inline=False)
+def _swiglu_bwd_traced(saved, dy, interpret):
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    w13, w2, hit, tile_group, used, tile_start, key, row_token, gate, \
+        rows, ab, y2 = saved
+    tokens, held = hit.shape
+    with jax.named_scope("combine"):
+        dy2, dgate = gmm.gather_rows(dy.astype(jnp.float32), row_token, used,
+                                     gate=gate, other=y2, out_dtype=dy.dtype)
+        dab = gmm.group_rows(dy2, w2, tile_group, used, transpose_w=True,
+                             epilogue="dswiglu", m=ab)
+        dw2 = gmm.group_weights(ab, dy2, tile_group, used, held,
+                                prologue="swiglu", out_dtype=w2.dtype)
+    with jax.named_scope("experts"):
+        drows = gmm.group_rows(dab, w13, tile_group, used, transpose_w=True)
+        dw13 = gmm.group_weights(rows, dab, tile_group, used, held,
+                                 out_dtype=w13.dtype)
+    with jax.named_scope("dispatch"):
+        dx = gmm.scatter_rows(drows, jnp.ones_like(gate), row_token, used,
+                              tokens).astype(dy.dtype)
+        dgates = _gates_of_rows(dgate, hit, key, tile_start, gmm.TILE_ROWS)
+    return dx, dw13, dw2, dgates
+
+
+_sorted_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+def _count_rows(tokens: int, top_k: int, held: int, n_routed: int,
+                follow_load: bool) -> None:
+    """The layer's ``moe_*_total`` counts, once per traced call."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    if follow_load:
+        expected = -(-tokens * top_k // n_routed)
+        computed = held * gmm.TILE_ROWS * -(-expected // gmm.TILE_ROWS)
+    else:
+        computed = held * tokens
+    monitor.stat_add("moe_calls_traced_total", 1)
+    monitor.stat_add("moe_expert_rows_computed_total", computed)
+    monitor.stat_add("moe_expert_rows_expected_total",
+                     tokens * top_k * held / n_routed)
 
 
 def latent_moe(u, router_w, router_bias, down_w, w1, w2, up_w, shared_w1,
@@ -298,15 +428,7 @@ def latent_moe(u, router_w, router_bias, down_w, w1, w2, up_w, shared_w1,
     held, n_routed = w1.shape[0], router_w.shape[0]
     tokens = u.shape[0] * u.shape[1]
     follow_load = gmm.supported(tokens, w1.shape[1], w1.shape[2], u.dtype)
-    if follow_load:
-        expected = -(-tokens * top_k // n_routed)
-        computed = held * gmm.TILE_ROWS * -(-expected // gmm.TILE_ROWS)
-    else:
-        computed = held * tokens
-    monitor.stat_add("moe_calls_traced_total", 1)
-    monitor.stat_add("moe_expert_rows_computed_total", computed)
-    monitor.stat_add("moe_expert_rows_expected_total",
-                     tokens * top_k * held / n_routed)
+    _count_rows(tokens, top_k, held, n_routed, follow_load)
     with jax.named_scope("router"):
         sel, g = route_top_k(u, router_w, router_bias, top_k, scale)
     with jax.named_scope("dispatch"):
@@ -324,3 +446,40 @@ def latent_moe(u, router_w, router_bias, down_w, w1, w2, up_w, shared_w1,
         y = y.reshape(*u.shape[:2], -1) @ up_w
     with jax.named_scope("shared"):
         return y + _relu2(u @ shared_w1) @ shared_w2
+
+
+def swiglu_moe(u, router_w, router_bias, w13, w2, shared_w13, shared_w2, *,
+               top_k: int, scale: float, expert_offset: int,
+               n_group: int = 1, topk_group: int = 1):
+    """The SwiGLU expert layer on ``u`` (batch, seq, hidden):
+
+        sel, g = route_top_k(u)                    over all n_routed, by
+                                                   groups where n_group > 1
+        y = sum_{e in sel, held} g_e (silu(u W1_e) * u W3_e) W2_e
+            + swiglu(u, W13_s) W2_s                (the shared expert)
+
+    ``w13`` (held, hidden, 2 x inner) and ``w2`` (held, inner, hidden) are
+    the experts ``expert_offset .. expert_offset + held``; the routed
+    half takes the sorted rows where ``grouped_matmul.supported`` accepts
+    the hidden and inner widths, else the dense mask."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+    held, n_routed = w13.shape[0], router_w.shape[0]
+    tokens = u.shape[0] * u.shape[1]
+    follow_load = gmm.supported(tokens, w13.shape[1], w2.shape[1], u.dtype)
+    _count_rows(tokens, top_k, held, n_routed, follow_load)
+    with jax.named_scope("router"):
+        sel, g = route_top_k(u, router_w, router_bias, top_k, scale,
+                             n_group, topk_group)
+    with jax.named_scope("dispatch"):
+        gates = held_gates(sel, g, held, expert_offset).reshape(tokens, held)
+    x = u.reshape(tokens, -1)
+    if follow_load:
+        with jax.named_scope("dispatch"):
+            hit = held_gates(sel, jnp.ones_like(g), held,
+                             expert_offset).reshape(tokens, held) > 0
+        y = _sorted_swiglu(x, w13, w2, gates, hit, top_k)
+    else:
+        y = _dense_swiglu(x, w13, w2, gates)
+    with jax.named_scope("shared"):
+        return y.reshape(u.shape) + swiglu(u, shared_w13) @ shared_w2

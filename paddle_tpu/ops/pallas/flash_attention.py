@@ -153,7 +153,9 @@ def _capable(q_shape, k_shape, no_mask, causal, bias_shape,
         # end-aligned causal with more queries than keys leaves rows with
         # no visible key; semantics degenerate — use the XLA path
         return False
-    if d % 128 != 0 and d not in (64,):
+    # 192: latent attention's q.k width (128 + 64 rotary), one block
+    # spanning the whole width, as Mosaic compiles it for a v5e
+    if d % 128 != 0 and d not in (64, 192):
         return False
     if bias_shape is not None and \
             _canon_bias_shape(bias_shape, b, h, sq, sk) is None:

@@ -35,7 +35,14 @@ Names in a device trace (``start_profiler`` or a bare
   ``router``, ``latent_down``, ``dispatch``, ``experts``, ``combine``,
   ``latent_up``, ``shared``; ``nn/functional/moe.py``) and its Mamba-2
   layer under ``ssm`` (inner ``ln``, ``in_proj``, ``conv``, ``scan``,
-  ``gate_norm``, ``out``; ``nn/functional/ssm.py``); ``optimizer`` from
+  ``gate_norm``, ``out``; ``nn/functional/ssm.py``); from
+  ``models/bailing_hybrid.py`` the same ``embed``, ``head_loss``, its
+  latent attention under ``attn`` (``ln``, ``qkv``, ``core``, ``out``),
+  its dense MLP under ``mlp`` (``ln``, ``up``, ``down``), its SwiGLU
+  experts under ``mlp`` (``ln``, ``router``, ``dispatch``, ``experts``,
+  ``combine``, ``shared``) and its KDA layer under ``kda`` (inner ``ln``,
+  ``qkv``, ``conv``, ``gate``, ``scan``, ``out_norm``, ``out``;
+  ``nn/functional/kda.py``); ``optimizer`` from
   ``jit.apply_functional_update``, ``grad_exchange`` where a step reduces
   gradients itself (``parallel/zero.py``).  jax adds the pass: ``jvp(`` is
   the forward, ``transpose(`` the backward, ``rematted_computation`` the
@@ -48,8 +55,9 @@ Names in a device trace (``start_profiler`` or a bare
   (``nn/functional/moe.py``), ``moe_router_kept_blocks_total`` (expert
   blocks whose ``jax.checkpoint`` keeps the router's choice for the
   backward, one per ``E`` block of a stack traced under ``remat``;
-  ``models/nemotron_h.py``), ``ssm_chunks_traced_total``
-  (``nn/functional/ssm.py``).
+  ``models/nemotron_h.py``, ``models/bailing_hybrid.py``),
+  ``ssm_chunks_traced_total`` (``nn/functional/ssm.py``),
+  ``kda_chunks_traced_total`` (``nn/functional/kda.py``).
 """
 from __future__ import annotations
 

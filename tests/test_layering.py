@@ -39,8 +39,11 @@ MAIN_PATH = {
     "paddle_tpu/models/gpt.py": LEAF,
     "paddle_tpu/models/bert.py": LEAF,
     "paddle_tpu/models/nemotron_h.py": LEAF,
+    "paddle_tpu/models/bailing_hybrid.py": LEAF,
     "paddle_tpu/nn/functional/moe.py": LEAF,
     "paddle_tpu/nn/functional/ssm.py": LEAF,
+    "paddle_tpu/nn/functional/kda.py": LEAF,
+    "paddle_tpu/nn/functional/rotary.py": LEAF,
     "paddle_tpu/ops/pallas/flash_attention.py": LEAF,
     "paddle_tpu/ops/pallas/grouped_matmul.py": LEAF,
     "paddle_tpu/ops/pallas/common.py": LEAF,

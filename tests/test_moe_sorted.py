@@ -163,7 +163,10 @@ def test_the_layer_takes_the_sorted_path_where_the_gate_accepts(monkeypatch):
 def test_the_gate_declines_what_the_kernels_cannot_tile(interpret):
     assert gmm.supported(4096, 1024, 2688, jnp.bfloat16)
     assert gmm.supported(256, 128, 256, jnp.float32)
-    assert not gmm.supported(8192, 1024, 2688, jnp.bfloat16)  # VMEM copy
+    # a token copy too wide for VMEM is held a block of columns at a time
+    assert gmm.supported(8192, 1024, 2688, jnp.bfloat16)
+    assert gmm.supported(8192, 2560, 768, jnp.bfloat16)
+    assert not gmm.supported(65536, 256, 256, jnp.bfloat16)  # no block fits
     assert not gmm.supported(255, 128, 256, jnp.float32)    # no row tile
     assert not gmm.supported(4096, 32, 48, jnp.float32)     # nemotron_h_tiny
     assert not gmm.supported(4096, 1024, 2688, jnp.float16)
@@ -224,6 +227,39 @@ def test_the_analysis_recorder_is_not_served_a_cached_trace(interpret):
                     "_scatter_kernel"]
     assert np.array_equal(np.asarray(jax.jit(_latent_moe)(u, p)),
                           np.asarray(want))
+
+
+def _jaxpr_digest(fn, *args):
+    import hashlib
+    return hashlib.sha256(str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()
+
+
+def test_at_one_group_the_router_and_the_sorted_rows_trace_as_before(
+        interpret):
+    """The latent experts' program is what it was before the group limit
+    and the SwiGLU path were added: the router at ``n_group`` 1 and the
+    sorted rows' value and gradients give the jaxpr whose text hashed so
+    on the tree before them (the same shapes, interpret mode)."""
+    rng = np.random.default_rng(0)
+    u = jnp.asarray(rng.standard_normal((1, 512, 64)), jnp.float32)
+    router_w = jnp.asarray(rng.standard_normal((16, 64)), jnp.float32)
+    bias = jnp.zeros(16)
+    assert _jaxpr_digest(
+        lambda u, w, b: moe.route_top_k(u, w, b, 4, 2.5), u, router_w,
+        bias) == ("bac0a985ff86ad66f02e3dd5f63f88ac"
+                  "65b732a405d423980132bf349320173f")
+    z = jnp.asarray(rng.standard_normal((512, 128)), jnp.float32)
+    w1 = jnp.asarray(rng.standard_normal((4, 128, 256)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((4, 256, 128)), jnp.float32)
+    gates = jnp.asarray(rng.random((512, 4)), jnp.float32)
+    hit = gates > 0.5
+
+    def f(z, w1, w2, gates):
+        return jnp.sum(moe._sorted_experts(z, w1, w2, gates, hit, 4))
+
+    assert _jaxpr_digest(jax.value_and_grad(f, argnums=(0, 1, 2, 3)), z, w1,
+                         w2, gates) == ("ddd9d98c09312741244347784843f105"
+                                        "ec8bdc6f9ce88614862ad5b23fb052e8")
 
 
 def test_import_paddle_tpu_leaves_the_kernels_unimported():

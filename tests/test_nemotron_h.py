@@ -604,3 +604,46 @@ def test_config_says_what_it_refuses():
     c = NemotronHConfig()
     assert c.num_layers == 88 and c.experts_held == 512
     assert [c.hybrid_override_pattern.count(k) for k in "ME*"] == [40, 40, 8]
+
+
+@pytest.mark.parametrize("path", ["dense_mask", "sorted_rows"])
+def test_nemotron_h_under_a_bias_agrees_with_the_reference(path, monkeypatch):
+    """The whole model, its buffer filled the way the benchmark fills it:
+    loss and every parameter's gradient against the reference's
+    ``loss_and_grads`` under the same bias, on the dense mask and on the
+    sorted rows (kernels in interpret mode)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    monkeypatch.setattr(gmm, "_INTERPRET", path == "sorted_rows")
+    c = nemotron_h_tiny(remat=False, seed=5, hybrid_override_pattern="ME*E",
+                        moe_latent_size=128, moe_intermediate_size=128)
+    model = NemotronH(c)
+    for name in ("e_w2", "e_up_w"):      # as loud as the shared expert
+        model._parameters[name]._data = model._parameters[name]._data * 10.0
+    rng = np.random.default_rng(23)
+    # a bias that decides: expert 1 (held) for every token, expert 6
+    # (absent) for none, the rest nudged; another row for each layer
+    bias = (0.05 * rng.standard_normal((2, c.n_routed_experts))
+            ).astype(np.float32)
+    bias[:, 1], bias[:, 6] = 5.0, -5.0
+    model.set_state_dict({"e_router_bias": bias})
+    ids = rng.integers(0, c.vocab_size, (2, 256)).astype(np.int32)
+    program, params = _loss_of_params(model, ids)
+    monitor.reset_all_stats()
+    with jax.default_matmul_precision("highest"):
+        got, got_grads = jax.jit(jax.value_and_grad(program))(params)
+    stats = monitor.all_stats()
+    rows = stats["moe_expert_rows_computed_total"] \
+        / stats["moe_calls_traced_total"]
+    # 512 tokens, 2 of 8 experts each: the dense mask computes every held
+    # expert on each token, the sorted rows one tile an expert
+    assert rows == c.experts_held * (512 if path == "dense_mask"
+                                     else gmm.TILE_ROWS)
+    under_bias = {**params, "e_router_bias": jnp.asarray(bias)}
+    want, want_grads = ref.loss_and_grads(under_bias, (ids, ids), sizes_of(c))
+    assert float(got) == pytest.approx(want, rel=2e-6)
+    assert ref.loss(params, (ids, ids), sizes_of(c), 1) \
+        != pytest.approx(want, rel=1e-5)
+    for name in params:
+        assert np.abs(np.asarray(want_grads[name])).max() > 0, name
+        assert_close(got_grads[name], want_grads[name], tol=5e-4, what=name)
+    assert not np.asarray(want_grads["e_router_bias"]).any()

@@ -17,9 +17,10 @@ from paddle_tpu import profiler
 from paddle_tpu.framework import health, monitor
 from paddle_tpu.framework.observability import tracer
 from paddle_tpu.jit import TrainStep
-from paddle_tpu.models import (GPT, Bert, NemotronH, bert_pretrain_loss,
-                               bert_tiny, gpt_loss, gpt_tiny,
-                               nemotron_h_loss, nemotron_h_tiny)
+from paddle_tpu.models import (GPT, BailingHybrid, Bert, NemotronH,
+                               bailing_hybrid_loss, bailing_hybrid_tiny,
+                               bert_pretrain_loss, bert_tiny, gpt_loss,
+                               gpt_tiny, nemotron_h_loss, nemotron_h_tiny)
 from paddle_tpu.parallel import (ShardedTrainStep, get_mesh, make_mesh,
                                  set_mesh)
 
@@ -33,6 +34,16 @@ INNER_NEMOTRON = {
     "mlp": ("ln", "router", "latent_down", "dispatch", "experts", "combine",
             "latent_up", "shared"),
     "ssm": ("ln", "in_proj", "conv", "scan", "gate_norm", "out")}
+# KDA linear attention beside latent attention: its own region ``kda``,
+# the latent attention under ``attn``, dense and SwiGLU experts under
+# ``mlp``
+BAILING = "bailing_hybrid_tiny-remat"
+INNER_BAILING = {
+    "attn": INNER["attn"],
+    "mlp": ("ln", "up", "down", "router", "dispatch", "experts", "combine",
+            "shared"),
+    "kda": ("ln", "qkv", "conv", "gate", "scan", "out_norm", "out")}
+OWN_REGION = {NEMOTRON: "ssm", BAILING: "kda"}
 CHILDREN = ("TrainStep.prepare", "TrainStep.launch", "TrainStep.commit")
 # instructions of the compiled step (tiny sizes, CPU) whose op_name is
 # under no region: the layer scan's slicing, AMP casts, the gradients'
@@ -42,7 +53,10 @@ UNSCOPED_LIMIT = {"gpt_tiny-TrainStep": 0.29, "bert_tiny-remat": 0.21,
                   "gpt_tiny-ShardedTrainStep-zero1-dp2": 0.27,
                   # per-type stacks sliced once a layer, AMP casts, the
                   # gradients put back into their stacks: 0.135
-                  NEMOTRON: 0.165}
+                  NEMOTRON: 0.165,
+                  # stacks sliced a layer, AMP casts, the gradients put
+                  # back: 0.081, with a fifth of room
+                  BAILING: 0.097}
 
 
 def _gpt_batch(rng):
@@ -74,6 +88,9 @@ def _build(case):
     elif case == NEMOTRON:
         model, loss = NemotronH(nemotron_h_tiny(remat=True)), nemotron_h_loss
         arrays = _gpt_batch(rng)
+    elif case == BAILING:
+        model = BailingHybrid(bailing_hybrid_tiny(remat=True))
+        loss, arrays = bailing_hybrid_loss, _gpt_batch(rng)
     else:
         model, loss = GPT(gpt_tiny(remat=False)), gpt_loss
         arrays = _gpt_batch(rng)
@@ -113,23 +130,24 @@ def test_regions_in_the_compiled_step(built):
     outside = 0
     for path in paths:
         which, tokens = _passes_of(path)
-        region = next((t for t in tokens
-                       if t in MODEL_REGIONS + ("ssm", "optimizer")), None)
+        region = next((t for t in tokens if t in MODEL_REGIONS
+                       + ("ssm", "kda", "optimizer")), None)
         outside += region is None
         if region is not None:
             seen.setdefault((which, region), set()).update(tokens)
     for region in MODEL_REGIONS:
         assert ("fwd", region) in seen and ("bwd", region) in seen, region
-    for region, inner in (INNER_NEMOTRON if case == NEMOTRON
-                          else INNER).items():
+    for region, inner in {NEMOTRON: INNER_NEMOTRON,
+                          BAILING: INNER_BAILING}.get(case, INNER).items():
         # post-LN BERT has no LayerNorm before the projections, but an
         # ``ln`` after each block all the same
         assert set(inner) <= seen[("fwd", region)], (region, inner)
         assert set(inner) <= seen[("bwd", region)], (region, inner)
-    assert (("fwd", "ssm") in seen) == (case == NEMOTRON)
+    for own in ("ssm", "kda"):
+        assert (("fwd", own) in seen) == (OWN_REGION.get(case) == own)
     assert ("fwd", "optimizer") in seen          # no jvp, no transpose
     assert ("bwd", "optimizer") not in seen
-    remat = case in ("bert_tiny-remat", NEMOTRON)
+    remat = case in ("bert_tiny-remat", NEMOTRON, BAILING)
     for region in ("attn", "mlp"):
         assert (("recompute", region) in seen) == remat, region
     assert ("recompute", "head_loss") not in seen

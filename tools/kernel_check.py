@@ -34,7 +34,14 @@ the module's own reference.  What it runs, with what the repo already has:
   rows down to none): the gather plain and gated, the grouped matmul's
   four variants, both weight gradients and the scatter, against float32
   matmuls a group at the highest precision, over the tiles the load
-  fills.
+  fills;
+* the SwiGLU experts on those kernels at the Ling cell's shapes (8192
+  tokens of 2560, 8 experts of 2560 -> 768 -> 2560, the token copy by
+  blocks of columns), ``moe._sorted_swiglu`` forward and backward (the
+  ``swiglu`` prologue, the ``dswiglu`` epilogue) under an even load of
+  about 128 rows an expert and a skewed one (1100 rows down to none),
+  against the dense mask in float32 at the highest precision: y, dx,
+  dW13, dW2 and the gates' gradient.
 
 ``python tools/kernel_check.py grouped`` runs the rows of one family
 (``flash``, ``tail``, ``other``, ``grouped``) alone.
@@ -63,6 +70,9 @@ QUANT_SHAPE = (4096, 1024)
 GROUPED_SHAPE = (34816, 8, 1024, 2688)   # rows, experts, latent, inner
 GROUPED_TOKENS = 4096
 GROUPED_LOAD = (838, 400, 200, 100, 50, 20, 5, 0)
+SWIGLU_SHAPE = (8192, 8, 2560, 768)      # tokens, experts, hidden, inner
+SWIGLU_TOP_K = 8
+SWIGLU_SKEWED = (1100, 400, 200, 100, 50, 20, 5, 0)
 
 
 def check(name, run_kernel, run_reference, labels, tol=None, run_also=None):
@@ -304,7 +314,7 @@ def grouped_rows():
     def kernels(src, r, dy, w1, w2):
         x = gmm.gather_rows(src, row_token, tiles_used, out_dtype=bf16)
         rel = gmm.group_rows(x, w1, tile_group, tiles_used, epilogue="relu")
-        y2 = gmm.group_rows(r, w2, tile_group, tiles_used, square_x=True,
+        y2 = gmm.group_rows(r, w2, tile_group, tiles_used, prologue="square",
                             out_dtype=jnp.float32)
         dy2, dgate = gmm.gather_rows(src, row_token, tiles_used, gate=gate,
                                      other=y2, out_dtype=bf16)
@@ -313,7 +323,7 @@ def grouped_rows():
         dx = gmm.group_rows(r, w1, tile_group, tiles_used, transpose_w=True,
                             out_dtype=jnp.float32)
         dw2 = gmm.group_weights(r, dy, tile_group, tiles_used, held,
-                                square_x=True)
+                                prologue="square")
         dw1 = gmm.group_weights(dy, r, tile_group, tiles_used, held)
         y = gmm.scatter_rows(y2, gate, row_token, tiles_used, tokens)
         return tuple(jnp.where(in_use, t, 0)
@@ -348,6 +358,57 @@ def grouped_rows():
           functools.partial(jax.jit(reference), src, r, dy, w1, w2),
           ("gather", "relu", "y2", "gather_gated", "row_dot", "dpre", "dx",
            "dw2", "dw1", "scatter"))
+    swiglu_rows()
+
+
+def swiglu_rows():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional import moe
+
+    tokens, held, hidden, inner = SWIGLU_SHAPE
+    rng = np.random.default_rng(1)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    x = jnp.asarray(rng.standard_normal((tokens, hidden)), bf16)
+    dy = jnp.asarray(rng.standard_normal((tokens, hidden)), bf16)
+    w13 = jnp.asarray(rng.standard_normal((held, hidden, 2 * inner)) * 0.02,
+                      bf16)
+    w2 = jnp.asarray(rng.standard_normal((held, inner, hidden)) * 0.02, bf16)
+    even = rng.random((tokens, held)) < 1 / 64
+    skewed = np.zeros((tokens, held), bool)
+    for e, load in enumerate(SWIGLU_SKEWED):
+        skewed[rng.choice(tokens, load, replace=False), e] = True
+    for name, hit in (("even", even), ("skewed", skewed)):
+        gates = jnp.asarray(np.where(hit, rng.random(hit.shape) * 0.5 + 0.1,
+                                     0), f32)
+        hit = jnp.asarray(hit)
+
+        def kernels(x, w13, w2, gates, dy, hit=hit):
+            y, back = jax.vjp(lambda *a: moe._sorted_swiglu(
+                *a, hit, SWIGLU_TOP_K), x, w13, w2, gates)
+            dx, dw13, dw2, dgates = back(dy)
+            return y, dx, dw13, dw2, dgates
+
+        def reference(x, w13, w2, gates, dy, hit=hit):
+            def dense(x, w13, w2, gates):
+                y = 0.0
+                for e in range(held):
+                    a, b = jnp.split(dot(x, w13[e]), 2, axis=-1)
+                    y += gates[:, e:e + 1] * dot(jax.nn.silu(a) * b, w2[e])
+                return y
+            y, back = jax.vjp(dense, *(t.astype(f32)
+                                       for t in (x, w13, w2, gates)))
+            dx, dw13, dw2, dgates = back(dy.astype(f32))
+            return y, dx, dw13, dw2, jnp.where(hit, dgates, 0)
+
+        rows = int(np.asarray(hit).sum())
+        check(f"swiglu experts tokens={tokens} experts={held} "
+              f"{hidden}->{inner}->{hidden} load={name} rows={rows}",
+              functools.partial(jax.jit(kernels), x, w13, w2, gates, dy),
+              functools.partial(jax.jit(reference), x, w13, w2, gates, dy),
+              ("y", "dx", "dw13", "dw2", "dgates"))
 
 
 def main() -> int:
