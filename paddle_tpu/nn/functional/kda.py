@@ -9,10 +9,10 @@ of Kimi Linear (arXiv:2510.26692) as a model file composes it
     o_t = S_t^T q_t
 
 with ``alpha_t`` in (0, 1] per key channel and ``beta_t`` in (0, 1) per
-head.  Everything is ``jax.numpy`` / ``jax.lax``, differentiable by jax;
-no kernel.  The device scopes ``qkv``, ``conv``, ``gate``, ``scan``,
-``out_norm`` and ``out`` are set here, the region around them (``kda``)
-and its ``ln`` by the caller.
+head.  Everything but the state's carry across chunks is ``jax.numpy`` /
+``jax.lax``, differentiable by jax.  The device scopes ``qkv``, ``conv``,
+``gate``, ``scan``, ``out_norm`` and ``out`` are set here, the region
+around them (``kda``) and its ``ln`` by the caller.
 
 **Chunks** (``kda_chunked``).  The sequence is cut into chunks of ``C``
 positions; within one, with ``g_t = log alpha_t`` and ``gamma_i = sum_{t
@@ -25,12 +25,15 @@ of the delta rule gives, for the state ``S`` entering the chunk,
     S <- Diag(Gamma_C) S + (K o Gamma_C / Gamma)^T Delta
 
 Everything but the last line is computed for all chunks at once; the
-state is carried across chunks by a ``lax.scan`` as ``S <- M S + B`` with
-``M = Diag(Gamma_C) - (K o Gamma_C / Gamma)^T W`` and ``B = (K o Gamma_C
-/ Gamma)^T U``, one ``(d_k, d_k) x (d_k, d_v)`` product a chunk.  Every
-product of the rule is float32 at the highest matmul precision, so that
-on float32 inputs the chunks agree with the token recurrence to float32's
-rounding.  The unit lower-triangular
+state is carried across chunks as ``S <- M S + B`` with ``M =
+Diag(Gamma_C) - (K o Gamma_C / Gamma)^T W`` and ``B = (K o Gamma_C /
+Gamma)^T U``, one ``(d_k, d_k) x (d_k, d_v)`` product a chunk: by the
+Pallas kernels of ``ops/pallas/kda_carry.py``, forward and backward, which
+hold the state in VMEM across all chunks, where ``kda_carry.supported``
+takes the widths and the state's dtype (a TPU, lane-wide heads), else by
+a ``lax.scan``.  Every product of the rule is float32 at the highest
+matmul precision, so that on float32 inputs the chunks agree with the
+token recurrence to float32's rounding.  The unit lower-triangular
 inverse is taken by blocks of 16: forward substitution inside the
 diagonal blocks, 16 steps of a row each, in float32, and block products
 below them (its gradient, ``T^T dT T^T``, two matmuls).
@@ -78,6 +81,10 @@ monitor.describe("kda_chunks_traced_total",
                  "chunks (batch x heads x chunks a sequence) of the KDA "
                  "chunked delta rule, added once per traced call of "
                  "kda_chunked (a trace-time count)")
+monitor.describe("kda_carry_kernel_total",
+                 "traced calls of kda_chunked whose state was carried "
+                 "across chunks by the Pallas kernels of "
+                 "ops/pallas/kda_carry.py (a trace-time count)")
 
 
 def l2_norm(x, eps: float = 1e-6):
@@ -151,6 +158,27 @@ def _inverse_bwd(t, dt):
 _unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
+def _carry(m, b):
+    """The state entering each chunk, (b, n, heads, d_k, d_v), of ``S_0
+    = 0, S <- M S + B`` over ``m`` (b, n, heads, d_k, d_k) and ``b``, in
+    their dtype."""
+    from paddle_tpu.ops.pallas import kda_carry
+    if kda_carry.supported(m.shape[-1], b.shape[-1], b.dtype):
+        monitor.stat_add("kda_carry_kernel_total", 1)
+        return kda_carry.carry(m, b)
+
+    def step(s, now):
+        m_n, b_n = now
+        return (jnp.matmul(m_n, s, precision=_HIGHEST,
+                           preferred_element_type=s.dtype)
+                + b_n).astype(s.dtype), s
+
+    zero = jnp.zeros(b.shape[:1] + b.shape[2:], b.dtype)
+    _, entering = jax.lax.scan(step, zero, (jnp.moveaxis(m, 1, 0),
+                                            jnp.moveaxis(b, 1, 0)))
+    return jnp.moveaxis(entering, 0, 1)
+
+
 def kda_chunked(q, k, v, g, beta, chunk: int = 64):
     """The chunked delta rule of the module's text.  ``q``, ``k`` (batch,
     seq, heads, d_k), ``v`` (batch, seq, heads, d_v): q already scaled;
@@ -216,16 +244,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64):
         "...ci,...cj->...ij", to_end, w, precision=_HIGHEST)
     b = jnp.einsum("...ci,...cj->...ij", to_end, u, precision=_HIGHEST)
 
-    def carry(s, step):
-        m_n, b_n = step
-        return (jnp.matmul(m_n.astype(state), s, precision=_HIGHEST,
-                           preferred_element_type=state)
-                + b_n.astype(state)).astype(state), s
-
-    zero = jnp.zeros((bsz, heads, dk, dv), state)
-    _, entering = jax.lax.scan(carry, zero, (jnp.moveaxis(m, 1, 0),
-                                             jnp.moveaxis(b, 1, 0)))
-    entering = jnp.moveaxis(entering, 0, 1).astype(f32)       # (b,n,h,dk,dv)
+    entering = _carry(m.astype(state), b.astype(state)).astype(f32)
     delta = u - jnp.matmul(w, entering, precision=_HIGHEST)
     o = jnp.matmul(qc * decay, entering, precision=_HIGHEST) \
         + jnp.matmul(aqk, delta, precision=_HIGHEST)

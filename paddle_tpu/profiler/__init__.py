@@ -57,7 +57,9 @@ Names in a device trace (``start_profiler`` or a bare
   backward, one per ``E`` block of a stack traced under ``remat``;
   ``models/nemotron_h.py``, ``models/bailing_hybrid.py``),
   ``ssm_chunks_traced_total`` (``nn/functional/ssm.py``),
-  ``kda_chunks_traced_total`` (``nn/functional/kda.py``).
+  ``kda_chunks_traced_total`` and ``kda_carry_kernel_total`` (calls
+  whose state crossed the chunks in ``ops/pallas/kda_carry.py``;
+  ``nn/functional/kda.py``).
 """
 from __future__ import annotations
 

@@ -26,6 +26,7 @@ from paddle_tpu.models import bailing_hybrid as bh
 from paddle_tpu.nn.functional import kda, moe, rotary
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import grouped_matmul as gmm
+from paddle_tpu.ops.pallas import kda_carry as kc
 from paddle_tpu.parallel import get_mesh, make_mesh, set_mesh
 
 SIZE_KEYS = ("num_hidden_layers", "layer_group_size", "first_k_dense_replace",
@@ -88,20 +89,29 @@ def _recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
-@pytest.mark.parametrize("seq, chunk, gate", [
-    (64, 64, "at_the_bound"), (200, 64, "at_the_bound"),
-    (96, 32, "near_zero"), (160, 32, "mixed"), (128, 64, "keys_alike")],
+@pytest.mark.parametrize("seq, chunk, gate, width", [
+    (64, 64, "at_the_bound", 0), (200, 64, "at_the_bound", 0),
+    (96, 32, "near_zero", 0), (160, 32, "mixed", 0),
+    (128, 64, "keys_alike", 0), (200, 64, "at_the_bound", 128),
+    (320, 64, "keys_alike", 128)],
     ids=["one_chunk", "four_chunks_padded", "three_chunks_near_zero",
-         "five_chunks_mixed", "two_chunks_keys_alike"])
-def test_chunked_delta_rule_equals_the_token_recurrence(seq, chunk, gate):
+         "five_chunks_mixed", "two_chunks_keys_alike",
+         "four_chunks_padded_carry_kernel",
+         "five_chunks_keys_alike_carry_kernel"])
+def test_chunked_delta_rule_equals_the_token_recurrence(seq, chunk, gate,
+                                                        width, monkeypatch):
     """Output and the gradient of every input.  At the bound a chunk of
     64 decays by e^-320 from its first position to its last, which only
     the sub-chunks keep inside float32.  ``keys_alike``: keys of positive
     entries, as after the convolution's SiLU, whose dot products near 1
     make the within-chunk inverse's Neumann powers grow as binomial
-    coefficients (a product of powers returned garbage there)."""
+    coefficients (a product of powers returned garbage there).  The carry
+    kernels (interpret mode) take lane-wide heads (``width`` 128: then
+    also against the ``lax.scan`` path); the narrow ones stay on the
+    scan."""
+    monkeypatch.setattr(kc, "_INTERPRET", True)
     rng = np.random.default_rng(seq + chunk)
-    b, h, dk, dv = 2, 3, 16, 8
+    b, h, dk, dv = (2, 2, width, width) if width else (2, 3, 16, 8)
     sign = np.abs if gate == "keys_alike" else (lambda x: x)
     q, k = (kda.l2_norm(jnp.asarray(sign(rng.standard_normal(
         (b, seq, h, dk))), jnp.float32)) for _ in range(2))
@@ -120,16 +130,80 @@ def test_chunked_delta_rule_equals_the_token_recurrence(seq, chunk, gate):
                 lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2, 3, 4)
             ))(q, k, v, g, beta)
 
+    def chunked(*a):
+        return kda.kda_chunked(*a, chunk=chunk)
+
     monitor.reset_all_stats()
-    got, got_grads = value_and_grads(
-        lambda *a: kda.kda_chunked(*a, chunk=chunk))
+    got, got_grads = value_and_grads(chunked)
     assert monitor.get_stat("kda_chunks_traced_total") \
         == b * h * -(-seq // chunk)
-    want, want_grads = value_and_grads(_recurrence)
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
-    for name, a, w in zip("q k v g beta".split(), got_grads, want_grads):
-        assert np.isfinite(np.asarray(a)).all(), name
-        assert_close(a, w, tol=5e-5, what=name)
+    assert monitor.get_stat("kda_carry_kernel_total") == (1 if width else 0)
+    wants = [value_and_grads(_recurrence)]
+    if width:
+        monkeypatch.setattr(kc, "_INTERPRET", False)
+        monitor.reset_all_stats()
+        wants.append(value_and_grads(chunked))
+        assert monitor.get_stat("kda_carry_kernel_total") == 0
+    for want, want_grads in wants:
+        assert float(got) == pytest.approx(float(want), rel=1e-5)
+        for name, a, w in zip("q k v g beta".split(), got_grads, want_grads):
+            assert np.isfinite(np.asarray(a)).all(), name
+            assert_close(a, w, tol=5e-5, what=name)
+
+
+def _scan_carry(m, b):
+    """``entering_n = S_n``, ``S_{n+1} = M_n S_n + B_n`` from ``S_0 = 0``,
+    by ``lax.scan`` over the chunk axis."""
+    def step(s, now):
+        return jnp.matmul(now[0], s, precision="highest") + now[1], s
+
+    _, entering = jax.lax.scan(
+        step, jnp.zeros(b.shape[:1] + b.shape[2:], b.dtype),
+        (jnp.moveaxis(m, 1, 0), jnp.moveaxis(b, 1, 0)))
+    return jnp.moveaxis(entering, 0, 1)
+
+
+@pytest.mark.parametrize("heads, block_bytes", [(2, None), (3, 1)],
+                         ids=["heads_in_one_block", "a_head_a_block"])
+@pytest.mark.parametrize("cotangent",
+                         ["every_chunk", "last_chunk_only", "first_chunk_only"])
+def test_carry_kernel_and_its_vjp_equal_the_scan(heads, block_bytes,
+                                                 cotangent, monkeypatch):
+    """``kda_carry.carry`` (interpret mode) and its hand-written backward
+    against ``lax.scan`` and jax's transpose of it, on random ``M``, ``B``
+    and ``E``.  ``last_chunk_only``: ``E`` is zero but for the last
+    chunk, read first by the reverse walk; its cotangent reaches ``dB`` of
+    the first chunk only through every ``M`` in between.
+    ``first_chunk_only``: ``E`` is zero but for the first chunk, read
+    last, on the state entering it, which is zero whatever ``M`` and ``B``
+    are: every gradient is zero."""
+    monkeypatch.setattr(kc, "_INTERPRET", True)
+    if block_bytes:
+        monkeypatch.setattr(kc, "_BLOCK_BYTES", block_bytes)
+    assert kc._heads_per_block(heads, 128, 128, 4) \
+        == (1 if block_bytes else heads)
+    rng = np.random.default_rng(heads)
+    bsz, n, dk, dv = 2, 5, 128, 128
+    m = jnp.eye(dk, dtype=jnp.float32) * 0.9 \
+        + _normal(rng, (bsz, n, heads, dk, dk), 0.02)
+    b = _normal(rng, (bsz, n, heads, dk, dv))
+    e = _normal(rng, (bsz, n, heads, dk, dv))
+    if cotangent == "last_chunk_only":
+        e = e.at[:, :-1].set(0.0)
+    if cotangent == "first_chunk_only":
+        e = e.at[:, 1:].set(0.0)
+    got, got_vjp = jax.vjp(kc.carry, m, b)
+    want, want_vjp = jax.vjp(_scan_carry, m, b)
+    (dm, db), (want_dm, want_db) = got_vjp(e), want_vjp(e)
+    assert not np.asarray(got[:, 0]).any()
+    if cotangent == "first_chunk_only":
+        assert not np.asarray(dm).any() and not np.asarray(db).any()
+        assert_close(got, want, tol=1e-6, what="entering")
+        return
+    assert np.abs(np.asarray(want_db[:, 0])).max() > 0.1
+    for name, a, w in (("entering", got, want), ("dM", dm, want_dm),
+                       ("dB", db, want_db)):
+        assert_close(a, w, tol=1e-6, what=name)
 
 
 def test_the_gate_keeps_each_step_within_its_bound():
@@ -175,16 +249,18 @@ def test_model_under_a_bias_agrees_with_the_reference(path, monkeypatch,
     """Loss and every parameter's gradient against ``loss_and_grads`` with
     the buffer filled as the harness fills it: a bias that decides
     (expert 1, held, for every token; expert 6, absent, for none).
-    ``kernels``: the routed experts over the sorted rows and the MLA core
-    on the flash kernels, both in interpret mode."""
+    ``kernels``: the routed experts over the sorted rows, the MLA core on
+    the flash kernels and KDA's state on the carry kernels, all in
+    interpret mode."""
     kernels = path == "kernels"
     monkeypatch.setattr(gmm, "_INTERPRET", kernels)
     monkeypatch.setattr(fa, "_INTERPRET", kernels)
+    monkeypatch.setattr(kc, "_INTERPRET", kernels)
     # lane-wide experts for the sorted rows, MLA at the published widths
-    # for the flash kernels
+    # for the flash kernels, KDA heads at the published 128
     wide = dict(hidden_size=128, moe_intermediate_size=128,
                 qk_nope_head_dim=128, qk_rope_head_dim=64,
-                v_head_dim=128) if kernels else {}
+                v_head_dim=128, head_dim=128) if kernels else {}
     c = bailing_hybrid_tiny(remat=kernels, seed=5, **wide)
     model = BailingHybrid(c)
     _drawn(model, 9)
@@ -203,6 +279,10 @@ def test_model_under_a_bias_agrees_with_the_reference(path, monkeypatch,
     rows = stats["moe_expert_rows_computed_total"] \
         / stats["moe_calls_traced_total"]
     assert rows == c.experts_held * (gmm.TILE_ROWS if kernels else 2 * seq)
+    kda_layers = sum(c.kinds(i)[0] == "kda"
+                     for i in range(c.num_hidden_layers))
+    assert stats.get("kda_carry_kernel_total", 0) \
+        == (kda_layers if kernels else 0)
     under_bias = {**params, "e_router_bias": jnp.asarray(bias)}
     sizes = sizes_of(c)
     want, want_grads = ref.loss_and_grads(under_bias, (ids, ids), sizes)
@@ -456,3 +536,6 @@ def test_train_step_amp_o2_trains_and_names_its_scopes():
     assert all(f"``{name}``" in doc for names in INNER_SCOPES.values()
                for name in names)
     assert "``kda``" in doc and "``kda_chunks_traced_total``" in doc
+    assert "``kda_carry_kernel_total``" in doc
+    # the narrow heads of the tiny config stay on the scan
+    assert stats.get("kda_carry_kernel_total", 0) == 0

@@ -46,6 +46,7 @@ MAIN_PATH = {
     "paddle_tpu/nn/functional/rotary.py": LEAF,
     "paddle_tpu/ops/pallas/flash_attention.py": LEAF,
     "paddle_tpu/ops/pallas/grouped_matmul.py": LEAF,
+    "paddle_tpu/ops/pallas/kda_carry.py": LEAF,
     "paddle_tpu/ops/pallas/common.py": LEAF,
 }
 

@@ -41,10 +41,16 @@ the module's own reference.  What it runs, with what the repo already has:
   ``swiglu`` prologue, the ``dswiglu`` epilogue) under an even load of
   about 128 rows an expert and a skewed one (1100 rows down to none),
   against the dense mask in float32 at the highest precision: y, dx,
-  dW13, dW2 and the gates' gradient.
+  dW13, dW2 and the gates' gradient;
+* ``kda_carry`` at the Ling cell's shape (1 sequence, 128 chunks, 4 heads,
+  a float32 state of 128 x 128): the carry and its backward against a
+  ``lax.scan`` at the highest precision and jax's transpose of it, each
+  output's worst difference relative to its largest value, and the device
+  time of each of the four programs (a profiler trace of a few calls: the
+  ``XLA Modules`` events on the chip, their mean).
 
 ``python tools/kernel_check.py grouped`` runs the rows of one family
-(``flash``, ``tail``, ``other``, ``grouped``) alone.
+(``flash``, ``tail``, ``other``, ``grouped``, ``kda_carry``) alone.
 
 Exits non-zero when a kernel does not compile or leaves its tolerance.
 Needs the TPU: one process, no children.
@@ -73,6 +79,10 @@ GROUPED_LOAD = (838, 400, 200, 100, 50, 20, 5, 0)
 SWIGLU_SHAPE = (8192, 8, 2560, 768)      # tokens, experts, hidden, inner
 SWIGLU_TOP_K = 8
 SWIGLU_SKEWED = (1100, 400, 200, 100, 50, 20, 5, 0)
+KDA_CARRY_SHAPE = (1, 128, 4, 128, 128)   # batch, chunks, heads, d_k, d_v
+# the two float32 carries, the kernel's and XLA's, each at the highest
+# precision; both products are float32 to the last places
+KDA_CARRY_TOL = 2e-5
 
 
 def check(name, run_kernel, run_reference, labels, tol=None, run_also=None):
@@ -411,6 +421,83 @@ def swiglu_rows():
               ("y", "dx", "dw13", "dw2", "dgates"))
 
 
+def device_ms(fn, *args, reps: int = 5) -> float:
+    """Mean device time of one call of the compiled ``fn``, in ms: the
+    ``XLA Modules`` events on the first chip of a profiler trace of
+    ``reps`` calls after a warm one."""
+    import glob
+    import shutil
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(fn(*args))
+    where = os.path.join(REPO, "chiprun_out", "kernel_check_trace")
+    shutil.rmtree(where, ignore_errors=True)
+    jax.profiler.start_trace(where)
+    try:
+        for _ in range(reps):
+            jax.block_until_ready(fn(*args))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(where, "**", "*.xplane.pb"),
+                      recursive=True)
+    total = sum(e.duration_ns for plane in ProfileData.from_file(path).planes
+                if plane.name == "/device:TPU:0" for line in plane.lines
+                if line.name == "XLA Modules" for e in line.events)
+    shutil.rmtree(where, ignore_errors=True)
+    return total * 1e-6 / reps
+
+
+def kda_carry_rows():
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import kda_carry
+
+    bsz, n, heads, dk, dv = KDA_CARRY_SHAPE
+    rng = np.random.default_rng(2)
+    f32 = jnp.float32
+    # a decay near 1 and a spectral radius under 1, as the rule's M has:
+    # the state stays bounded over the 128 chunks
+    m = jnp.asarray(np.eye(dk) * 0.9 + rng.standard_normal(
+        (bsz, n, heads, dk, dk)) * (0.05 / np.sqrt(dk)), f32)
+    b, e = (jnp.asarray(rng.standard_normal(KDA_CARRY_SHAPE), f32)
+            for _ in range(2))
+
+    def scan(m, b):
+        def step(s, now):
+            return jnp.matmul(now[0], s, precision=jax.lax.Precision.HIGHEST
+                              ) + now[1], s
+        _, entering = jax.lax.scan(
+            step, jnp.zeros((bsz, heads, dk, dv), f32),
+            (jnp.moveaxis(m, 1, 0), jnp.moveaxis(b, 1, 0)))
+        return jnp.moveaxis(entering, 0, 1)
+
+    forward = {"kernel": jax.jit(kda_carry.carry), "scan": jax.jit(scan)}
+    backward = jax.jit(lambda pullback, e: pullback(e))
+    pullbacks = {name: jax.vjp(fn, m, b)[1] for name, fn in forward.items()}
+
+    def run(name):
+        return (forward[name](m, b), *backward(pullbacks[name], e))
+
+    check(f"kda_carry {KDA_CARRY_SHAPE} float32 fwd + dM + dB",
+          lambda: run("kernel"), lambda: run("scan"),
+          ("entering", "dM", "dB"), tol=KDA_CARRY_TOL)
+    row = ROWS[-1]
+    if "max_abs_err" not in row:
+        return
+    row["rel_err"] = {label: float(f"{err / row['ref_max_abs'][label]:.3g}")
+                      for label, err in row["max_abs_err"].items()}
+    row["device_ms"] = {
+        f"{name}.{way}": round(device_ms(*call), 4)
+        for name in forward
+        for way, call in (("fwd", (forward[name], m, b)),
+                          ("bwd", (backward, pullbacks[name], e)))}
+    print(json.dumps({"kernel": row["kernel"], "rel_err": row["rel_err"],
+                      "device_ms": row["device_ms"]}), flush=True)
+
+
 def main() -> int:
     import jax
 
@@ -424,7 +511,7 @@ def main() -> int:
     print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}  "
           f"jax {jax.__version__}", flush=True)
     families = {"flash": flash_rows, "tail": tail_rows, "other": other_rows,
-                "grouped": grouped_rows}
+                "grouped": grouped_rows, "kda_carry": kda_carry_rows}
     for name in sys.argv[1:] or families:
         families[name]()
     bad = [r["kernel"] for r in ROWS
