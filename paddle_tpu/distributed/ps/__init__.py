@@ -498,6 +498,7 @@ class PSTrainStep:
             if prefetch_depth is None else int(prefetch_depth)
         self._opt_states = None
         self._cache: Dict[tuple, object] = {}
+        self._last_call_start = None
         # -- prefetch pipeline state (single training thread drives it;
         # only the executor tasks run concurrently, and they touch only
         # thread-safe table/client objects + local arrays)
@@ -812,10 +813,13 @@ class PSTrainStep:
                                        "_global_step", 0))})
         with step_span:
             loss = self._call_inner(ids, step_span, *inputs)
-        step_ms = (_time.perf_counter() - t_start) * 1e3
-        monitor.observe("train_step_ms", step_ms)
+        # a step: from one call's start to the next's (jit.TrainStep)
+        before, self._last_call_start = self._last_call_start, t_start
+        if before is not None:
+            step_ms = (t_start - before) * 1e3
+            monitor.observe("train_step_ms", step_ms)
+            health.observe("train_step_ms", step_ms)
         monitor.stat_add("train_steps_total")
-        health.observe("train_step_ms", step_ms)
         return loss
 
     def _call_inner(self, ids, step_span, *inputs):

@@ -16,15 +16,31 @@ one design center (deterministic, clock-injectable, cheap when off):
   ``static_arg_change``), bumps ``jit_compiles_total`` (+ a per-cause
   counter; a program jax compiles under a signature the site's own cache
   HIT, its retrace for an input's new sharding, is counted too, cause
-  ``jax_retrace``, from ``jax.monitoring``'s backend-compile event),
-  records ``compile_ms`` (first-dispatch latency:
-  trace+compile+run — the honest proxy without AOT lowering), and
-  counts ``jit_recompiles_steady_total`` when a site that already
-  compiled recompiles past its warmup calls.  ≥K post-warmup compiles
-  at one site is a **compile storm**: a ``health.compile_storm``
-  flight-recorder event fires so the post-mortem shows it next to the
-  step-time anomalies it caused.  Cache hits land in
-  ``jit_cache_hits_total``.
+  ``jax_retrace`` — scoped to the dispatch alone, ``TrainStep.launch``),
+  records ``compile_ms`` (trace + lower + backend of that compile, as
+  jax reports them), and counts ``jit_recompiles_steady_total`` when a
+  site that already compiled recompiles past its warmup calls.  ≥K
+  post-warmup compiles at one site is a **compile storm**: a
+  ``health.compile_storm`` flight-recorder event fires so the
+  post-mortem shows it next to the step-time anomalies it caused.
+  Cache hits land in ``jit_cache_hits_total``.
+
+  One listener takes every compile event jax emits: the three phases as
+  time spans (jaxpr trace, lowering to StableHLO, backend compile) and
+  the persistent cache's hits and misses.  Each phase is booked to the
+  step call open on its thread (:func:`step_call`: a ``TrainStep`` call
+  from the start of ``prepare`` to the end of ``commit``, so the first
+  call's optimizer-state init and eager compiles count too), per site in
+  :func:`compile_report` (``traces``, ``trace_s``, ``lower_s``,
+  ``backend_s``, ``cold_compile_s``) and process-wide in
+  ``jit_traces_total`` and
+  ``jit_{trace,lower,backend,cold_compile}_seconds_total``.
+  A nested interval counts once, for the innermost phase, so the
+  phases' seconds never overlap; a backend compile is cold
+  unless the persistent cache reported a hit inside it.  Compiles
+  outside any step call are booked to no step site.  While the profiler
+  is on, each phase is also a ``jit.trace`` / ``jit.lower`` /
+  ``jit.backend_compile`` row on the profiler's clock.
 
 * **Device-memory observability** — :class:`MemoryTracker` samples
   ``jax.live_arrays()`` into ``device_mem_live_bytes`` /
@@ -82,8 +98,8 @@ from paddle_tpu.framework.observability import flight, tracer
 __all__ = ["Anomaly", "Detector", "HealthMonitor", "MemoryTracker",
            "memory", "watch", "observe", "enabled", "snapshot", "reset",
            "classify_recompile", "note_compile", "note_cache_hit",
-           "compile_report", "maybe_sample_memory", "DEFAULT_SIGNALS",
-           "RECOMPILE_CAUSES"]
+           "compile_report", "step_call", "maybe_sample_memory",
+           "DEFAULT_SIGNALS", "RECOMPILE_CAUSES"]
 
 
 # ---------------------------------------------------------------------------
@@ -285,7 +301,8 @@ class Detector:
 #: the built-in signal set FLAGS_health_detectors="default" arms —
 #: exactly the streams the train/transport/ingest tiers feed
 DEFAULT_SIGNALS: Dict[str, dict] = {
-    # per-step wall time (TrainStep / PSTrainStep __call__).  The wide
+    # step time, one call's start to the next's on the same step object
+    # (TrainStep / PSTrainStep / ShardedUpdateTrainStep).  The wide
     # relative floor absorbs host-side dispatch jitter on real (tens
     # of ms+) steps; the absolute ms floor keeps sub-ms CPU baselines
     # from flagging scheduler noise — only a multiple-of-baseline /
@@ -551,7 +568,8 @@ def classify_recompile(sig, cached_sigs) -> str:
 
 class _CompileSite:
     __slots__ = ("name", "calls", "compiles", "steady_recompiles",
-                 "causes", "last_cause", "compile_ms_total")
+                 "causes", "last_cause", "compile_ms_total", "traces",
+                 "trace_s", "lower_s", "backend_s", "cold_compile_s")
 
     def __init__(self, name: str):
         self.name = name
@@ -561,6 +579,12 @@ class _CompileSite:
         self.causes: Dict[str, int] = {}
         self.last_cause: Optional[str] = None
         self.compile_ms_total = 0.0
+        # the phases of every compile booked to the site's step calls
+        self.traces = 0
+        self.trace_s = 0.0
+        self.lower_s = 0.0
+        self.backend_s = 0.0
+        self.cold_compile_s = 0.0
 
 
 _sites: Dict[str, _CompileSite] = {}
@@ -585,8 +609,7 @@ def note_cache_hit(site: str):
 def note_compile(site: str, cause: str, compile_ms: float):
     """A signature-cache miss at ``site``: count the compile under its
     ``cause``, record ``compile_ms``, and run the storm/steady-state
-    bookkeeping.  Call sites time the first dispatch of the fresh
-    executable (trace + XLA compile + run) and classify the cause with
+    bookkeeping.  Call sites classify the cause with
     :func:`classify_recompile` BEFORE inserting the new signature."""
     s = _site(site)
     s.calls += 1
@@ -620,53 +643,190 @@ def _count_compile(s: _CompileSite, cause: str, compile_ms: float):
 
 def compile_report() -> Dict[str, dict]:
     """Per-site compile bookkeeping (JSON-able): calls, compiles,
-    steady-state recompiles, per-cause counts, total compile ms."""
+    steady-state recompiles, per-cause counts, total compile ms, and the
+    phases booked to the site's step calls (:func:`step_call`):
+    ``traces`` (jaxpr traces, nested ones included), the seconds of
+    ``trace_s``, ``lower_s`` and ``backend_s`` (persistent-cache reads
+    included), which never overlap (:class:`_Union`), and
+    ``cold_compile_s`` (the part of ``backend_s`` no persistent-cache hit
+    served)."""
     with _sites_lock:
         sites = list(_sites.values())
     return {s.name: {"calls": s.calls, "compiles": s.compiles,
                      "steady_recompiles": s.steady_recompiles,
                      "causes": dict(s.causes),
                      "last_cause": s.last_cause,
-                     "compile_ms_total": round(s.compile_ms_total, 3)}
+                     "compile_ms_total": round(s.compile_ms_total, 3),
+                     "traces": s.traces, "trace_s": round(s.trace_s, 6),
+                     "lower_s": round(s.lower_s, 6),
+                     "backend_s": round(s.backend_s, 6),
+                     "cold_compile_s": round(s.cold_compile_s, 6)}
             for s in sites}
 
 
-class _TimedCompile:
-    """Context manager the jit tiers wrap a dispatch in.  On a
-    signature-cache miss (``cause`` set): a ``jit.compile`` tracer span
-    carrying site + cause, timed into :func:`note_compile` on exit.  On
-    a hit nothing is timed, but while it is open the site owns whatever
-    jax hands to the backend compiler (:func:`_on_backend_compile`)."""
+# what jax emits while it compiles, on the compiling thread: three phases
+# as time spans (``time.time()`` stamps, a ``fun_name`` each) and, inside
+# a backend compile's span, whether the persistent cache served it
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_PHASES = {TRACE_EVENT: "trace", LOWER_EVENT: "lower",
+           BACKEND_COMPILE_EVENT: "backend"}
+_PROFILER_ROWS = {"trace": "jit.trace", "lower": "jit.lower",
+                  "backend": "jit.backend_compile"}
 
-    __slots__ = ("site", "cause", "_t0", "_span", "_outer")
+monitor.describe("jit_traces_total",
+                 "jaxpr traces booked to step calls, nested ones included")
+monitor.describe("jit_trace_seconds_total",
+                 "seconds of jaxpr tracing booked to step calls")
+monitor.describe("jit_lower_seconds_total",
+                 "seconds of lowering to StableHLO booked to step calls")
+monitor.describe("jit_backend_seconds_total",
+                 "seconds of backend compiles booked to step calls, "
+                 "persistent-cache reads included")
+monitor.describe("jit_cold_compile_seconds_total",
+                 "seconds of backend compiles booked to step calls that "
+                 "the persistent cache did not serve")
+monitor.describe("compile_ms", "trace + lower + backend ms of one compile "
+                 "at a jit site (a signature-cache miss or jax's retrace)")
+
+
+class _Union:
+    """The compile phases booked to one open scope (a step call, a
+    launch), as the seconds their intervals cover together: ``add``
+    returns what a new interval covers that no earlier one did.  So a
+    nested interval counts once, for the phase that ended first, the
+    innermost (a jit's trace inside its caller's, an eager op's compile
+    inside a trace), and the phases' seconds never overlap.  jax reports
+    a phase when it ends, so on one thread a new interval ends after
+    every earlier one and can only overlap the last few."""
+
+    __slots__ = ("spans", "seconds")
+
+    def __init__(self):
+        self.spans: List[tuple] = []
+        self.seconds = 0.0
+
+    def add(self, a: float, b: float) -> float:
+        covered = 0.0
+        while self.spans and self.spans[-1][1] >= a \
+                and self.spans[-1][0] <= b:
+            x, y = self.spans.pop()
+            covered += y - x
+            a, b = min(a, x), max(b, y)
+        self.spans.append((a, b))
+        new = (b - a) - covered
+        self.seconds += new
+        return new
+
+
+class _StepCall:
+    """One call of a step, from the start of its ``prepare`` to the end
+    of its ``commit``: every compile phase jax reports on this thread
+    meanwhile is booked to ``site`` — the jitted step's, and the eager
+    programs of the first call (optimizer-state init, the RNG split)."""
+
+    __slots__ = ("site", "_union", "_outer")
+
+    def __init__(self, site: str):
+        self.site = site
+        self._union = None
+        self._outer = None
+
+    def __enter__(self):
+        _listen()
+        self._outer = getattr(_open, "call", None)
+        _open.call = self
+        return self
+
+    def __exit__(self, *exc):
+        _open.call = self._outer
+        return False
+
+    def book(self, phase: str, a: float, b: float, cold: bool):
+        if self._union is None:
+            self._union = _Union()
+        new = self._union.add(a, b)
+        s = _site(self.site)
+        if phase == "trace":
+            s.traces += 1
+            s.trace_s += new
+            monitor.stat_add("jit_traces_total")
+            monitor.stat_add("jit_trace_seconds_total", new)
+        elif phase == "lower":
+            s.lower_s += new
+            monitor.stat_add("jit_lower_seconds_total", new)
+        else:
+            s.backend_s += new
+            monitor.stat_add("jit_backend_seconds_total", new)
+            if cold:
+                s.cold_compile_s += new
+                monitor.stat_add("jit_cold_compile_seconds_total", new)
+
+
+def step_call(site: str):
+    """See :class:`_StepCall` — ``TrainStep.__call__`` and
+    ``TrainStep.multi_step`` wrap each call in one.  Compiles outside any
+    step call (a reference, a solve, an eager op between steps) are
+    booked to no step site."""
+    return _StepCall(site)
+
+
+class _TimedCompile:
+    """Context manager the jit tiers wrap a dispatch in (a step's
+    ``launch``).  On a signature-cache miss (``cause`` set): a
+    ``jit.compile`` tracer span carrying site + cause, and on exit
+    :func:`note_compile` with the trace + lower + backend ms jax reported
+    meanwhile.  On a hit nothing is counted, but while it is open the site
+    owns whatever jax hands to the backend compiler (:func:`_on_phase`):
+    jax's own retrace, cause ``jax_retrace``."""
+
+    __slots__ = ("site", "cause", "_span", "_outer", "_union", "_since")
 
     def __init__(self, site: str, cause: Optional[str]):
         self.site = site
         self.cause = cause
         self._span = None
-        self._t0 = 0.0
         self._outer = None
+        self._union = None
+        self._since = 0.0      # trace + lower seconds since the last backend
 
     def __enter__(self):
-        _listen_for_backend_compiles()
-        self._outer = getattr(_dispatching, "site", None)
-        _dispatching.site = self
+        _listen()
+        self._outer = getattr(_open, "launch", None)
+        _open.launch = self
         if self.cause is not None:
             self._span = tracer.start_span(
                 "jit.compile",
                 attrs={"site": self.site, "cause": self.cause})
             self._span.__enter__()
-        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        ms = (time.perf_counter() - self._t0) * 1e3
-        _dispatching.site = self._outer
+        _open.launch = self._outer
         if self._span is not None:
             self._span.__exit__(exc_type, exc, tb)
             if exc_type is None:
-                note_compile(self.site, self.cause, ms)
+                note_compile(self.site, self.cause, 0.0 if self._union
+                             is None else self._union.seconds * 1e3)
         return False
+
+    def book(self, phase: str, a: float, b: float):
+        if self._union is None:
+            self._union = _Union()
+        new = self._union.add(a, b)
+        if phase != "backend":
+            self._since += new
+        elif self.cause is None:
+            # under a hit of our signature cache this is jax's own retrace
+            # (an input's sharding changed: the second call of every
+            # process, whose parameters carry the first call's output
+            # sharding), which no signature of ours can see
+            _count_compile(_site(self.site), "jax_retrace",
+                           (self._since + new) * 1e3)
+            self._since = 0.0
 
 
 def timed_compile(site: str, cause: Optional[str]):
@@ -677,34 +837,56 @@ def timed_compile(site: str, cause: Optional[str]):
     return _TimedCompile(site, cause)
 
 
-_dispatching = threading.local()    # .site: the innermost open dispatch
+# .call: the innermost open step call; .launch: the innermost open
+# dispatch; .cache_hit: the persistent cache served the backend compile
+# now in progress
+_open = threading.local()
 _listening = threading.Event()
 
 
-def _listen_for_backend_compiles():
-    """Register :func:`_on_backend_compile` with jax, once a process."""
+def _listen():
+    """Register :func:`_on_phase` and :func:`_on_cache_event` with jax,
+    once a process: the program's one compile listener."""
     if not _listening.is_set():
         with _sites_lock:
             if not _listening.is_set():
                 import jax
-                jax.monitoring.register_event_duration_secs_listener(
-                    _on_backend_compile)
+                jax.monitoring.register_event_time_span_listener(_on_phase)
+                jax.monitoring.register_event_listener(_on_cache_event)
                 _listening.set()
 
 
-def _on_backend_compile(event: str, duration_secs: float, **_):
-    """jax compiled a program (it calls back on the compiling thread).
-    Under a dispatch whose signature cache MISSED this is the compile
-    :class:`_TimedCompile` counts on exit, once; under a hit it is jax's
-    own retrace (an input's sharding changed: the second call of every
-    process, whose parameters carry the first call's output sharding),
-    which no signature of ours can see: count it for the open site."""
-    if event != BACKEND_COMPILE_EVENT:
+def _on_cache_event(event: str, **_):
+    if event == CACHE_HIT_EVENT:
+        _open.cache_hit = True
+    elif event == CACHE_MISS_EVENT:
+        _open.cache_hit = False
+
+
+def _on_phase(event: str, start: float, end: float, **kwargs):
+    """jax finished one phase of a compile on this thread: book it to the
+    open step call and the open launch, and, while the profiler is on,
+    give it a row on the profiler's clock."""
+    phase = _PHASES.get(event)
+    if phase is None:
         return
-    open_ = getattr(_dispatching, "site", None)
-    if open_ is not None and open_.cause is None:
-        _count_compile(_site(open_.site), "jax_retrace",
-                       duration_secs * 1e3)
+    cold = False
+    if phase == "backend":
+        # the hit or miss jax reported inside this compile's span
+        cold = not getattr(_open, "cache_hit", False)
+        _open.cache_hit = False
+    call = getattr(_open, "call", None)
+    if call is not None:
+        call.book(phase, start, end, cold)
+    launch = getattr(_open, "launch", None)
+    if launch is not None:
+        launch.book(phase, start, end)
+    from paddle_tpu import profiler
+    if profiler.is_profiling():
+        shift = time.perf_counter() - time.time()
+        profiler.record_span(_PROFILER_ROWS[phase], start + shift,
+                             end + shift,
+                             fun_name=str(kwargs.get("fun_name", "")))
 
 
 # ---------------------------------------------------------------------------

@@ -403,7 +403,8 @@ def export_prometheus() -> str:
 
 # core train-loop metrics described where the registry lives; subsystem
 # metrics are described by their owning modules via describe()
-describe("train_step_ms", "per-step wall time (ms) histogram")
+describe("train_step_ms", "step time (ms) histogram: from one call's "
+         "start to the next call's start on the same step object")
 describe("train_steps_total", "train steps completed")
 describe("input_stall_pct",
          "share of step time spent waiting on input (gauge)")

@@ -340,6 +340,7 @@ class TrainStep:
         self.recompute = recompute
         self._cache: Dict[tuple, Callable] = {}
         self._opt_states: Optional[dict] = None
+        self._last_call_start: Optional[float] = None
 
     # -- pure step ----------------------------------------------------------
     def _build_one_step(self, numerics_aux: bool = False):
@@ -542,8 +543,11 @@ class TrainStep:
         """
         from paddle_tpu.framework import health, monitor
         from paddle_tpu.profiler import RecordEvent
+        # a K-step call is no step interval: train_step_ms starts again
+        self._last_call_start = None
         with RecordEvent("TrainStep.multi_step",
-                         step=int(self.optimizer._global_step)):
+                         step=int(self.optimizer._global_step)), \
+                health.step_call("TrainStep.multi_step"):
             with RecordEvent("TrainStep.prepare"):
                 self._resolve_layouts("multi", inputs)
                 named_params, named_buffers, params, buffers, arrs, key, \
@@ -581,7 +585,8 @@ class TrainStep:
         """One step.  On the profiler's clock it is one host span,
         ``TrainStep`` (``step=<n>``), around three that follow each other:
         ``TrainStep.prepare``, ``TrainStep.launch`` (the jitted call
-        alone) and ``TrainStep.commit``."""
+        alone) and ``TrainStep.commit``; every compile in them is booked
+        to the site ``TrainStep`` (``health.step_call``)."""
         import time as _time
 
         from paddle_tpu.framework import health, numerics
@@ -589,7 +594,8 @@ class TrainStep:
         from paddle_tpu.profiler import RecordEvent
         t_start = _time.perf_counter()
         step_no = int(self.optimizer._global_step)
-        with RecordEvent("TrainStep", step=step_no):
+        with RecordEvent("TrainStep", step=step_no), \
+                health.step_call("TrainStep"):
             with RecordEvent("TrainStep.prepare"):
                 self._resolve_layouts("step", inputs)
                 named_params, named_buffers, params, buffers, arrs, key, \
@@ -631,8 +637,6 @@ class TrainStep:
                       t_start):
         """``TrainStep.commit``: write the step's outputs back and run
         every per-step hook.  Returns the loss array."""
-        import time as _time
-
         from paddle_tpu.framework import health, monitor, numerics
         if armed:
             new_params, new_states, new_buffers, loss, aux = out
@@ -652,10 +656,14 @@ class TrainStep:
         self._commit_step(loss, "TrainStep", named_params, new_params,
                           named_buffers, new_buffers, new_states)
         self.optimizer._global_step += 1
-        step_ms = (_time.perf_counter() - t_start) * 1e3
-        monitor.observe("train_step_ms", step_ms)
+        # a step is the interval from one call's start to the next's:
+        # under one step in flight the call itself is only its dispatch
+        before, self._last_call_start = self._last_call_start, t_start
+        if before is not None:
+            step_ms = (t_start - before) * 1e3
+            monitor.observe("train_step_ms", step_ms)
+            health.observe("train_step_ms", step_ms)
         monitor.stat_add("train_steps_total")
-        health.observe("train_step_ms", step_ms)
         health.maybe_sample_memory(lambda: {
             "params": sum(int(p._data.nbytes)
                           for p in named_params.values()),

@@ -70,6 +70,7 @@ from paddle_tpu.nn.functional import moe as _moe
 from paddle_tpu.nn.functional import rotary as _rotary
 from paddle_tpu.nn.functional import ssm as _ssm
 from paddle_tpu.nn.layer.layers import Layer
+from paddle_tpu.profiler import CountedEvent
 
 __all__ = ["BailingHybridConfig", "BailingHybrid", "bailing_hybrid_loss",
            "bailing_hybrid_tiny"]
@@ -165,7 +166,13 @@ def bailing_hybrid_tiny(**kw):
 class BailingHybrid(Layer):
     def __init__(self, config: BailingHybridConfig):
         super().__init__()
-        self.config = c = config
+        self.config = config
+        with CountedEvent("model.init"):
+            self._init_parameters(config)
+
+    def _init_parameters(self, c: BailingHybridConfig):
+        """Every parameter and the router's bias, drawn on the host from
+        ``c.seed``."""
         rng = np.random.default_rng(c.seed)
         std = c.initializer_range
         out_std = std / math.sqrt(2 * c.num_hidden_layers)

@@ -22,6 +22,7 @@ import numpy as np
 from paddle_tpu.core import Parameter, Tensor, apply1
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.parallel.mesh import DistAttr, get_mesh
+from paddle_tpu.profiler import CountedEvent
 
 __all__ = ["BertConfig", "Bert", "bert_base", "bert_tiny",
            "bert_pretrain_loss", "Ernie", "ErnieConfig"]
@@ -76,7 +77,12 @@ _PARAM_ORDER = ("wte", "wpe", "wtt", "emb_ln_w", "emb_ln_b",
 class Bert(Layer):
     def __init__(self, config: BertConfig):
         super().__init__()
-        self.config = c = config
+        self.config = config
+        with CountedEvent("model.init"):
+            self._init_parameters(config)
+
+    def _init_parameters(self, c: BertConfig):
+        """Every parameter, drawn on the host from ``c.seed``."""
         rng = np.random.default_rng(c.seed)
         std = c.initializer_range
         L, H, F, V = c.num_layers, c.hidden_size, c.ffn_size, c.vocab_size

@@ -34,6 +34,7 @@ import numpy as np
 from paddle_tpu.core import Parameter, Tensor, apply1
 from paddle_tpu.nn.layer.layers import Layer
 from paddle_tpu.parallel.mesh import DistAttr, get_mesh
+from paddle_tpu.profiler import CountedEvent
 
 __all__ = ["GPTConfig", "GPT", "gpt_loss", "gpt_tiny", "gpt2_small",
            "gpt2_medium", "gpt2_345m"]
@@ -104,7 +105,11 @@ class GPT(Layer):
     def __init__(self, config: GPTConfig):
         super().__init__()
         self.config = config
-        c = config
+        with CountedEvent("model.init"):
+            self._init_parameters(config)
+
+    def _init_parameters(self, c: GPTConfig):
+        """Every parameter, drawn on the host from ``c.seed``."""
         rng = np.random.default_rng(c.seed)
         std = c.initializer_range
         L, H, F, V, S = (c.num_layers, c.hidden_size, c.ffn_size,

@@ -68,6 +68,7 @@ from paddle_tpu.models.gpt import _attention
 from paddle_tpu.nn.functional import moe as _moe
 from paddle_tpu.nn.functional import ssm as _ssm
 from paddle_tpu.nn.layer.layers import Layer
+from paddle_tpu.profiler import CountedEvent
 
 __all__ = ["NemotronHConfig", "NemotronH", "nemotron_h_loss",
            "nemotron_h_tiny", "routing_load"]
@@ -175,7 +176,13 @@ def nemotron_h_tiny(**kw):
 class NemotronH(Layer):
     def __init__(self, config: NemotronHConfig):
         super().__init__()
-        self.config = c = config
+        self.config = config
+        with CountedEvent("model.init"):
+            self._init_parameters(config)
+
+    def _init_parameters(self, c: NemotronHConfig):
+        """Every parameter and the router's bias, drawn on the host from
+        ``c.seed``."""
         rng = np.random.default_rng(c.seed)
         std = c.initializer_range
         out_std = std / math.sqrt(c.num_layers)

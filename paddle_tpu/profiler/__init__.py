@@ -25,8 +25,13 @@ Names in a device trace (``start_profiler`` or a bare
   cache lookup), ``TrainStep.launch`` (the jitted call alone: trace +
   compile on a miss, enqueue on a hit) and ``TrainStep.commit`` (state
   write-back and every per-step hook); ``TrainStep.multi_step`` with the
-  same three children; ``Predictor.run``; and, while paddle's profiler is
-  on, one row per tracer span (``ps.*``, ``ingest.*``, ``jit.compile``).
+  same three children; ``model.init`` (the parameters' draw in the
+  constructor of ``models.GPT``, ``Bert``, ``NemotronH`` and
+  ``BailingHybrid``); ``Predictor.run``; and, while paddle's profiler is
+  on, one row per tracer span (``ps.*``, ``ingest.*``, ``jit.compile``)
+  and one per phase of every compile jax runs (``jit.trace``,
+  ``jit.lower``, ``jit.backend_compile``, each with its ``fun_name``;
+  ``framework.health`` books them, on this clock).
 * device, in each operation's ``op_name`` path (``jax.named_scope``):
   ``embed``, ``attn`` (inner ``ln``, ``qkv``, ``core``, ``out``), ``mlp``
   (inner ``ln``, ``up``, ``down``) and ``head_loss`` from ``models/gpt.py``
@@ -60,6 +65,14 @@ Names in a device trace (``start_profiler`` or a bare
   ``kda_chunks_traced_total`` and ``kda_carry_kernel_total`` (calls
   whose state crossed the chunks in ``ops/pallas/kda_carry.py``;
   ``nn/functional/kda.py``).
+* set-up counters, always on: ``model_init_seconds_total`` (the seconds
+  of every ``model.init`` span) and, for the compiles booked to step calls
+  (``framework.health``), ``jit_traces_total``,
+  ``jit_trace_seconds_total``, ``jit_lower_seconds_total``,
+  ``jit_backend_seconds_total`` and ``jit_cold_compile_seconds_total``;
+  ``health.compile_report()`` gives the same numbers per site as
+  ``traces``, ``trace_s``, ``lower_s``, ``backend_s`` and
+  ``cold_compile_s``.
 """
 from __future__ import annotations
 
@@ -72,9 +85,9 @@ from typing import Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["RecordEvent", "record_event", "start_profiler", "stop_profiler",
-           "reset_profiler", "profiler", "export_chrome_tracing",
-           "is_profiling"]
+__all__ = ["RecordEvent", "CountedEvent", "record_event", "record_span",
+           "start_profiler", "stop_profiler", "reset_profiler", "profiler",
+           "export_chrome_tracing", "is_profiling"]
 
 _state = {
     "on": False,
@@ -85,7 +98,7 @@ _lock = threading.Lock()
 # name -> [calls, total, min, max] running aggregates (seconds; calls is
 # an int) — O(1) memory per distinct name, however long the profiling run
 _events: Dict[str, list] = {}
-_spans: List[tuple] = []                      # (name, tid, t0, t1)
+_spans: List[tuple] = []                 # (name, tid, t0, t1, args)
 _dropped = [0]                                # spans over the retention cap
 _t_start = [0.0]
 
@@ -126,26 +139,7 @@ class RecordEvent:
         t1 = time.perf_counter()
         self._ann.__exit__(*exc)
         if _state["on"]:
-            dur = t1 - self._t0
-            with _lock:
-                e = _events.get(self.name)
-                if e is None:
-                    _events[self.name] = [1, dur, dur, dur]
-                else:
-                    e[0] += 1
-                    e[1] += dur
-                    if dur < e[2]:
-                        e[2] = dur
-                    if dur > e[3]:
-                        e[3] = dur
-                # the aggregate above keeps counting unconditionally;
-                # only the per-span timeline is bounded (long profiling
-                # runs must not grow host memory without limit)
-                if len(_spans) < _max_spans():
-                    _spans.append((self.name, threading.get_ident(),
-                                   self._t0, t1))
-                else:
-                    _dropped[0] += 1
+            record_span(self.name, self._t0, t1)
         return False
 
     def __call__(self, fn):
@@ -154,6 +148,48 @@ class RecordEvent:
                 return fn(*a, **k)
         wrapped.__name__ = getattr(fn, "__name__", "wrapped")
         return wrapped
+
+
+class CountedEvent(RecordEvent):
+    """A :class:`RecordEvent` whose seconds also go, profiling or not,
+    into the ``framework.monitor`` counter named after it, dots as
+    underscores: ``model.init`` adds to ``model_init_seconds_total``.
+    For set-up spans a run reads back, never for a per-step one."""
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        from paddle_tpu.framework import monitor
+        monitor.stat_add(self.name.replace(".", "_") + "_seconds_total",
+                         time.perf_counter() - self._t0)
+        return False
+
+
+def record_span(name: str, t0: float, t1: float, **args):
+    """Book one span of ``name`` from ``t0`` to ``t1`` (``perf_counter``
+    seconds) on the calling thread, while profiling is on: into the
+    aggregate table and the timeline, ``args`` as its chrome-trace
+    arguments."""
+    if not _state["on"]:
+        return
+    dur = t1 - t0
+    with _lock:
+        e = _events.get(name)
+        if e is None:
+            _events[name] = [1, dur, dur, dur]
+        else:
+            e[0] += 1
+            e[1] += dur
+            if dur < e[2]:
+                e[2] = dur
+            if dur > e[3]:
+                e[3] = dur
+        # the aggregate above keeps counting unconditionally; only the
+        # per-span timeline is bounded (long profiling runs must not grow
+        # host memory without limit)
+        if len(_spans) < _max_spans():
+            _spans.append((name, threading.get_ident(), t0, t1, args))
+        else:
+            _dropped[0] += 1
 
 
 @contextlib.contextmanager
@@ -254,8 +290,9 @@ def export_chrome_tracing(path: str = "/tmp/profile"):
         dropped = _dropped[0]
     t0 = _t_start[0]
     events = [{"name": name, "ph": "X", "pid": 0, "tid": tid,
-               "ts": (a - t0) * 1e6, "dur": (b - a) * 1e6,
-               "cat": "host"} for name, tid, a, b in spans]
+               "ts": (a - t0) * 1e6, "dur": (b - a) * 1e6, "cat": "host",
+               **({"args": args} if args else {})}
+              for name, tid, a, b, args in spans]
     payload = {"traceEvents": events, "displayTimeUnit": "ms",
                "metadata": {"dropped_spans": dropped,
                             "max_spans": _max_spans()}}

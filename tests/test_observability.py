@@ -607,7 +607,8 @@ class TestPrometheusExport:
         for _ in range(3):
             step(x, y)
         assert monitor.get_stat("train_steps_total") == 3
-        assert monitor.all_histograms()["train_step_ms"]["count"] == 3
+        # a step time is one call's start to the next's: two of them
+        assert monitor.all_histograms()["train_step_ms"]["count"] == 2
 
 
 class TestHistogramSatellites:

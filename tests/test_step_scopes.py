@@ -254,3 +254,187 @@ def test_jit_compiles_total_sees_jaxs_own_retrace():
     assert report["last_cause"] == "jax_retrace"
     assert report["calls"] == 3 and report["compiles"] == 2
     assert monitor.get_stat("jit_cache_hits_total") == 2
+
+
+# ---------------------------------------------------------------------------
+# set-up inside the program: compile phases booked to step calls, and the
+# parameters' draw
+# ---------------------------------------------------------------------------
+
+def _linear_step(seed=0):
+    paddle.seed(seed)
+    net = paddle.nn.Linear(4, 2)
+    opt = paddle.optimizer.SGD(learning_rate=0.1,
+                               parameters=net.parameters())
+    return TrainStep(net, lambda m, x, y: ((m(x) - y) ** 2).mean(), opt)
+
+
+def _linear_batch():
+    rng = np.random.default_rng(0)
+    return (paddle.to_tensor(rng.standard_normal((8, 4)).astype(np.float32)),
+            paddle.to_tensor(rng.standard_normal((8, 2)).astype(np.float32)))
+
+
+class _Spans:
+    """jax's compile-phase spans while open, as (event, start, end)."""
+
+    def __enter__(self):
+        self.spans = []
+        self._fn = lambda event, a, b, **_: self.spans.append((event, a, b))
+        jax.monitoring.register_event_time_span_listener(self._fn)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_time_span_listener(self._fn)
+        return False
+
+    def of(self, event):
+        return [(a, b) for e, a, b in self.spans if e == event]
+
+
+def _union(intervals):
+    total, end = 0.0, -np.inf
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def test_nested_traces_are_each_counted_and_their_time_once():
+    health.reset()
+    with health.step_call("nested"):
+        pass                                  # the listener is in place
+
+    @jax.jit
+    def inner_a(x):
+        return jax.numpy.sin(x) * 2.0
+
+    @jax.jit
+    def inner_b(x):
+        return jax.numpy.cos(x) + 1.0
+
+    @jax.jit
+    def outer(x):
+        return inner_a(x) + inner_b(x)
+
+    with _Spans() as seen, health.step_call("nested"):
+        outer(np.ones(5, np.float32)).block_until_ready()
+    traces = seen.of(health.TRACE_EVENT)
+    report = health.compile_report()["nested"]
+    assert len(traces) >= 3 and report["traces"] == len(traces)
+    # the outer trace holds both inner ones: their time counts once
+    assert report["trace_s"] == pytest.approx(_union(traces), abs=2e-6)
+    assert report["trace_s"] < sum(b - a for a, b in traces)
+    assert report["lower_s"] > 0 and report["backend_s"] > 0
+    # no persistent cache here: every backend compile is cold
+    assert report["cold_compile_s"] == report["backend_s"]
+
+
+def test_compiles_outside_a_step_call_are_booked_to_no_step_site():
+    health.reset()
+    monitor.reset_stat("jit_traces_total")
+    step = _linear_step()
+    x, y = _linear_batch()
+    step(x, y)
+    booked = health.compile_report()["TrainStep"]
+    assert booked["traces"] > 0
+    with _Spans() as seen:
+        jax.jit(lambda v: v * 3.0 + 1.0)(np.ones(11, np.float32))
+        (jax.numpy.ones((13, 3)) * 7.0).block_until_ready()   # eager
+    assert seen.of(health.TRACE_EVENT) and seen.of(
+        health.BACKEND_COMPILE_EVENT)
+    report = health.compile_report()
+    assert report["TrainStep"] == booked
+    assert all(site["traces"] == 0 for name, site in report.items()
+               if name != "TrainStep")
+    assert monitor.get_stat("jit_traces_total") == booked["traces"]
+
+
+def test_a_second_step_on_the_same_shapes_reads_the_persistent_cache(
+        tmp_path):
+    from jax._src import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    try:
+        x, y = _linear_batch()
+        readings = []
+        for seed in (0, 1):
+            health.reset()
+            step = _linear_step(seed)
+            step(x, y)
+            step(x, y)
+            readings.append(health.compile_report()["TrainStep"])
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    first, second = readings
+    assert first["cold_compile_s"] > 0
+    assert second["backend_s"] > 0 and second["cold_compile_s"] == 0
+    assert second["traces"] > 0
+
+
+@pytest.mark.parametrize("build", [
+    lambda: GPT(gpt_tiny()), lambda: Bert(bert_tiny()),
+    lambda: NemotronH(nemotron_h_tiny()),
+    lambda: BailingHybrid(bailing_hybrid_tiny())],
+    ids=["GPT", "Bert", "NemotronH", "BailingHybrid"])
+def test_model_init_is_one_span_a_model_and_its_seconds_a_counter(
+        build, tmp_path):
+    before = monitor.get_stat("model_init_seconds_total")
+    profiler.start_profiler("CPU")
+    try:
+        build()
+        build()
+        calls, total = profiler._events["model.init"][:2]
+    finally:
+        profiler.stop_profiler(profile_path=str(tmp_path / "chrome.json"))
+    assert calls == 2
+    counted = monitor.get_stat("model_init_seconds_total") - before
+    # the counter's clock stops a moment after the span's
+    assert total <= counted <= total + 0.05
+
+
+def test_compile_phases_nest_under_the_steps_spans(tmp_path):
+    health.reset()
+    step = _linear_step(2)
+    x, y = _linear_batch()
+    path = str(tmp_path / "chrome.json")
+    profiler.start_profiler("CPU")
+    try:
+        step(x, y)
+    finally:
+        profiler.stop_profiler(profile_path=path)
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    spans = [(e["ts"], e["ts"] + e["dur"]) for e in events
+             if e["name"] in CHILDREN]
+    rows = [e for e in events if e["name"].startswith("jit.")]
+    names = {e["name"] for e in rows}
+    assert {"jit.trace", "jit.lower", "jit.backend_compile"} <= names
+    assert all(e["args"]["fun_name"] for e in rows
+               if e["name"] != "jit.compile")
+    slack = 50.0                  # us: two clocks read one after the other
+    for e in rows:
+        assert any(a - slack <= e["ts"] and e["ts"] + e["dur"] <= b + slack
+                   for a, b in spans), e
+
+
+def test_train_step_ms_is_one_calls_start_to_the_next():
+    import time
+    hist = monitor.get_histogram("train_step_ms")
+    hist.reset()
+    step = _linear_step(3)
+    x, y = _linear_batch()
+    step(x, y)
+    assert hist.count == 0           # the first call observes nothing
+    time.sleep(0.2)
+    step(x, y)
+    assert hist.count == 1 and hist.max >= 200.0
