@@ -9,7 +9,7 @@ of Kimi Linear (arXiv:2510.26692) as a model file composes it
     o_t = S_t^T q_t
 
 with ``alpha_t`` in (0, 1] per key channel and ``beta_t`` in (0, 1) per
-head.  Everything but the state's carry across chunks is ``jax.numpy`` /
+head.  Everything but the state's pass across chunks is ``jax.numpy`` /
 ``jax.lax``, differentiable by jax.  The device scopes ``qkv``, ``conv``,
 ``gate``, ``scan``, ``out_norm`` and ``out`` are set here, the region
 around them (``kda``) and its ``ln`` by the caller.
@@ -24,13 +24,14 @@ of the delta rule gives, for the state ``S`` entering the chunk,
     O = (Q o Gamma) S + Lower((Q o Gamma)(K / Gamma)^T) Delta
     S <- Diag(Gamma_C) S + (K o Gamma_C / Gamma)^T Delta
 
-Everything but the last line is computed for all chunks at once; the
-state is carried across chunks as ``S <- M S + B`` with ``M =
-Diag(Gamma_C) - (K o Gamma_C / Gamma)^T W`` and ``B = (K o Gamma_C /
-Gamma)^T U``, one ``(d_k, d_k) x (d_k, d_v)`` product a chunk: by the
-Pallas kernels of ``ops/pallas/kda_carry.py``, forward and backward, which
-hold the state in VMEM across all chunks, where ``kda_carry.supported``
-takes the widths and the state's dtype (a TPU, lane-wide heads), else by
+Everything but ``S`` is computed for all chunks at once: ``W``, ``U``,
+``Q o Gamma``, the masked ``A_qk`` and ``K o Gamma_C / Gamma``.  The
+state's pass across the chunks takes them and walks the chunks in order,
+``Delta``, ``O`` and the state's update at each (``kda_carry.chunk``; no
+``(d_k, d_k)`` matrix of a chunk is built): by the Pallas kernels of
+``ops/pallas/kda_carry.py``, forward and backward, which hold the state
+in VMEM across all chunks, where ``kda_carry.supported`` takes the widths
+and the state's dtype (a TPU, lane-wide heads), else by the same step in
 a ``lax.scan``.  Every product of the rule is float32 at the highest
 matmul precision, so that on float32 inputs the chunks agree with the
 token recurrence to float32's rounding.  The unit lower-triangular
@@ -70,9 +71,9 @@ __all__ = ["l2_norm", "kda_gate", "kda_chunked", "kda_mixer"]
 
 SUB_CHUNK = 16
 
-# what the state carried across chunks and its products are computed in;
-# tools/ling3_check.py sets bfloat16 here to show that its comparison of
-# the scan with the token recurrence sees it.  Not an option.
+# what the state carried across chunks is held in (its products take it
+# in float32); tools/ling3_check.py sets bfloat16 here to show that its
+# comparison of the scan with the token recurrence sees it.  Not an option.
 _STATE_DTYPE = jnp.float32
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -82,9 +83,10 @@ monitor.describe("kda_chunks_traced_total",
                  "chunked delta rule, added once per traced call of "
                  "kda_chunked (a trace-time count)")
 monitor.describe("kda_carry_kernel_total",
-                 "traced calls of kda_chunked whose state was carried "
-                 "across chunks by the Pallas kernels of "
-                 "ops/pallas/kda_carry.py (a trace-time count)")
+                 "traced calls of kda_chunked whose state pass across "
+                 "chunks (delta, output and the state's update at each) "
+                 "took the Pallas kernels of ops/pallas/kda_carry.py (a "
+                 "trace-time count)")
 
 
 def l2_norm(x, eps: float = 1e-6):
@@ -158,25 +160,15 @@ def _inverse_bwd(t, dt):
 _unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def _carry(m, b):
-    """The state entering each chunk, (b, n, heads, d_k, d_v), of ``S_0
-    = 0, S <- M S + B`` over ``m`` (b, n, heads, d_k, d_k) and ``b``, in
-    their dtype."""
+def _state_pass(w, u, qg, aqk, kt, gc):
+    """``O`` (b, n, heads, C, d_v) of the state's pass across the chunks,
+    the state held in ``_STATE_DTYPE``: by the kernels where they take it,
+    else by the same step in a ``lax.scan``."""
     from paddle_tpu.ops.pallas import kda_carry
-    if kda_carry.supported(m.shape[-1], b.shape[-1], b.dtype):
+    if kda_carry.supported(w.shape[-1], u.shape[-1], _STATE_DTYPE):
         monitor.stat_add("kda_carry_kernel_total", 1)
-        return kda_carry.carry(m, b)
-
-    def step(s, now):
-        m_n, b_n = now
-        return (jnp.matmul(m_n, s, precision=_HIGHEST,
-                           preferred_element_type=s.dtype)
-                + b_n).astype(s.dtype), s
-
-    zero = jnp.zeros(b.shape[:1] + b.shape[2:], b.dtype)
-    _, entering = jax.lax.scan(step, zero, (jnp.moveaxis(m, 1, 0),
-                                            jnp.moveaxis(b, 1, 0)))
-    return jnp.moveaxis(entering, 0, 1)
+        return kda_carry.state_pass(_STATE_DTYPE, w, u, qg, aqk, kt, gc)
+    return kda_carry.scan_pass(_STATE_DTYPE, w, u, qg, aqk, kt, gc)
 
 
 def kda_chunked(q, k, v, g, beta, chunk: int = 64):
@@ -195,7 +187,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64):
             for t in (q, k, v, g, beta))
     n = (seq + pad) // chunk
     monitor.stat_add("kda_chunks_traced_total", bsz * heads * n)
-    f32, state = jnp.float32, _STATE_DTYPE
+    f32 = jnp.float32
 
     def blocks(t):                          # (b, n, heads, C, width)
         return t.reshape(bsz, n, chunk, heads, -1).transpose(0, 1, 3, 2, 4)
@@ -239,15 +231,7 @@ def kda_chunked(q, k, v, g, beta, chunk: int = 64):
     w = jnp.matmul(t, kc * decay, precision=_HIGHEST)
     u = jnp.matmul(t, vc, precision=_HIGHEST)
     to_end = kc * jnp.exp(gam[..., -1:, :] - gam)             # K o G_C / G
-    whole = decay[..., -1, :]                                 # Gamma_C
-    m = jnp.eye(dk, dtype=f32) * whole[..., :, None] - jnp.einsum(
-        "...ci,...cj->...ij", to_end, w, precision=_HIGHEST)
-    b = jnp.einsum("...ci,...cj->...ij", to_end, u, precision=_HIGHEST)
-
-    entering = _carry(m.astype(state), b.astype(state)).astype(f32)
-    delta = u - jnp.matmul(w, entering, precision=_HIGHEST)
-    o = jnp.matmul(qc * decay, entering, precision=_HIGHEST) \
-        + jnp.matmul(aqk, delta, precision=_HIGHEST)
+    o = _state_pass(w, u, qc * decay, aqk, to_end, decay[..., -1:, :])
     o = o.transpose(0, 1, 3, 2, 4).reshape(bsz, n * chunk, heads, dv)
     return o[:, :seq].astype(v.dtype)
 

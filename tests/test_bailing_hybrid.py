@@ -105,10 +105,10 @@ def test_chunked_delta_rule_equals_the_token_recurrence(seq, chunk, gate,
     the sub-chunks keep inside float32.  ``keys_alike``: keys of positive
     entries, as after the convolution's SiLU, whose dot products near 1
     make the within-chunk inverse's Neumann powers grow as binomial
-    coefficients (a product of powers returned garbage there).  The carry
-    kernels (interpret mode) take lane-wide heads (``width`` 128: then
-    also against the ``lax.scan`` path); the narrow ones stay on the
-    scan."""
+    coefficients (a product of powers returned garbage there).  The state
+    pass's kernels (interpret mode) take lane-wide heads (``width`` 128:
+    then also against the ``lax.scan`` path); the narrow ones stay on
+    the scan."""
     monkeypatch.setattr(kc, "_INTERPRET", True)
     rng = np.random.default_rng(seq + chunk)
     b, h, dk, dv = (2, 2, width, width) if width else (2, 3, 16, 8)
@@ -151,59 +151,122 @@ def test_chunked_delta_rule_equals_the_token_recurrence(seq, chunk, gate,
             assert_close(a, w, tol=5e-5, what=name)
 
 
-def _scan_carry(m, b):
-    """``entering_n = S_n``, ``S_{n+1} = M_n S_n + B_n`` from ``S_0 = 0``,
-    by ``lax.scan`` over the chunk axis."""
-    def step(s, now):
-        return jnp.matmul(now[0], s, precision="highest") + now[1], s
+def _state_pass_inputs(rng, bsz, n, heads, chunk, dk, dv):
+    """Random ``W``, ``U``, ``QG``, ``A``, ``Kt`` and ``Gamma_C`` of the
+    state pass: ``W`` and ``Kt`` small and ``Gamma_C`` in (0.5, 0.95), so
+    that ``Diag(Gamma_C) - Kt^T W`` keeps the state bounded, as the rule's
+    does; ``A`` lower triangular with its diagonal."""
+    lead = (bsz, n, heads)
+    lower = np.tril(np.ones((chunk, chunk), np.float32))
+    return (_normal(rng, lead + (chunk, dk), 0.05),
+            _normal(rng, lead + (chunk, dv)),
+            _normal(rng, lead + (chunk, dk)),
+            _normal(rng, lead + (chunk, chunk), chunk ** -0.5) * lower,
+            _normal(rng, lead + (chunk, dk), 0.05),
+            jnp.asarray(rng.uniform(0.5, 0.95, lead + (1, dk)), jnp.float32))
 
-    _, entering = jax.lax.scan(
-        step, jnp.zeros(b.shape[:1] + b.shape[2:], b.dtype),
-        (jnp.moveaxis(m, 1, 0), jnp.moveaxis(b, 1, 0)))
-    return jnp.moveaxis(entering, 0, 1)
 
-
-@pytest.mark.parametrize("heads, block_bytes", [(2, None), (3, 1)],
+@pytest.mark.parametrize("heads, block_bytes, n",
+                         [(2, None, 6), (3, 1, 5)],
                          ids=["heads_in_one_block", "a_head_a_block"])
 @pytest.mark.parametrize("cotangent",
                          ["every_chunk", "last_chunk_only", "first_chunk_only"])
-def test_carry_kernel_and_its_vjp_equal_the_scan(heads, block_bytes,
-                                                 cotangent, monkeypatch):
-    """``kda_carry.carry`` (interpret mode) and its hand-written backward
-    against ``lax.scan`` and jax's transpose of it, on random ``M``, ``B``
-    and ``E``.  ``last_chunk_only``: ``E`` is zero but for the last
-    chunk, read first by the reverse walk; its cotangent reaches ``dB`` of
-    the first chunk only through every ``M`` in between.
-    ``first_chunk_only``: ``E`` is zero but for the first chunk, read
-    last, on the state entering it, which is zero whatever ``M`` and ``B``
-    are: every gradient is zero."""
+def test_state_pass_kernels_and_their_vjp_equal_the_scan(heads, block_bytes,
+                                                         n, cotangent,
+                                                         monkeypatch):
+    """``kda_carry.state_pass`` (interpret mode) and its hand-written
+    backward against ``kda_carry.scan_pass``, the same step in a
+    ``lax.scan``, and jax's transpose of it, on random inputs and ``dO``;
+    ``d_k`` 128 and ``d_v`` 256, so that a transposed state or product
+    cannot pass; six chunks walk two a grid step, five one.
+    ``last_chunk_only``: ``dO`` is zero but for the last
+    chunk, read first by the reverse walk; its cotangent reaches ``dU``
+    of the first chunk only through every state in between.
+    ``first_chunk_only``: ``dO`` is zero but for the first chunk, read
+    last, on the state entering it, which is zero whatever the inputs
+    are: only that chunk's ``dU`` and ``dA`` are not zero."""
     monkeypatch.setattr(kc, "_INTERPRET", True)
     if block_bytes:
         monkeypatch.setattr(kc, "_BLOCK_BYTES", block_bytes)
-    assert kc._heads_per_block(heads, 128, 128, 4) \
+    bsz, chunk, dk, dv = 2, 32, 128, 256
+    p = kc._chunks_per_step(n)
+    assert p == (2 if n == 6 else 1)
+    assert kc._heads_per_block(heads, p, dk, dv) \
         == (1 if block_bytes else heads)
     rng = np.random.default_rng(heads)
-    bsz, n, dk, dv = 2, 5, 128, 128
-    m = jnp.eye(dk, dtype=jnp.float32) * 0.9 \
-        + _normal(rng, (bsz, n, heads, dk, dk), 0.02)
-    b = _normal(rng, (bsz, n, heads, dk, dv))
-    e = _normal(rng, (bsz, n, heads, dk, dv))
+    ins = _state_pass_inputs(rng, bsz, n, heads, chunk, dk, dv)
+    do = _normal(rng, (bsz, n, heads, chunk, dv))
     if cotangent == "last_chunk_only":
-        e = e.at[:, :-1].set(0.0)
+        do = do.at[:, :-1].set(0.0)
     if cotangent == "first_chunk_only":
-        e = e.at[:, 1:].set(0.0)
-    got, got_vjp = jax.vjp(kc.carry, m, b)
-    want, want_vjp = jax.vjp(_scan_carry, m, b)
-    (dm, db), (want_dm, want_db) = got_vjp(e), want_vjp(e)
-    assert not np.asarray(got[:, 0]).any()
+        do = do.at[:, 1:].set(0.0)
+    got, got_vjp = jax.vjp(
+        lambda *a: kc.state_pass(jnp.float32, *a), *ins)
+    want, want_vjp = jax.vjp(
+        lambda *a: kc.scan_pass(jnp.float32, *a), *ins)
+    names = ("dW", "dU", "dQG", "dA", "dKt", "dGamma_C")
+    grads, want_grads = got_vjp(do), want_vjp(do)
+    assert_close(got, want, tol=1e-6, what="O")
     if cotangent == "first_chunk_only":
-        assert not np.asarray(dm).any() and not np.asarray(db).any()
-        assert_close(got, want, tol=1e-6, what="entering")
-        return
-    assert np.abs(np.asarray(want_db[:, 0])).max() > 0.1
-    for name, a, w in (("entering", got, want), ("dM", dm, want_dm),
-                       ("dB", db, want_db)):
+        for name, a in zip(names, grads):
+            rest = a if name not in ("dU", "dA") else a[:, 1:]
+            assert not np.asarray(rest).any(), name
+    if cotangent == "last_chunk_only":
+        assert np.abs(np.asarray(want_grads[1][:, 0])).max() > 0.1
+    for name, a, w in zip(names, grads, want_grads):
         assert_close(a, w, tol=1e-6, what=name)
+
+
+def test_a_bf16_state_rounds_alike_in_both_executors(monkeypatch):
+    """The state's dtype reaches the kernels (``tools/ling3_check.py``'s
+    bf16 control runs on them): with the state held in bf16, the kernel
+    (interpret mode) and the scan agree, and both stand apart from the
+    float32 state by more than the scan's limit against the recurrence
+    there (1e-4)."""
+    monkeypatch.setattr(kc, "_INTERPRET", True)
+    ins = _state_pass_inputs(np.random.default_rng(7), 1, 6, 2, 32, 128,
+                             128)
+    got = kc.state_pass(jnp.bfloat16, *ins)
+    assert_close(got, kc.scan_pass(jnp.bfloat16, *ins), tol=1e-6,
+                 what="O, bf16 state")
+    wide = kc.scan_pass(jnp.float32, *ins)
+    worst = np.abs(np.asarray(got) - np.asarray(wide)).max()
+    assert worst > 1e-4 * np.abs(np.asarray(wide)).max()
+
+
+def test_the_kernel_path_builds_no_matrix_of_a_chunk(monkeypatch):
+    """The traced forward and backward of ``kda_chunked`` on the kernels
+    (interpret mode, heads 128 wide) hold no array shaped (batch, chunks,
+    heads, d_k, d_k), the per-chunk ``M`` of a carry ``S <- M S + B``:
+    the state pass takes ``Delta`` and ``O`` from the state in VMEM.
+    ``d_v`` 256 keeps the backward's residual (.., d_v, d_k) apart."""
+    monkeypatch.setattr(kc, "_INTERPRET", True)
+    rng = np.random.default_rng(5)
+    b, seq, h, dk, dv, chunk = 1, 256, 2, 128, 256, 64
+    q, k = (kda.l2_norm(_normal(rng, (b, seq, h, dk))) for _ in range(2))
+    v = _normal(rng, (b, seq, h, dv))
+    g = jnp.asarray(-5.0 * rng.random((b, seq, h, dk)), jnp.float32)
+    beta = jnp.asarray(rng.random((b, seq, h)), jnp.float32)
+
+    def shapes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield from (getattr(x.aval, "shape", None) for x in eqn.outvars)
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (tuple, list))
+                            else (param,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from shapes(sub)
+
+    monitor.reset_all_stats()
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jnp.sum(kda.kda_chunked(*a, chunk=chunk)),
+        argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta)
+    assert monitor.get_stat("kda_carry_kernel_total") == 1
+    seen = set(shapes(traced.jaxpr))
+    n = seq // chunk
+    assert (b, n, h, dv, dk) in seen            # the state, as the residual
+    assert (b, n, h, dk, dk) not in seen
 
 
 def test_the_gate_keeps_each_step_within_its_bound():
@@ -250,7 +313,7 @@ def test_model_under_a_bias_agrees_with_the_reference(path, monkeypatch,
     the buffer filled as the harness fills it: a bias that decides
     (expert 1, held, for every token; expert 6, absent, for none).
     ``kernels``: the routed experts over the sorted rows, the MLA core on
-    the flash kernels and KDA's state on the carry kernels, all in
+    the flash kernels and KDA's state pass on its kernels, all in
     interpret mode."""
     kernels = path == "kernels"
     monkeypatch.setattr(gmm, "_INTERPRET", kernels)

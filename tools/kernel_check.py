@@ -42,12 +42,14 @@ the module's own reference.  What it runs, with what the repo already has:
   about 128 rows an expert and a skewed one (1100 rows down to none),
   against the dense mask in float32 at the highest precision: y, dx,
   dW13, dW2 and the gates' gradient;
-* ``kda_carry`` at the Ling cell's shape (1 sequence, 128 chunks, 4 heads,
-  a float32 state of 128 x 128): the carry and its backward against a
-  ``lax.scan`` at the highest precision and jax's transpose of it, each
-  output's worst difference relative to its largest value, and the device
-  time of each of the four programs (a profiler trace of a few calls: the
-  ``XLA Modules`` events on the chip, their mean).
+* ``kda_carry`` at the Ling cell's shape (1 sequence, 128 chunks of 64,
+  4 heads, a float32 state of 128 x 128): the state pass and its backward
+  (``kda_carry.state_pass``) against the same step in a ``lax.scan``
+  (``kda_carry.scan_pass``) at the highest precision and jax's transpose
+  of it, each output's worst difference relative to its largest value,
+  and the device time of each side's forward and backward (a profiler
+  trace of a few calls: the ``XLA Modules`` events on the chip, their
+  mean).
 
 ``python tools/kernel_check.py grouped`` runs the rows of one family
 (``flash``, ``tail``, ``other``, ``grouped``, ``kda_carry``) alone.
@@ -80,8 +82,9 @@ SWIGLU_SHAPE = (8192, 8, 2560, 768)      # tokens, experts, hidden, inner
 SWIGLU_TOP_K = 8
 SWIGLU_SKEWED = (1100, 400, 200, 100, 50, 20, 5, 0)
 KDA_CARRY_SHAPE = (1, 128, 4, 128, 128)   # batch, chunks, heads, d_k, d_v
-# the two float32 carries, the kernel's and XLA's, each at the highest
-# precision; both products are float32 to the last places
+KDA_CHUNK = 64
+# the two float32 state passes, the kernels' and XLA's scan, each at the
+# highest precision; both are float32 to the last places
 KDA_CARRY_TOL = 2e-5
 
 
@@ -456,34 +459,35 @@ def kda_carry_rows():
     from paddle_tpu.ops.pallas import kda_carry
 
     bsz, n, heads, dk, dv = KDA_CARRY_SHAPE
+    lead, chunk = (bsz, n, heads), KDA_CHUNK
     rng = np.random.default_rng(2)
     f32 = jnp.float32
-    # a decay near 1 and a spectral radius under 1, as the rule's M has:
-    # the state stays bounded over the 128 chunks
-    m = jnp.asarray(np.eye(dk) * 0.9 + rng.standard_normal(
-        (bsz, n, heads, dk, dk)) * (0.05 / np.sqrt(dk)), f32)
-    b, e = (jnp.asarray(rng.standard_normal(KDA_CARRY_SHAPE), f32)
-            for _ in range(2))
 
-    def scan(m, b):
-        def step(s, now):
-            return jnp.matmul(now[0], s, precision=jax.lax.Precision.HIGHEST
-                              ) + now[1], s
-        _, entering = jax.lax.scan(
-            step, jnp.zeros((bsz, heads, dk, dv), f32),
-            (jnp.moveaxis(m, 1, 0), jnp.moveaxis(b, 1, 0)))
-        return jnp.moveaxis(entering, 0, 1)
+    def normal(shape, std=1.0):
+        return jnp.asarray(std * rng.standard_normal(shape), f32)
 
-    forward = {"kernel": jax.jit(kda_carry.carry), "scan": jax.jit(scan)}
-    backward = jax.jit(lambda pullback, e: pullback(e))
-    pullbacks = {name: jax.vjp(fn, m, b)[1] for name, fn in forward.items()}
+    # W and Kt small, Gamma_C in (0.5, 0.95): Diag(Gamma_C) - Kt^T W keeps
+    # the state bounded over the 128 chunks, as the rule's does
+    ins = (normal(lead + (chunk, dk), 0.05), normal(lead + (chunk, dv)),
+           normal(lead + (chunk, dk)),
+           normal(lead + (chunk, chunk), chunk ** -0.5)
+           * np.tril(np.ones((chunk, chunk), np.float32)),
+           normal(lead + (chunk, dk), 0.05),
+           jnp.asarray(rng.uniform(0.5, 0.95, lead + (1, dk)), f32))
+    do = normal(lead + (chunk, dv))
+    passes = {"kernel": kda_carry.state_pass, "scan": kda_carry.scan_pass}
+    forward = {name: jax.jit(functools.partial(fn, f32))
+               for name, fn in passes.items()}
+    backward = jax.jit(lambda pullback, do: pullback(do))
+    pullbacks = {name: jax.vjp(forward[name], *ins)[1] for name in passes}
 
     def run(name):
-        return (forward[name](m, b), *backward(pullbacks[name], e))
+        return (forward[name](*ins), *backward(pullbacks[name], do))
 
-    check(f"kda_carry {KDA_CARRY_SHAPE} float32 fwd + dM + dB",
+    check(f"kda_state_pass {KDA_CARRY_SHAPE} C={chunk} float32 fwd + vjp",
           lambda: run("kernel"), lambda: run("scan"),
-          ("entering", "dM", "dB"), tol=KDA_CARRY_TOL)
+          ("o", "dW", "dU", "dQG", "dA", "dKt", "dGamma_C"),
+          tol=KDA_CARRY_TOL)
     row = ROWS[-1]
     if "max_abs_err" not in row:
         return
@@ -491,9 +495,9 @@ def kda_carry_rows():
                       for label, err in row["max_abs_err"].items()}
     row["device_ms"] = {
         f"{name}.{way}": round(device_ms(*call), 4)
-        for name in forward
-        for way, call in (("fwd", (forward[name], m, b)),
-                          ("bwd", (backward, pullbacks[name], e)))}
+        for name in passes
+        for way, call in (("fwd", (forward[name], *ins)),
+                          ("bwd", (backward, pullbacks[name], do)))}
     print(json.dumps({"kernel": row["kernel"], "rel_err": row["rel_err"],
                       "device_ms": row["device_ms"]}), flush=True)
 
