@@ -14,9 +14,9 @@ with random weights from seed 0, and checks what comes out:
   loss of an untrained model (ln V), the last below the first, the
   parameters on a TPU device afterwards;
 * the compiled step holds one Mosaic custom call per layer for each of
-  the flash forward, dq and dk/dv kernels and never materialises the
-  (B, H, S, S) score matrix — the Pallas kernel is in the step and
-  ``_xla_attention`` is not;
+  the flash forward and the fused backward (``flash_bwd_dq_dkv``) and
+  never materialises the (B, H, S, S) score matrix — the Pallas kernel
+  is in the step and ``_xla_attention`` is not;
 * the flash kernel agrees with its reference (float32, highest matmul
   precision), forward and backward, on one small input at the model's
   own (S, d);
@@ -132,7 +132,7 @@ def one_chip(devices) -> dict:
               if 'custom_call_target="tpu_custom_call"' in ln]
     calls = {k: sum(1 for ln in mosaic
                     if re.search(rf'op_name="[^"]*\b{k}\b', ln))
-             for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+             for k in ("flash_fwd", "flash_bwd_dq_dkv")}
     _require(all(n == cfg.num_layers for n in calls.values()),
              f"expected {cfg.num_layers} Mosaic calls per flash kernel "
              f"in the compiled step, found {calls}")

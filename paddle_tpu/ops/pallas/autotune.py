@@ -36,7 +36,8 @@ _PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _cache = None
 _lock = threading.Lock()
 
-# set via force_blocks() during measurement; keys "both"/"fwd"/"bwd"/"dkv"
+# set via force_blocks() during measurement; keys "both"/"fwd"/"bwd"/"dkv"/
+# "fused"
 _FORCE: dict = {}
 
 # the lopsided ones are for the two-level nest: dq wants a large block of
@@ -83,12 +84,16 @@ def _entry_blocks(hit):
 # the dk/dv kernel on the two-level nest, where its tile is (the piece of
 # queries a sub-tile takes, the block of keys a grid step owns) and so the
 # mirror image of the dq kernel's: one "bwd" entry cannot suit both.
-# Without an entry of its own it shares the backward's, the backward the
+# "fused" is the nest's one-kernel backward, on dk/dv's grid and with a
+# tile of the same meaning.  Without an entry of its own the fused
+# backward shares dk/dv's, dk/dv the backward's, the backward the
 # forward's.
 _FORCED_BY = {"fwd": ("fwd", "both"), "bwd": ("bwd", "both"),
-              "dkv": ("dkv", "bwd", "both")}
+              "dkv": ("dkv", "bwd", "both"),
+              "fused": ("fused", "dkv", "bwd", "both")}
 _ENTRY_OF = {"fwd": ("fwd",), "bwd": ("bwd", "fwd"),
-             "dkv": ("dkv", "bwd", "fwd")}
+             "dkv": ("dkv", "bwd", "fwd"),
+             "fused": ("fused", "dkv", "bwd", "fwd")}
 
 
 def lookup(sq, sk, d, dtype, causal, biased, direction="fwd"):
@@ -120,7 +125,8 @@ def record(sq, sk, d, dtype, causal, biased, blocks, persist=True,
 class force_blocks:
     """Context manager pinning the kernel block choice (measurement).
     ``direction`` pins only the forward ("fwd"), the backward ("bwd") or,
-    on the two-level nest, the dk/dv kernel ("dkv"); default pins all."""
+    on the two-level nest, the dk/dv kernel ("dkv") or the fused backward
+    ("fused"); default pins all."""
 
     def __init__(self, bq: int, bk: int, direction: str = "both"):
         self._blocks = (bq, bk)
@@ -267,11 +273,13 @@ def measure(sq, sk, d, dtype="bfloat16", causal=False, biased=False,
     return best, results
 
 
-_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# longest first: the fused backward's name contains dq's
+_KERNELS = ("flash_bwd_dq_dkv", "flash_bwd_dkv", "flash_bwd_dq",
+            "flash_fwd")
 
 
 def _kernel_seconds(f, args, iters):
-    """{kernel name: device seconds a call} for the three flash kernels,
+    """{kernel name: device seconds a call} for the flash kernels,
     read from a profiler trace of ``iters`` calls of ``f`` on the first
     chip: what the kernel itself takes, with no dispatch or fetch in it.
     (A wall-clock sweep of the same candidates ranked them differently:
@@ -305,26 +313,29 @@ def _kernel_seconds(f, args, iters):
             if line.name != "XLA Ops":
                 continue
             for event in line.events:
-                for kernel in _KERNELS:          # no name contains another
+                for kernel in _KERNELS:
                     if kernel in event.name:
                         ns[kernel] += event.duration_ns
+                        break
     return {kernel: t / iters / 1e9 for kernel, t in ns.items()}
 
 
 def measure_split(sq, sk, d, dtype="bfloat16", causal=False, biased=False,
                   batch=1, heads=8, iters=3, persist=True, verbose=False,
                   rejected=None):
-    """Tune the forward, the backward and, on the two-level nest, the dk/dv
-    tile independently, in one sweep: every candidate is forced on all
-    three kernels, forward + backward run ``iters`` times under a trace,
-    and each kernel's own device time picks its winner (``_kernel_seconds``).
-    The kernels are separate calls, so a kernel's time at a tile does not
-    depend on the tiles of the other two.  On the three-axis grid dq and
-    dk/dv share the "bwd" entry, which their summed time picks.
+    """Tune the forward's and the backward's tiles independently, in one
+    sweep: every candidate is forced on every kernel, forward + backward
+    run ``iters`` times under a trace, and each kernel's own device time
+    picks its winner (``_kernel_seconds``).  The kernels are separate
+    calls, so a kernel's time at a tile does not depend on the others'.
+    The backward's entries follow the loop nest the call runs: on the
+    two-level nest the fused kernel's ("fused") or, where its accumulator
+    does not fit, the dq kernel's ("bwd") and the dk/dv kernel's ("dkv");
+    on the three-axis grid one "bwd" entry that dq's and dk/dv's summed
+    time picks.
 
-    Returns ((fwd_best, fwd_res), (bwd_best, bwd_res), (dkv_best,
-    dkv_res) or None), each ``res`` {(bq, bk): seconds}; None where no
-    candidate is viable.
+    Returns {direction: (best, res)}, each ``res`` {(bq, bk): seconds};
+    None where no candidate is viable.
     """
     import jax
 
@@ -341,16 +352,19 @@ def measure_split(sq, sk, d, dtype="bfloat16", causal=False, biased=False,
         return None
     # every candidate divides the sequences, so all run the same nest
     nest = fa._two_level(sq, sk, d, q.dtype, *next(iter(times)), biased)
-    cost = {"fwd": lambda t: t["flash_fwd"],
-            "bwd": lambda t: t["flash_bwd_dq"] + (
-                0.0 if nest else t["flash_bwd_dkv"])}
-    if nest:
+    cost = {"fwd": lambda t: t["flash_fwd"]}
+    if nest and fa._fused_bwd_fits(sq, d, q.dtype):
+        cost["fused"] = lambda t: t["flash_bwd_dq_dkv"]
+    elif nest:
+        cost["bwd"] = lambda t: t["flash_bwd_dq"]
         cost["dkv"] = lambda t: t["flash_bwd_dkv"]
-    out = []
+    else:
+        cost["bwd"] = lambda t: t["flash_bwd_dq"] + t["flash_bwd_dkv"]
+    out = {}
     for direction, of in cost.items():
         res = {blocks: of(t) for blocks, t in times.items()}
         best = min(res, key=res.get)
         record(sq, sk, d, dtype, causal, biased, best, persist=persist,
                direction=direction, verified=oracle is not None)
-        out.append((best, res))
-    return (*out, None) if not nest else tuple(out)
+        out[direction] = (best, res)
+    return out

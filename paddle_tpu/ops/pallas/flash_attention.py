@@ -23,7 +23,8 @@ innermost, sequentially-executed grid axis, so running (max, sum-exp, acc)
 state lives in VMEM scratch.  The per-row logsumexp is written out as a
 residual for the backward.
 
-Backward: three kernels, all recomputing p-tiles from (q, k, lse, mask):
+Backward on this grid: three kernels, all recomputing p-tiles from (q, k,
+lse, mask):
   - dq:     grid (bh, q_blocks, kv_blocks), dq accumulates in VMEM over kv.
   - dk/dv:  grid (bh, kv_blocks, q_blocks), dk/dv accumulate over q.
   - dbias:  grid (g, kv_blocks, q_blocks, r) where g indexes the bias'
@@ -36,16 +37,23 @@ precompute.  This is the standard FlashAttention-2 backward dataflow.
 Two-level tiling: where a call has no bias, segments or tail, sq == sk and
 the operands of one (batch, head) fit ``_RESIDENT_VMEM_BYTES``, the same
 dataflow runs as a different loop nest.  What is resident is large, what
-is computed at once is small: forward and dq run on a grid (bh, q_blocks)
-with K and V whole in VMEM, dk/dv on (bh, kv_blocks) with Q, dO, lse and
-delta whole, and the innermost axis is a static loop inside the kernel
-over sub-tiles that carries its accumulators as values.  Under a causal
-mask each sub-tile is clipped to the bounding box of its visible scores,
-the loop stops at the diagonal, and only the sub-tiles the diagonal
-crosses pay for the iota/compare/select mask: a grid step costs more than
-the work a finer *grid* would skip (the measured reason S = 1024 sat on
-one (1024, 1024) tile and computed the whole square), a sub-tile of
-straight-line code does not.
+is computed at once is small: the forward runs on a grid (bh, q_blocks)
+with K and V whole in VMEM, the backward on (bh, kv_blocks) with Q, dO,
+lse and delta whole, and the innermost axis is a static loop inside the
+kernel over sub-tiles that carries its accumulators as values.  The
+backward is one kernel (``flash_bwd_dq_dkv``): each sub-tile builds S, P,
+dP and dS once and feeds all three products, dK and dV carried as values,
+dQ added into a float32 VMEM accumulator of the whole (batch, head) that
+lives across the kv axis.  Where that accumulator does not fit beside the
+resident operands (``_fused_bwd_fits``), a dq kernel on (bh, q_blocks)
+with K and V whole and a dk/dv kernel on (bh, kv_blocks) split the work,
+each rebuilding P, dP and dS.  Under a causal mask each sub-tile is
+clipped to the bounding box of its visible scores, the loop stops at the
+diagonal, and only the sub-tiles the diagonal crosses pay for the
+iota/compare/select mask: a grid step costs more than the work a finer
+*grid* would skip (the measured reason S = 1024 sat on one (1024, 1024)
+tile and computed the whole square), a sub-tile of straight-line code
+does not.
 
 Rows with no visible key (fully masked) produce output 0 with zero
 gradients (lse = -inf); the XLA fallback's uniform-attention behaviour on
@@ -81,11 +89,14 @@ _MIN_BLOCK = 128
 
 # Two-level tiling keeps these operands of one (batch, head) in VMEM for a
 # whole row of the grid: K and V (forward, dq), or Q, dO, lse and delta
-# (dk/dv).  Their VMEM footprint (lanes padded to 128) may be this many
-# bytes; the pipeline double-buffers it, so the kernels hold twice that of
-# the 16 MiB Mosaic scopes by default, beside their working tiles.  2 MiB
-# admits S <= 1024 at d <= 128 in any dtype up to four bytes, which is as
-# far as the nest has been measured (PERF.md, PR 26).
+# (the backward).  Their VMEM footprint (lanes padded to 128) may be this
+# many bytes; the pipeline double-buffers it, so the kernels hold twice
+# that of the 16 MiB Mosaic scopes by default, beside their working tiles.
+# 2 MiB admits S <= 1024 at d <= 128 in any dtype up to four bytes, which
+# is as far as the nest has been measured (PERF.md).  The fused backward's
+# float32 dQ accumulator counts against the same budget
+# (``_fused_bwd_fits``): S = 1024 in bf16 at d <= 128 fits, float32 there
+# does not.
 _RESIDENT_VMEM_BYTES = 2 * 2 ** 20
 
 # tests flip this to run the kernels in interpreter mode on CPU
@@ -106,6 +117,11 @@ monitor.describe("flash_subtiles_skipped_total",
                  "squares of the score matrix above the causal diagonal "
                  "that the flash kernels' two-level loop nest leaves out, "
                  "over batch x heads, added once per traced kernel call (a "
+                 "trace-time count)")
+monitor.describe("flash_bwd_fused_total",
+                 "flash attention backwards that ran as one kernel "
+                 "(flash_bwd_dq_dkv: S, P, dP and dS built once a sub-tile "
+                 "for dQ, dK and dV), added once per traced call (a "
                  "trace-time count)")
 monitor.describe("flash_dispatch_kernel_total",
                  "attention calls that flash_attention.supported() sent to "
@@ -280,8 +296,21 @@ def _two_level(sq, sk, d, dtype, block_q, block_k, has_mask):
         return False
     item = jnp.dtype(dtype).itemsize
     kv = 2 * _vmem_bytes(sk, d, item)                    # forward, dq
-    q_side = 2 * _vmem_bytes(sq, d, item) + 2 * _vmem_bytes(sq, 1, 4)
-    return max(kv, q_side) <= _RESIDENT_VMEM_BYTES
+    return max(kv, _q_side_bytes(sq, d, item)) <= _RESIDENT_VMEM_BYTES
+
+
+def _q_side_bytes(s, d, item):
+    """VMEM bytes of what the backward keeps whole: Q, dO, lse, delta."""
+    return 2 * _vmem_bytes(s, d, item) + 2 * _vmem_bytes(s, 1, 4)
+
+
+def _fused_bwd_fits(s, d, dtype):
+    """Does a call the nest admits take the fused backward?  Its resident
+    operands and its float32 (s, d) dQ accumulator must fit
+    ``_RESIDENT_VMEM_BYTES`` together; shapes alone decide."""
+    item = jnp.dtype(dtype).itemsize
+    return _q_side_bytes(s, d, item) + _vmem_bytes(s, d, 4) \
+        <= _RESIDENT_VMEM_BYTES
 
 
 def _count_subtiles(bh, s, block, width, causal, block_is_q):
@@ -876,17 +905,27 @@ def _bwd_dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                             dk_ref, dv_ref, *, scale, causal, block_q,
-                             block_k):
+                             *outs, scale, causal, block_q, block_k):
     """dk/dv on the two-level nest, the transpose of the above: grid
     (bh, kb), Q, dO, lse and delta whole in VMEM, the q axis a static loop
     that carries both accumulators; a piece of queries adds to the columns
     up to its last query.  The loop runs from the last piece, which sees
     every column of the block and so starts the accumulators, back to the
-    diagonal."""
+    diagonal.
+
+    ``outs`` is (dk_ref, dv_ref), or (dq_ref, dk_ref, dv_ref, dq_scr) for
+    the fused backward: then each sub-tile's P, dP and dS also give dS K,
+    added into its rows of ``dq_scr``, a float32 (S, d) accumulator that
+    lives across the kv axis.  The first kv block's pieces cover every
+    query, causal or not, so they set the accumulator and later blocks add
+    to it; the last writes dQ through an output block that every kv block
+    shares."""
     from jax.experimental import pallas as pl
 
+    dq_ref, dk_ref, dv_ref, dq_scr = outs if len(outs) == 4 \
+        else (None, *outs, None)
     sq = q_ref.shape[1]
+    n_kb = sq // block_k
 
     def run(kb):
         c0 = kb * block_k
@@ -896,17 +935,23 @@ def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             rows, cols = slice(r, r + n), slice(lo, hi)
             q = q_ref[0, rows, :]
             do = do_ref[0, rows, :]
-            p = _rebuild_p_at(q, k_ref[0, cols, :], lse_ref[0, rows, :],
-                              scale, masked, r, c0 + lo)
+            k = k_ref[0, cols, :]
+            p = _rebuild_p_at(q, k, lse_ref[0, rows, :], scale, masked, r,
+                              c0 + lo)
             # contract the query axis: pT@do and dsT@q with bf16 operands
             dv_t = jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = _dot_nt(do, v_ref[0, cols, :])
-            ds = p * (dp - delta_ref[0, rows, :])
+            ds = (p * (dp - delta_ref[0, rows, :])).astype(q.dtype)
             dk_t = jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if dq_scr is not None:
+                dq = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+                if kb:
+                    dq = dq_scr[rows, :] + dq
+                dq_scr[rows, :] = dq
             if dk is None:
                 dk, dv = dk_t, dv_t
             else:
@@ -914,8 +959,10 @@ def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dv = _set_rows(dv, lo, hi, dv[cols] + dv_t)
         dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
+        if dq_ref is not None and kb == n_kb - 1:
+            dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
-    _at_block(pl, sq // block_k, run)
+    _at_block(pl, n_kb, run)
 
 
 def _bwd_dbias_kernel(*args, scale, causal, block_q, block_k, n_qb, n_r,
@@ -999,7 +1046,19 @@ def _flash_bwd_folded(qt, kt, vt, bias, qseg, kseg, ot, lse, do, scale,
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
                     axis=-1, keepdims=True)
 
-    # the two-level nest reads a tile of its own for dk/dv (autotune)
+    # the two-level nest reads a tile of its own for the fused backward,
+    # and for dk/dv where it splits (autotune)
+    fused_blocks = _blocks_for(sq, sk, d, qt.dtype, causal,
+                               has_bias or has_segs, direction="fused")
+    if _two_level(sq, sk, d, qt.dtype, *fused_blocks, has_bias or has_segs) \
+            and _fused_bwd_fits(sq, d, qt.dtype):
+        monitor.stat_add("flash_bwd_fused_total", 1)
+        _count_subtiles(bh, sq, fused_blocks[1], fused_blocks[0], causal,
+                        False)
+        dq, dk, dv = _bwd_fused_call(qt, kt, vt, dot, lse, delta, scale,
+                                     causal, fused_blocks, _INTERPRET,
+                                     pl.pallas_call)
+        return _unfold(dq, b, h), _unfold(dk, b, h), _unfold(dv, b, h), None
     dkv_blocks = _blocks_for(sq, sk, d, qt.dtype, causal,
                              has_bias or has_segs, direction="dkv")
     if all(_two_level(sq, sk, d, qt.dtype, *blocks, has_bias or has_segs)
@@ -1071,20 +1130,62 @@ def _flash_bwd_folded(qt, kt, vt, bias, qseg, kseg, ot, lse, do, scale,
             dbias)
 
 
+def _tile(pl, rows, cols):
+    """A nest operand blocked along the grid's second axis."""
+    return pl.BlockSpec((1, rows, cols), lambda bh, i: (bh, i, 0))
+
+
+def _whole(pl, rows, cols):
+    """A nest operand resident for the (batch, head): its index map ignores
+    the grid's second axis, so the pipeline fetches it (or, an output,
+    writes it back) once a row of the grid."""
+    return pl.BlockSpec((1, rows, cols), lambda bh, i: (bh, 0, 0))
+
+
+@functools.partial(_traced_once, static_argnums=(6, 7, 8, 9, 10))
+def _bwd_fused_call(qt, kt, vt, dot, lse, delta, scale, causal, blocks,
+                    interpret, pallas_call):
+    """The fused backward's ``pallas_call`` on the two-level nest: (dq, dk,
+    dv), folded.  ``blocks`` is (the piece of queries a sub-tile takes, the
+    block of keys a grid step owns)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, s, d = qt.shape
+    block_q, block_k = blocks
+    with no_x64():
+        return pallas_call(
+            functools.partial(_bwd_dkv_resident_kernel, scale=scale,
+                              causal=causal, block_q=block_q,
+                              block_k=block_k),
+            grid=(bh, s // block_k),
+            in_specs=[_whole(pl, s, d), _tile(pl, block_k, d),
+                      _tile(pl, block_k, d), _whole(pl, s, d),
+                      _whole(pl, s, 1), _whole(pl, s, 1)],
+            out_specs=[_whole(pl, s, d), _tile(pl, block_k, d),
+                       _tile(pl, block_k, d)],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, s, d), qt.dtype),
+                jax.ShapeDtypeStruct((bh, s, d), kt.dtype),
+                jax.ShapeDtypeStruct((bh, s, d), vt.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
+            name="flash_bwd_dq_dkv",
+            interpret=interpret,
+        )(qt, kt, vt, dot, lse, delta)
+
+
 @functools.partial(_traced_once, static_argnums=(6, 7, 8, 9, 10, 11))
 def _bwd_resident_calls(qt, kt, vt, dot, lse, delta, scale, causal,
                         dq_blocks, dkv_blocks, interpret, pallas_call):
-    """The two backward kernels on the two-level nest: (dq, dk, dv), folded.
-    ``tile`` blocks an operand along the grid's second axis, ``whole``
-    keeps it resident for the (batch, head): its index map ignores that
-    axis, so the pipeline fetches it once a row of the grid."""
+    """The split backward on the two-level nest, for calls whose dQ
+    accumulator does not fit beside the resident operands: a dq and a dk/dv
+    kernel, (dq, dk, dv), folded."""
     from jax.experimental import pallas as pl
 
     bh, s, d = qt.shape
-    tile = lambda rows, cols: pl.BlockSpec((1, rows, cols),
-                                           lambda bh, i: (bh, i, 0))
-    whole = lambda rows, cols: pl.BlockSpec((1, rows, cols),
-                                            lambda bh, i: (bh, 0, 0))
+    tile = functools.partial(_tile, pl)
+    whole = functools.partial(_whole, pl)
     block_q, block_k = dq_blocks
     with no_x64():
         dq = pallas_call(

@@ -53,9 +53,11 @@ Names in a device trace (``start_profiler`` or a bare
   the forward, ``transpose(`` the backward, ``rematted_computation`` the
   forward that ``jax.checkpoint`` runs again.
 * counters (``framework.monitor``), added once per traced call, so
-  trace-time counts: ``flash_subtiles_computed_total`` / ``_skipped_total``
-  and ``flash_dispatch_kernel_total`` / ``_xla_for_speed_total``
-  (``ops/pallas/flash_attention.py``), ``moe_calls_traced_total``,
+  trace-time counts: ``flash_subtiles_computed_total`` / ``_skipped_total``,
+  ``flash_dispatch_kernel_total`` / ``_xla_for_speed_total`` and
+  ``flash_bwd_fused_total`` (backwards that ran as the one kernel
+  ``flash_bwd_dq_dkv``; ``ops/pallas/flash_attention.py``),
+  ``moe_calls_traced_total``,
   ``moe_expert_rows_computed_total``, ``moe_expert_rows_expected_total``
   (``nn/functional/moe.py``), ``moe_router_kept_blocks_total`` (expert
   blocks whose ``jax.checkpoint`` keeps the router's choice for the

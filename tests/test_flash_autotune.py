@@ -77,6 +77,28 @@ def test_dkv_direction_falls_back_to_bwd_then_fwd():
             assert autotune.lookup(*key, direction="bwd") == (256, 256)
 
 
+def test_fused_direction_falls_back_to_dkv_then_bwd_then_fwd():
+    key = (1024, 1024, 64, "bfloat16", True, False)
+    autotune.record(*key, (512, 512))
+    assert autotune.lookup(*key, direction="fused") == (512, 512)
+    autotune.record(*key, (1024, 256), direction="bwd")
+    assert autotune.lookup(*key, direction="fused") == (1024, 256)
+    autotune.record(*key, (128, 1024), direction="dkv")
+    assert autotune.lookup(*key, direction="fused") == (128, 1024)
+    autotune.record(*key, (256, 1024), direction="fused")
+    assert autotune.lookup(*key, direction="fused") == (256, 1024)
+    assert autotune.lookup(*key, direction="dkv") == (128, 1024)
+    with open(autotune._PATH) as f:
+        assert json.load(f)[
+            "1024x1024:d64:bfloat16:causal:nobias:fused"] == [256, 1024]
+    # pinning dk/dv pins the fused backward too, unless it is pinned itself
+    with autotune.force_blocks(256, 256, direction="dkv"):
+        assert autotune.lookup(*key, direction="fused") == (256, 256)
+        with autotune.force_blocks(512, 1024, direction="fused"):
+            assert autotune.lookup(*key, direction="fused") == (512, 1024)
+            assert autotune.lookup(*key, direction="dkv") == (256, 256)
+
+
 def test_distinct_mask_class_keys():
     autotune.record(2048, 2048, 64, "bfloat16", False, True, (256, 512))
     assert autotune.lookup(2048, 2048, 64, "bfloat16", False,
@@ -153,16 +175,18 @@ def test_split_sweep_traces_every_candidate(monkeypatch):
 
     monkeypatch.setattr(autotune.force_blocks, "__exit__", exit_and_read)
     before = monitor.get_stat("flash_subtiles_computed_total")
-    fwd, bwd, dkv = autotune.measure_split(
+    out = autotune.measure_split(
         256, 256, 64, "float32", causal=True, batch=1, heads=1, iters=1,
         persist=False)
-    # one sweep: three kernels a candidate, 3 of 4 sub-tiles each at
+    # one sweep: two kernels a candidate, 3 of 4 sub-tiles each at
     # (128, 128), then 1 of 1
-    assert [traced[0] - before, traced[1] - traced[0]] == [9, 3]
-    # this call runs the two-level nest, so dk/dv has an entry of its own
-    assert set(fwd[1]) == set(bwd[1]) == set(dkv[1]) == {(128, 128),
-                                                         (256, 256)}
-    for direction, (best, _) in (("fwd", fwd), ("bwd", bwd), ("dkv", dkv)):
+    assert [traced[0] - before, traced[1] - traced[0]] == [6, 2]
+    # this call runs the two-level nest and its fused backward, which has
+    # an entry of its own
+    assert list(out) == ["fwd", "fused"]
+    assert set(out["fwd"][1]) == set(out["fused"][1]) == {(128, 128),
+                                                          (256, 256)}
+    for direction, (best, _) in out.items():
         assert autotune.lookup(256, 256, 64, "float32", True, False,
                                direction=direction) == best
 
@@ -170,16 +194,27 @@ def test_split_sweep_traces_every_candidate(monkeypatch):
 def test_split_sweep_picks_each_kernel_by_its_own_time(monkeypatch):
     """Each direction's winner is the tile at which its own kernel is
     fastest; on the three-axis grid dq and dk/dv share the "bwd" entry."""
-    clock = {(128, 128): (3.0, 1.0, 9.0), (256, 256): (1.0, 5.0, 2.0),
-             (128, 256): (2.0, 2.0, 1.0)}
+    # seconds of (flash_fwd, flash_bwd_dq, flash_bwd_dkv, flash_bwd_dq_dkv)
+    clock = {(128, 128): (3.0, 1.0, 9.0, 4.0),
+             (256, 256): (1.0, 5.0, 2.0, 3.0),
+             (128, 256): (2.0, 2.0, 1.0, 5.0)}
+    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+             "flash_bwd_dq_dkv")
     monkeypatch.setattr(autotune, "CANDIDATES", list(clock))
     monkeypatch.setattr(
         autotune, "_kernel_seconds", lambda f, args, iters: dict(zip(
-            autotune._KERNELS, clock[autotune._FORCE["both"]])))
-    fwd, bwd, dkv = autotune.measure_split(
-        256, 256, 64, "float32", causal=True, persist=False)
-    assert (fwd[0], bwd[0], dkv[0]) == ((256, 256), (128, 128), (128, 256))
+            names, clock[autotune._FORCE["both"]])))
+    best = lambda out: {dr: b for dr, (b, _) in out.items()}
+    # the nest, its fused backward
+    assert best(autotune.measure_split(
+        256, 256, 64, "float32", causal=True, persist=False)) == {
+            "fwd": (256, 256), "fused": (256, 256)}
+    # the nest where the dQ accumulator does not fit: dq and dk/dv apart
+    monkeypatch.setattr(fa, "_fused_bwd_fits", lambda *_: False)
+    assert best(autotune.measure_split(
+        256, 256, 64, "float32", causal=True, persist=False)) == {
+            "fwd": (256, 256), "bwd": (128, 128), "dkv": (128, 256)}
     # a biased call keeps the old grid: one entry for both backward kernels
-    fwd, bwd, dkv = autotune.measure_split(
-        256, 256, 64, "float32", causal=True, biased=True, persist=False)
-    assert (fwd[0], bwd[0], dkv) == ((256, 256), (128, 256), None)
+    assert best(autotune.measure_split(
+        256, 256, 64, "float32", causal=True, biased=True,
+        persist=False)) == {"fwd": (256, 256), "bwd": (128, 256)}

@@ -380,6 +380,25 @@ def _kernel_grids(fn, *args):
     return [tuple(m.grid) for m in trace_kernels(fn, *args)]
 
 
+def _kernel_names(fn, *args):
+    """The ``name`` of every pallas_call in the jaxpr of ``fn(*args)``."""
+    import re
+    return re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)(*args)))
+
+
+class _without_the_fused_backward:
+    """Every call the nest admits takes the split dq and dk/dv kernels, as
+    the calls whose dQ accumulator does not fit do."""
+
+    def __enter__(self):
+        self._fits = fa._fused_bwd_fits
+        fa._fused_bwd_fits = lambda *_: False
+
+    def __exit__(self, *exc):
+        fa._fused_bwd_fits = self._fits
+        return False
+
+
 class TestTwoLevelTiling:
     """The two-level nest (resident K/V or Q/dO, an in-kernel loop over
     sub-tiles to the diagonal) in interpret mode: the unmasked body under
@@ -425,7 +444,8 @@ class TestTwoLevelTiling:
         with autotune.force_blocks(*blocks):
             grids = _kernel_grids(lambda: run(flash))
             got = run(flash)
-        n = 1 if direction == "forward" else 3
+        # the forward, and the fused backward beside it
+        n = 1 if direction == "forward" else 2
         assert len(grids) == n and all(len(g) == 2 for g in grids), grids
         # the float32 reference on the same (possibly bf16-rounded) inputs
         want = run(lambda q, k, v: ref(q.astype(jnp.float32),
@@ -444,8 +464,7 @@ class TestTwoLevelTiling:
         from paddle_tpu.framework import monitor
         from paddle_tpu.ops.pallas import autotune
         traced = []
-        for name in ("_fwd_resident_kernel", "_bwd_dq_resident_kernel",
-                     "_bwd_dkv_resident_kernel"):
+        for name in ("_fwd_resident_kernel", "_bwd_dkv_resident_kernel"):
             def body(*a, _real=getattr(fa, name), _name=name, **k):
                 traced.append(_name)
                 return _real(*a, **k)
@@ -461,14 +480,14 @@ class TestTwoLevelTiling:
         with autotune.force_blocks(128, 128):
             text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
                 x, x, x))
+        # the fused backward is the dk/dv loop with dQ beside it
         assert sorted(traced) == ["_bwd_dkv_resident_kernel",
-                                  "_bwd_dq_resident_kernel",
                                   "_fwd_resident_kernel"]
         assert [text.count(f"name={n}") for n in (
-            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [3, 3, 3]
-        # 6 of 9 sub-tiles a head a kernel, three kernels, three layers
+            "flash_fwd", "flash_bwd_dq_dkv", "flash_bwd_dkv")] == [3, 3, 0]
+        # 6 of 9 sub-tiles a head a kernel, two kernels, three layers
         assert monitor.get_stat("flash_subtiles_computed_total") - before \
-            == 6 * 3 * 3 * 3
+            == 6 * 3 * 2 * 3
 
     @pytest.mark.parametrize("kind", ["bias", "segments", "tail",
                                       "cross_length"])
@@ -516,8 +535,8 @@ class TestTwoLevelTiling:
 
     def test_subtile_counters_at_the_gpt2_shape(self):
         """(S, t) = (1024, 256): 10 of a head's 16 sub-tiles are computed
-        and 6 left out, in each of the three kernels — counts taken when
-        the call is traced."""
+        and 6 left out, in the forward and in the fused backward — counts
+        taken when the call is traced."""
         from paddle_tpu.framework import monitor
         from paddle_tpu.ops.pallas import autotune
         B, S, H, D = 2, 1024, 3, 64
@@ -538,22 +557,179 @@ class TestTwoLevelTiling:
 
         with autotune.force_blocks(256, 256):
             assert traced() == (10, 6)
-            computed, skipped = traced(grad=True)    # forward, dq, dk/dv
-        assert (computed, skipped) == (30, 18)
+            computed, skipped = traced(grad=True)    # forward, backward
+        assert (computed, skipped) == (20, 12)
         assert skipped / (computed + skipped) == 0.375
         # one (1024, 1024) tile, the parent's table entry, skips nothing
         with autotune.force_blocks(1024, 1024):
             assert traced() == (1, 0)
         # tiles that are not square count in squares of their shorter side,
-        # so the share is of the area: a (1024, 256) dq kernel computes the
-        # trapezoid under the diagonal, a (128, 1024) dk/dv kernel 36 of 64
+        # so the share is of the area: a (128, 1024) backward computes 36
+        # of 64; with no tile of its own it takes dk/dv's
         with autotune.force_blocks(512, 512, direction="fwd"), \
                 autotune.force_blocks(1024, 256, direction="bwd"), \
                 autotune.force_blocks(128, 1024, direction="dkv"):
             assert traced() == (3, 1)
+            assert traced(grad=True) == (3 + 36, 1 + 28)
+        # where the dQ accumulator does not fit, a (1024, 256) dq kernel
+        # computes the trapezoid under the diagonal beside dk/dv's 36
+        with autotune.force_blocks(512, 512, direction="fwd"), \
+                autotune.force_blocks(1024, 256, direction="bwd"), \
+                autotune.force_blocks(128, 1024, direction="dkv"), \
+                _without_the_fused_backward():
             assert traced(grad=True) == (3 + 10 + 36, 1 + 6 + 28)
         text = monitor.export_prometheus()
         assert "above the causal diagonal" in text
+
+
+class TestFusedBackward:
+    """The two-level nest's backward as one kernel (``flash_bwd_dq_dkv``:
+    S, P, dP and dS built once a sub-tile for dQ, dK and dV) in interpret
+    mode, against the float32 reference and the split dq and dk/dv pair
+    it replaces; and which calls take it."""
+
+    def setup_method(self):
+        fa._INTERPRET = True
+
+    def teardown_method(self):
+        fa._INTERPRET = False
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype,d", [("bfloat16", 64), ("float32", 128)])
+    # (piece of queries, block of keys): several kv blocks that add into
+    # the dQ accumulator, pieces a block that a diagonal crosses, and one
+    # block of the whole sequence
+    @pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)])
+    def test_matches_the_reference_and_the_split_pair(self, blocks, dtype,
+                                                      d, causal):
+        from paddle_tpu.framework import monitor
+        from paddle_tpu.ops.pallas import autotune
+        rng = np.random.default_rng(45)
+        S = 256
+        q, k, v = (x.astype(dtype) for x in _rand_qkv(rng, 1, S, S, 2, d))
+        scale = 1.0 / np.sqrt(d)
+        f32 = lambda x: np.asarray(x, dtype=np.float32)
+        loss = lambda attn: lambda q, k, v: (
+            attn(q, k, v).astype(jnp.float32) ** 2).sum()
+        flash = lambda q, k, v: fa.flash_attention(q, k, v, causal, scale)
+        grad = lambda attn: jax.grad(loss(attn), argnums=(0, 1, 2))(q, k, v)
+        with autotune.force_blocks(*blocks):
+            before = monitor.get_stat("flash_bwd_fused_total")
+            assert _kernel_names(lambda: grad(flash)) == [
+                "flash_fwd", "flash_bwd_dq_dkv"]
+            fused = grad(flash)
+            assert monitor.get_stat("flash_bwd_fused_total") - before == 2
+            with _without_the_fused_backward():
+                assert _kernel_names(lambda: grad(flash)) == [
+                    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+                split = grad(flash)
+        want = grad(lambda q, k, v: fa._xla_reference(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), scale, causal))
+        tol = dict(rtol=5e-3, atol=5e-4) if dtype == "float32" \
+            else dict(rtol=5e-2, atol=1e-1)
+        for a, b, c, name in zip(fused, split, want, "qkv"):
+            assert a.dtype == jnp.dtype(dtype)
+            np.testing.assert_allclose(f32(a), f32(c), err_msg=f"d{name}",
+                                       **tol)
+            # the same products in the same order over the same pieces
+            np.testing.assert_allclose(f32(a), f32(b), rtol=1e-6,
+                                       atol=1e-6, err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("kind", ["bias", "segments", "tail",
+                                      "cross_length", "over_budget"])
+    def test_calls_outside_the_nest_keep_the_three_axis_pair(self, kind):
+        """Traced, not run: the backward of a call the nest does not admit
+        is the three-axis dq and dk/dv kernels, and the counter stays."""
+        from paddle_tpu.framework import monitor
+        from paddle_tpu.ops.pallas import autotune
+        sq, sk = {"tail": (320, 320), "cross_length": (128, 256),
+                  "over_budget": (2048, 2048)}.get(kind, (256, 256))
+        q = jax.ShapeDtypeStruct((1, sq, 2, 64), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, sk, 2, 64), jnp.bfloat16)
+        bias = jnp.zeros((1, 1, 1, sk), jnp.float32) \
+            if kind == "bias" else None
+        segs = jnp.zeros((1, sq), jnp.int32) if kind == "segments" else None
+        loss = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, bias=bias, q_segment_ids=segs,
+            kv_segment_ids=segs).astype(jnp.float32).sum()
+        grad = jax.grad(loss, argnums=(0, 1, 2))
+        before = monitor.get_stat("flash_bwd_fused_total")
+        # tiles of 128: 320 is a tail (its default tile is the whole 320)
+        with autotune.force_blocks(128, 128):
+            names = _kernel_names(grad, q, kv, kv)
+            grids = _kernel_grids(grad, q, kv, kv)
+        assert monitor.get_stat("flash_bwd_fused_total") == before
+        assert "flash_bwd_dq_dkv" not in names
+        assert names[:3] == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+        assert [len(g) for g in grids][:3] == [3, 3, 3], grids
+
+    def test_the_budget_decides_by_shape_alone(self):
+        """The accumulator and the resident set share the nest's budget:
+        the cells' calls fit, S = 1024 at d = 128 in float32 (the split
+        pair's edge) and d = 192 at S = 1024 do not."""
+        import inspect
+        fits = fa._fused_bwd_fits
+        assert fits(1024, 64, jnp.bfloat16) and fits(512, 64, jnp.bfloat16)
+        assert fits(1024, 128, jnp.bfloat16) and fits(512, 128, jnp.float32)
+        assert not fits(1024, 128, jnp.float32)
+        assert not fits(1024, 192, jnp.bfloat16)
+        assert fa._two_level(1024, 1024, 128, jnp.float32, 512, 512, False)
+        assert list(inspect.signature(fits).parameters) == ["s", "d",
+                                                           "dtype"]
+        source = inspect.getsource(fits)
+        assert "environ" not in source and "get_flag" not in source
+
+    def test_the_split_pair_serves_what_the_accumulator_does_not_fit(self):
+        """S = 1024, d = 128, float32: the nest, with dq and dk/dv apart."""
+        from paddle_tpu.framework import monitor
+        x = jax.ShapeDtypeStruct((1, 1024, 1, 128), jnp.float32)
+        loss = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+        before = monitor.get_stat("flash_bwd_fused_total")
+        grad = jax.grad(loss, argnums=(0, 1, 2))
+        assert _kernel_names(grad, x, x, x) == [
+            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+        assert [len(g) for g in _kernel_grids(grad, x, x, x)] == [2, 2, 2]
+        assert monitor.get_stat("flash_bwd_fused_total") == before
+
+    def test_the_counter_counts_each_traced_nest_backward(self):
+        from paddle_tpu.framework import monitor
+        x = jax.ShapeDtypeStruct((2, 512, 2, 64), jnp.bfloat16)
+        before = monitor.get_stat("flash_bwd_fused_total")
+
+        def loss(q, k, v):
+            for _ in range(3):                       # three "layers"
+                q = fa.flash_attention(q, k, v, causal=True)
+            return q.astype(jnp.float32).sum()
+
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+        assert monitor.get_stat("flash_bwd_fused_total") - before == 3
+        jax.eval_shape(loss, x, x, x)                # a forward counts none
+        assert monitor.get_stat("flash_bwd_fused_total") - before == 3
+        assert "ran as one kernel" in monitor.export_prometheus()
+
+    def test_the_benchmark_finds_the_kernel_by_its_name(self):
+        """The flash metrics sum the kernels whose name matches their
+        pattern: the fused kernel's must, once, and the names it replaced
+        must still."""
+        import re
+        from benchmarks.layer_metrics import flash_ms_per_step, flash_roofline
+        from paddle_tpu.ops.pallas import autotune
+        x = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
+        loss = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+        with autotune.force_blocks(128, 128):
+            names = _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+        assert "flash_bwd_dq_dkv" in names
+        for pattern in (flash_ms_per_step.FLASH_EVENT,
+                        flash_roofline.FLASH_EVENT):
+            assert all(re.search(pattern, n) for n in names), names
+            assert all(re.search(pattern, n) for n in (
+                "flash_bwd_dq", "flash_bwd_dkv"))
+        # the chip's own kernel timing tells it from dq's
+        assert autotune._KERNELS.index("flash_bwd_dq_dkv") < \
+            autotune._KERNELS.index("flash_bwd_dq")
 
 
 def _parent_faster(sq, sk, causal):
@@ -712,7 +888,7 @@ class TestBertCellGeometry:
                 q, k, v)
             tol = dict(rtol=5e-2, atol=1e-1)
         grids = _kernel_grids(lambda: run(flash))
-        n = 1 if direction == "forward" else 3
+        n = 1 if direction == "forward" else 2
         # the nest: two grid axes, (batch x heads, blocks of the table)
         assert len(grids) == n and all(len(g) == 2 for g in grids), grids
         for a, b, name in zip(run(flash), run(ref),

@@ -41,9 +41,10 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=1)
     ap.add_argument("--heads", type=int, default=8)
     ap.add_argument("--split", action="store_true",
-                    help="tune fwd, bwd and (two-level nest) dk/dv block "
-                         "sizes independently, each kernel by its own "
-                         "device time from a trace")
+                    help="tune the forward's and the backward's block "
+                         "sizes independently (on the two-level nest the "
+                         "fused backward's, or dq's and dk/dv's), each "
+                         "kernel by its own device time from a trace")
     ap.add_argument("--no-verify", action="store_true",
                     help="skip the differential oracle pre-timing gate "
                          "(candidates are then recorded unstamped)")
@@ -75,9 +76,9 @@ def main(argv=None) -> int:
             if out is None:
                 print("  no viable candidate")
             else:
-                fwd, bwd, dkv = out
-                print(f"  -> fwd {fwd[0]}, bwd {bwd[0]}" +
-                      (f", dkv {dkv[0]}" if dkv else ""))
+                print("  -> " + ", ".join(
+                    f"{direction} {best}"
+                    for direction, (best, _) in out.items()))
         else:
             out = autotune.measure(sq, sk, d, dt, causal, biased,
                                    batch=a.batch, heads=a.heads,

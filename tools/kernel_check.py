@@ -17,8 +17,11 @@ the module's own reference.  What it runs, with what the repo already has:
   output passes when its max abs error is within ``verify.SCALE_TOL`` of
   the largest reference value;
 * the benchmark's own calls, (8, 16, 1024, 64) causal and (32, 12, 512,
-  64) non-causal bf16, the same way;
-* the two-level nest at the edge of its VMEM budget, S=1024 d=128 float32;
+  64) non-causal bf16, the same way: the fused backward
+  (``flash_bwd_dq_dkv``), with the split dq and dk/dv pair's error beside
+  it (``split_pair_err``);
+* the two-level nest at the edge of its VMEM budget, S=1024 d=128 float32,
+  where the fused backward's accumulator does not fit and the pair runs;
 * ``verify.check_flash_candidate`` (compiled vs interpret vs reference on
   ``verify.boundary_corpus``: the non-divisible tail paths and a square of
   several tiles a side) at the largest and the smallest tile and at the
@@ -92,8 +95,9 @@ def check(name, run_kernel, run_reference, labels, tol=None, run_also=None):
     """One row: compile + run the kernel, then hold each output's max abs
     error to ``tol`` (one share for all outputs or one per label; default
     ``verify.SCALE_TOL``) of the reference's largest value.  ``run_also``
-    is a second implementation measured against the same reference, for
-    information (``xla_bf16_err``)."""
+    maps a column (``xla_bf16_err``, ``split_pair_err``) to another
+    implementation measured against the same reference, for
+    information."""
     from paddle_tpu.ops.pallas import verify
     t0 = time.perf_counter()
     row = {"kernel": name, "compiled": False, "within_tolerance": False}
@@ -111,10 +115,10 @@ def check(name, run_kernel, run_reference, labels, tol=None, run_also=None):
                               for lb, (_, m) in zip(labels, errs)}
         row["within_tolerance"] = all(
             e <= t * m for (e, m), t in zip(errs, tols))
-        if run_also is not None:
-            row["xla_bf16_err"] = {
+        for column, run in (run_also or {}).items():
+            row[column] = {
                 lb: float(f"{e:.3g}") for lb, (e, _) in
-                zip(labels, verify.max_errors(run_also(), want))}
+                zip(labels, verify.max_errors(run(), want))}
     except Exception as e:             # noqa: BLE001 — the row reports it
         traceback.print_exc()
         row["error"] = " ".join(str(e).split())[:400]
@@ -131,7 +135,8 @@ def flash_rows():
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     def case(name, sq, sk, d, causal, bias_shape=None, segs=False,
-             bias_grad=False, b=1, h=2, dtype=jnp.bfloat16):
+             bias_grad=False, b=1, h=2, dtype=jnp.bfloat16,
+             split_beside=False):
         rng = np.random.default_rng(sq + sk + d)
         q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
                                jnp.bfloat16) for s in (sq, sk, sk))
@@ -158,34 +163,48 @@ def flash_rows():
             with jax.default_matmul_precision("highest"):
                 return grads(reference, jnp.float32)
 
-        check(name,
-              lambda: grads(lambda a, b_, c, m: fa.flash_attention(
-                  a, b_, c, causal=causal, scale=scale, bias=m,
-                  bias_grad=bias_grad, q_segment_ids=seg,
-                  kv_segment_ids=seg)),
-              truth, ("loss", "dq", "dk", "dv", "dbias"),
-              run_also=lambda: grads(reference))
+        def flash(a, b_, c, m):
+            return fa.flash_attention(
+                a, b_, c, causal=causal, scale=scale, bias=m,
+                bias_grad=bias_grad, q_segment_ids=seg, kv_segment_ids=seg)
+
+        def split_pair():
+            """The nest's dq and dk/dv kernels, as where the fused
+            backward's accumulator does not fit."""
+            fits = fa._fused_bwd_fits
+            fa._fused_bwd_fits = lambda *_: False
+            try:
+                return grads(lambda *a: flash(*a))
+            finally:
+                fa._fused_bwd_fits = fits
+
+        also = {"xla_bf16_err": lambda: grads(reference)}
+        if split_beside:
+            also["split_pair_err"] = split_pair
+        check(name, lambda: grads(flash), truth,
+              ("loss", "dq", "dk", "dv", "dbias"), run_also=also)
 
     table = autotune._load()
     for key in sorted(k for k in table
-                      if not k.endswith((":bwd", ":dkv"))):
+                      if not k.endswith((":bwd", ":dkv", ":fused"))):
         shape, d, _, mask, biased = key.split(":")
         sq, sk = (int(x) for x in shape.split("x"))
         tiles = {"fwd": autotune._entry_blocks(table[key])}
-        for direction in ("bwd", "dkv"):
+        for direction in ("bwd", "dkv", "fused"):
             if f"{key}:{direction}" in table:
                 tiles[direction] = autotune._entry_blocks(
                     table[f"{key}:{direction}"])
         case(f"flash {key} tiles {tiles}", sq, sk, int(d[1:]),
              mask == "causal",
              bias_shape=(1, 1, 1, sk) if biased == "bias" else None)
-    # the benchmark's GPT-2 cells call the kernels at exactly this shape
+    # the benchmark's GPT-2 cells call the kernels at exactly this shape,
+    # the fused backward, with the split dq and dk/dv pair's error beside
     case("flash GPT-2 345M cell shape (8,16,1024,64) causal", 1024, 1024, 64,
-         True, b=8, h=16)
+         True, b=8, h=16, split_beside=True)
     # and bert_base.pretrain_b32_s512 at this one: no mask, the nest with
     # no diagonal, at the table's 512x512:d64:full tiles
     case("flash BERT-base cell shape (32,12,512,64) non-causal", 512, 512,
-         64, False, b=32, h=12)
+         64, False, b=32, h=12, split_beside=True)
     # the most the two-level nest keeps resident (its VMEM budget's edge)
     case("flash two-level nest at its budget: S=1024 d128 float32 causal",
          1024, 1024, 128, True, dtype=jnp.float32)
@@ -203,9 +222,10 @@ def tail_rows():
     from paddle_tpu.ops.pallas import autotune, verify
 
     # the largest and the smallest tile, and the tiles the table holds for
-    # the shape the benchmark's cells run (forward, dq and dk/dv)
+    # the shape the benchmark's cells run (forward, dq, dk/dv and the fused
+    # backward)
     tiles = [(1024, 1024), (128, 128)]
-    for direction in ("fwd", "bwd", "dkv"):
+    for direction in ("fwd", "bwd", "dkv", "fused"):
         tile = autotune.lookup(1024, 1024, 64, "bfloat16", True, False,
                                direction=direction)
         if tile and tile not in tiles:
